@@ -46,6 +46,9 @@ def main(argv=None) -> None:
                    help="run only the named benchmark(s)")
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         fig1_rho_tradeoff,
         fig2_tail_latency,

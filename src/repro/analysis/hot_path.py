@@ -37,9 +37,9 @@ from repro.analysis.kernel_contracts import Violation
 
 # Primitive names (substring match) that force a host round-trip inside a
 # traced computation. "callback" covers pure_callback / io_callback /
-# debug_callback (jax.debug.print's carrier); infeed/outfeed are the raw XLA
-# host-transfer ops.
-FORBIDDEN_PRIMITIVE_SUBSTRINGS = ("callback", "infeed", "outfeed")
+# debug_callback; jax.debug.print traces to its own debug_print primitive;
+# infeed/outfeed are the raw XLA host-transfer ops.
+FORBIDDEN_PRIMITIVE_SUBSTRINGS = ("callback", "debug_print", "infeed", "outfeed")
 
 
 def check_host_sync(closed_jaxpr, label: str = "<traced>", case: str = "trace"):

@@ -15,7 +15,10 @@ Layout facts this module relies on (verified against the pinned jax):
     outputs, scratch) — ``PrefetchScalarGridSpec`` operands arrive FIRST, as
     SMEM refs, counted by ``grid_mapping.num_index_operands``;
     ``params["grid_mapping"]`` carries ``grid``, ``block_mappings`` (inputs +
-    outputs only, scalars excluded), and the ``num_*`` operand counts;
+    outputs only, scalars excluded), and the ``num_*`` operand counts; a
+    ``BlockMapping`` holds the full array as ``array_aval`` and its block as
+    ``block_shape`` entries ``Squeezed()`` / ``Blocked(n)`` / ``Element`` /
+    ``BoundedSlice`` (read through :func:`block_dims`);
   * ``dma_start`` / ``dma_wait`` eqns share one invar layout — the flat
     ``(src_ref, *src_idx, dst_ref, *dst_idx, sem_ref, *sem_idx)`` copy
     descriptor — with constant indices appearing as ``Literal``s;
@@ -110,7 +113,10 @@ def memory_space_of(aval: Any) -> str:
 def aval_bytes(aval: Any) -> int:
     shape = getattr(aval, "shape", ())
     dtype = getattr(aval, "dtype", None)
-    itemsize = np.dtype(dtype).itemsize if dtype is not None else 4
+    try:
+        itemsize = np.dtype(dtype).itemsize if dtype is not None else 4
+    except TypeError:  # semaphore refs carry an opaque dtype: count 4 bytes
+        itemsize = 4
     return int(np.prod(shape, dtype=np.int64)) * itemsize if shape else itemsize
 
 
@@ -131,10 +137,32 @@ class KernelOperand:
     nbytes: int  # bytes of ONE buffer instance (no pipeline multiplier)
     array_shape: Tuple[int, ...]  # full HBM array shape ("" for scratch)
     block_mapping: Any = None  # the pallas BlockMapping (inputs/outputs only)
+    # per-array-dim block extent (a squeezed dim counts 1); () for scratch
+    block_dims: Tuple[int, ...] = ()
 
 
-def _block_bytes(block_shape: Sequence[Any], dtype: Any) -> Tuple[Tuple[int, ...], int]:
-    dims = tuple(int(d) for d in block_shape if d is not None)
+def block_dims(block_mapping: Any) -> Tuple[Tuple[int, ...], Tuple[bool, ...]]:
+    """``(extent per array dim, squeezed per array dim)`` of one BlockMapping."""
+    from jax._src.pallas import core as pallas_core
+
+    extents, squeezed = [], []
+    for d in block_mapping.block_shape:
+        if d is None or isinstance(d, pallas_core.Squeezed):
+            extents.append(1)
+            squeezed.append(True)
+        elif isinstance(d, int):
+            extents.append(d)
+            squeezed.append(False)
+        else:
+            extents.append(int(pallas_core._get_block_dim_size(d)))
+            squeezed.append(False)
+    return tuple(extents), tuple(squeezed)
+
+
+def _block_bytes(block_mapping: Any, dtype: Any) -> Tuple[Tuple[int, ...], int]:
+    """VMEM tile shape (squeezed dims dropped) and its bytes."""
+    extents, squeezed = block_dims(block_mapping)
+    dims = tuple(e for e, sq in zip(extents, squeezed) if not sq)
     n = 1
     for d in dims:
         n *= d
@@ -187,16 +215,19 @@ def kernel_operands(pallas_eqn: JaxprEqn) -> list[KernelOperand]:
             origin = getattr(bm, "origin", "") if bm is not None else ""
             label = f"{role}[{idx}]" + (f" {origin}" if origin else "")
             if bm is not None:
-                dtype = bm.array_shape_dtype.dtype
-                block_shape, nbytes = _block_bytes(bm.block_shape, dtype)
-                array_shape = tuple(bm.array_shape_dtype.shape)
+                dtype = bm.array_aval.dtype
+                block_shape, nbytes = _block_bytes(bm, dtype)
+                array_shape = tuple(bm.array_aval.shape)
+                extents = block_dims(bm)[0]
             else:  # defensive: fall back to the aval itself
                 dtype = getattr(aval, "dtype", np.float32)
                 block_shape = tuple(getattr(aval, "shape", ()))
                 nbytes = aval_bytes(aval)
-                array_shape = block_shape
+                array_shape = extents = block_shape
             out.append(
-                KernelOperand(label, role, space, block_shape, dtype, nbytes, array_shape, bm)
+                KernelOperand(
+                    label, role, space, block_shape, dtype, nbytes, array_shape, bm, extents
+                )
             )
         else:
             j = i - n_in - n_out

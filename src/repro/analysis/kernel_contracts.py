@@ -202,11 +202,10 @@ def _check_blocks(contract: KernelContract, case: ShapeCase, eqn) -> list[Violat
         bm = op.block_mapping
         if bm is None or op.space != "vmem":
             continue
-        block = tuple(d for d in bm.block_shape)
-        array_shape = tuple(int(s) for s in bm.array_shape_dtype.shape)
+        block = op.block_dims
+        array_shape = op.array_shape
         nblocks = []
         for d, (b, s) in enumerate(zip(block, array_shape)):
-            b = 1 if b is None else int(b)
             if s % b != 0:
                 out.append(
                     Violation(

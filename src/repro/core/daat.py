@@ -57,17 +57,19 @@ Kernel-backed phase 2 (``use_kernels=True``)
 The batched engine can route its hot inner ops through the batch-gridded
 Pallas kernels instead of jnp:
 
-  * block upper bounds — ``block_prune_batched`` contracts per-query dense
-    block-max rows with the query weights on the MXU (one launch, phase 0);
+  * block upper bounds — ``block_prune_csr_batched`` walks the per-term
+    block-max lists and accumulates the bound slot by slot (one launch,
+    phase 0);
   * chunk selection — ``block_topk_batched`` replaces ``lax.top_k`` over the
     remaining-ub vector (phase 1 seeding and every phase-2 iteration);
   * chunk scoring — ``sparse_score_batched`` match-and-accumulate replaces
     the jnp gather-reduce ``score_blocks``.
 
-The jnp path is kept verbatim as the parity oracle: doc ids and ``WorkStats``
-must match exactly, scores to fp32 tolerance (the kernels reassociate the
-same sums). All threshold/merge/masking logic is shared between the modes —
-``use_kernels`` swaps only HOW the same numbers are produced.
+The jnp path is kept verbatim as the parity oracle: doc ids, scores and
+``WorkStats`` must match exactly (every sum runs in the same fixed order in
+XLA and in the kernels — see :mod:`repro.kernels.common`). All
+threshold/merge/masking logic is shared between the modes — ``use_kernels``
+swaps only HOW the same numbers are produced.
 
 Fused chunk step (``use_kernels=True, fused_chunk=True``)
 ---------------------------------------------------------
@@ -245,19 +247,22 @@ def block_upper_bounds(
     """BMW-style additive upper bound for every document block.
 
     ``[Lq]`` inputs give ``f32[n_blocks]``; ``[B, Lq]`` inputs give
-    ``f32[B, n_blocks]`` computed by ONE batched scatter-add over the
-    per-term block-max lists (``ub[b_q, blk] = sum_t qw * blockmax``).
+    ``f32[B, n_blocks]``. The bound accumulates slot by slot
+    (``ub += qw_l * blockmax_l``): within one slot's list a block appears at
+    most once, so every scatter has distinct indices and the sum order is
+    fixed — the bits the ``block_prune_csr`` kernel reproduces.
     Ranks above 2 are not supported (the row-index scatter is rank-2).
     """
     blocks, w = _gather_blockmax_lists(index, q_terms, q_weights, max_bm_per_term)
     w = w * q_weights[..., :, None].astype(jnp.float32)
-    flat = blocks.shape[:-2] + (blocks.shape[-2] * blocks.shape[-1],)
-    blocks, w = blocks.reshape(flat), w.reshape(flat)
-    ub = jnp.zeros(blocks.shape[:-1] + (index.n_blocks,), jnp.float32)
-    if blocks.ndim == 1:
-        return ub.at[blocks].add(w)
-    rows = jnp.arange(blocks.shape[0], dtype=jnp.int32)[:, None]
-    return ub.at[rows, blocks].add(w)
+    ub = jnp.zeros(blocks.shape[:-2] + (index.n_blocks,), jnp.float32)
+    for l in range(blocks.shape[-2]):
+        if blocks.ndim == 2:
+            ub = ub.at[blocks[l]].add(w[l])
+        else:
+            rows = jnp.arange(blocks.shape[0], dtype=jnp.int32)[:, None]
+            ub = ub.at[rows, blocks[:, l]].add(w[:, l])
+    return ub
 
 
 def daat_plan(
@@ -289,8 +294,9 @@ def score_blocks(
     bitmap; nonzero = live) is 0 — masking happens at selection, never inside
     the score sum, so surviving docs' f32 scores are bit-identical with or
     without the mask. The inner op is a gather of query weights by term id +
-    a weighted row reduction — the ``block_score`` Pallas kernel implements
-    the same contraction with VMEM-tiled blocks.
+    a weighted row reduction in the fixed pairwise order of
+    :func:`repro.kernels.common.tree_sum_last` — the ``sparse_score`` and
+    ``chunk_step`` kernels compute the same bits with VMEM-tiled blocks.
     """
     bs = index.block_size
     docs = block_ids[..., :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
@@ -301,7 +307,9 @@ def score_blocks(
     else:
         rows = jnp.arange(qvec.shape[0], dtype=jnp.int32)[:, None, None, None]
         qv = qvec[rows, terms]
-    scores = jnp.sum(qv * w, axis=-1)
+    from repro.kernels.common import tree_sum_last
+
+    scores = tree_sum_last(qv * w)  # the kernels' fixed sum order
     scores = jnp.where(docs < index.n_docs, scores, -jnp.inf)
     if live_mask is not None:
         scores = jnp.where(live_mask[docs] != 0, scores, -jnp.inf)

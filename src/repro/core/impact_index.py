@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quantization import QuantConfig, dequantize, quantize
+from repro.kernels.common import next_pow2
 
 
 def _pad_to(x: np.ndarray, n: int, fill) -> np.ndarray:
@@ -42,6 +43,17 @@ def _pad_to(x: np.ndarray, n: int, fill) -> np.ndarray:
 
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
+
+
+# The doc-major store is never narrower than one 128-lane tile. A TPU lays a
+# narrower i32/f32 array out padded to 128 lanes in HBM anyway, so the floor
+# costs the chip no bytes; and Mosaic DMAs a block of the store's rows (the
+# fused DAAT chunk step) only when its width is a whole lane tile.
+DOC_STORE_MIN_WIDTH = 128
+
+
+def _store_width(max_doc_terms: int) -> int:
+    return next_pow2(max(max_doc_terms, DOC_STORE_MIN_WIDTH))
 
 
 # Static (non-array) fields of ImpactIndex. The single source of truth for
@@ -180,7 +192,11 @@ def build_impact_index(
       quant: impact quantization config.
       block_size: document-block size for the block-max (BMW) structure.
       pad_postings_to: pad the posting store to this multiple (TPU alignment).
-      max_doc_terms: doc-major padding width (defaults to the longest doc).
+      max_doc_terms: longest document the doc-major store keeps (defaults
+        to the longest doc; longer ones are truncated). The store's width is
+        this rounded up to a power of two, the shape the engines' fixed-order
+        row sum (``repro.kernels.common.tree_sum_last``) reduces without
+        padding, and to at least ``DOC_STORE_MIN_WIDTH`` slots.
     """
     doc_idx = np.asarray(doc_idx, dtype=np.int64)
     term_idx = np.asarray(term_idx, dtype=np.int64)
@@ -194,7 +210,7 @@ def build_impact_index(
         # but the posting/segment/block-max stores still get padded rows so
         # no zero-length array ever reaches a jitted gather.
         _, scale = quantize(weights, quant, max_weight=quant_max_weight)
-        max_doc_terms = max(1, max_doc_terms or 1)
+        max_doc_terms = _store_width(max_doc_terms or 1)
         n_docs_pad = _round_up(max(n_docs, 1), block_size)
         zc = np.zeros(n_terms + 1, dtype=np.int32)
         return ImpactIndex(
@@ -291,9 +307,10 @@ def build_impact_index(
     if max_doc_terms is None:
         max_doc_terms = int(doc_n.max())
     max_doc_terms = max(1, max_doc_terms)
+    width = _store_width(max_doc_terms)
     n_docs_pad = _round_up(max(n_docs, 1), block_size)
-    doc_terms = np.full((n_docs_pad, max_doc_terms), n_terms, dtype=np.int32)
-    doc_weights = np.zeros((n_docs_pad, max_doc_terms), dtype=np.float32)
+    doc_terms = np.full((n_docs_pad, width), n_terms, dtype=np.int32)
+    doc_weights = np.zeros((n_docs_pad, width), dtype=np.float32)
     # position of each posting within its doc
     doc_offsets = np.zeros(n_docs + 1, dtype=np.int64)
     doc_offsets[1:] = np.cumsum(doc_n)
@@ -330,7 +347,7 @@ def build_impact_index(
         n_terms=int(n_terms),
         n_blocks=int(n_blocks),
         block_size=int(block_size),
-        max_doc_terms=int(max_doc_terms),
+        max_doc_terms=int(width),
         scale=float(scale),
         bits=int(quant.bits),
         max_segs=int(term_seg_count.max()),

@@ -43,6 +43,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.impact_index import ImpactIndex
 from repro.core.topk import topk
@@ -175,59 +176,45 @@ def _gather_postings_batched(
     return docs, contribs, n_processed
 
 
-def _accumulate(index: ImpactIndex, docs, contribs, scatter_impl: str) -> jax.Array:
-    n_docs_pad = index.doc_terms.shape[0]
-    if scatter_impl == "jnp":
-        acc = jnp.zeros((n_docs_pad,), jnp.float32).at[docs].add(contribs)
-    elif scatter_impl == "sort":
-        # Sort-by-doc then segment-sum: the layout the Pallas kernel assumes.
-        order = jnp.argsort(docs)
-        sd, sc = docs[order], contribs[order]
-        acc = jax.ops.segment_sum(sc, sd, num_segments=n_docs_pad)
-    elif scatter_impl == "pallas":
-        from repro.kernels.impact_scatter import ops as scatter_ops
-
-        acc = scatter_ops.impact_scatter(docs, contribs, n_docs_pad)
-    else:
-        raise ValueError(f"unknown scatter_impl {scatter_impl!r}")
-    return acc
+def _accumulate(
+    index: ImpactIndex, docs, contribs, scatter_impl: str, max_run: int
+) -> jax.Array:
+    """Single-query scatter: the batched one at B = 1 (same sums, same bits)."""
+    return _accumulate_batched(index, docs[None], contribs[None], scatter_impl, max_run)[0]
 
 
 def _accumulate_batched(
-    index: ImpactIndex, docs: jax.Array, contribs: jax.Array, scatter_impl: str
+    index: ImpactIndex,
+    docs: jax.Array,
+    contribs: jax.Array,
+    scatter_impl: str,
+    max_run: int,
 ) -> jax.Array:
-    """Batch-aware scatter: ``docs/contribs [B, rho]`` -> ``acc [B, n_docs_pad]``."""
+    """Batch-aware scatter: ``docs/contribs [B, rho]`` -> ``acc [B, n_docs_pad]``.
+
+    ``"jnp"`` is the plain scatter-add oracle (addend order left to the
+    backend). ``"sort"`` and ``"pallas"`` first fold each document's
+    postings into one value in the canonical order of
+    :func:`repro.kernels.common.canonical_doc_sums` (stable doc sort, then a
+    segmented doubling scan bounded by ``max_run``, the query width), so the
+    accumulator they build — by XLA scatter or by the one-hot kernel — is
+    the same bits on every backend, and equal to the fused kernel's.
+    """
     n_docs_pad = index.doc_terms.shape[0]
     B = docs.shape[0]
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     if scatter_impl == "jnp":
-        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-        acc = jnp.zeros((B, n_docs_pad), jnp.float32).at[rows, docs].add(contribs)
-    elif scatter_impl == "sort":
-        if B * n_docs_pad < 2**31:  # row-offset keys must fit int32
-            # One batched multi-operand sort-by-doc (docs key, contribs
-            # payload — cheaper than argsort + two gathers), then a single
-            # flat segment-sum with row-offset doc keys (row b owns keys
-            # [b*D, (b+1)*D)).
-            sd, sc = jax.lax.sort((docs, contribs), dimension=-1, num_keys=1)
-            keys = sd + jnp.arange(B, dtype=jnp.int32)[:, None] * n_docs_pad
-            acc = jax.ops.segment_sum(
-                sc.reshape(-1),
-                keys.reshape(-1),
-                num_segments=B * n_docs_pad,
-                indices_are_sorted=True,
-            ).reshape(B, n_docs_pad)
-        else:
-            # Flat keys would overflow int32 and an unsorted scatter can't
-            # exploit ordering anyway, so skip the sort entirely.
-            rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-            acc = jnp.zeros((B, n_docs_pad), jnp.float32).at[rows, docs].add(contribs)
-    elif scatter_impl == "pallas":
+        return jnp.zeros((B, n_docs_pad), jnp.float32).at[rows, docs].add(contribs)
+    if scatter_impl == "sort":
+        from repro.kernels.common import canonical_doc_sums
+
+        sd, sums = canonical_doc_sums(docs, contribs, max_run)  # one nonzero per doc
+        return jnp.zeros((B, n_docs_pad), jnp.float32).at[rows, sd].add(sums)
+    if scatter_impl == "pallas":
         from repro.kernels.impact_scatter import ops as scatter_ops
 
-        acc = scatter_ops.impact_scatter_batched(docs, contribs, n_docs_pad)
-    else:
-        raise ValueError(f"unknown scatter_impl {scatter_impl!r}")
-    return acc
+        return scatter_ops.impact_scatter_batched(docs, contribs, n_docs_pad, max_run=max_run)
+    raise ValueError(f"unknown scatter_impl {scatter_impl!r}")
 
 
 def _mask_pad_docs(
@@ -245,6 +232,7 @@ def _fused_scatter_topk_batched(
     docs: jax.Array,
     contribs: jax.Array,
     k: int,
+    max_run: int,
     live_mask: jax.Array | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Scatter + pad-mask + top-k in ONE kernel: HBM sees only candidates."""
@@ -252,7 +240,8 @@ def _fused_scatter_topk_batched(
 
     n_docs_pad = index.doc_terms.shape[0]
     return fused_ops.impact_scatter_topk_batched(
-        docs, contribs, n_docs_pad, k, n_live=index.n_docs, live=live_mask
+        docs, contribs, n_docs_pad, k, n_live=index.n_docs, live=live_mask,
+        max_run=max_run,
     )
 
 
@@ -303,9 +292,11 @@ def saat_search(
     plan = saat_plan(index, q_terms, q_weights, max_segs_per_term)
     docs, contribs, n_proc = _gather_postings_batched(index, plan, rho)
     if fused_topk:
-        scores, ids = _fused_scatter_topk_batched(index, docs, contribs, k, live_mask)
+        scores, ids = _fused_scatter_topk_batched(
+            index, docs, contribs, k, q_terms.shape[-1], live_mask
+        )
     else:
-        acc = _accumulate_batched(index, docs, contribs, scatter_impl)
+        acc = _accumulate_batched(index, docs, contribs, scatter_impl, q_terms.shape[-1])
         scores, ids = topk(_mask_pad_docs(index, acc, live_mask), k)
     return SaatResult(scores, ids.astype(jnp.int32), n_proc, plan.total_postings)
 
@@ -333,13 +324,24 @@ def saat_search_vmap(
     def one(qt, qw):
         plan = saat_plan(index, qt, qw, max_segs_per_term)
         docs, contribs, n_proc = _gather_postings(index, plan, rho)
-        acc = _accumulate(index, docs, contribs, scatter_impl)
+        acc = _accumulate(index, docs, contribs, scatter_impl, qt.shape[-1])
         scores, ids = topk(_mask_pad_docs(index, acc, live_mask), k)
         return SaatResult(scores, ids.astype(jnp.int32), n_proc, plan.total_postings)
 
     return jax.vmap(one)(q_terms, q_weights)
 
 
-def exact_rho(index: ImpactIndex) -> int:
-    """A rho that guarantees rank-safe evaluation for any query."""
-    return index.n_postings
+def exact_rho(index: ImpactIndex, lq: int | None = None) -> int:
+    """A rho that guarantees rank-safe evaluation.
+
+    With ``lq=None``: for any query (the whole posting store). With a query
+    width ``lq``: for any query of at most ``lq`` distinct terms — the sum of
+    the ``lq`` longest posting lists, which is all such a query can touch.
+    Budget buffers are ``[B, rho]``, so on a deployment-size corpus this
+    bound, not the store size, is what fits the device. Reads the per-term
+    posting counts back from the device once.
+    """
+    if lq is None:
+        return index.n_postings
+    counts = np.sort(np.asarray(jax.device_get(index.term_post_count)))[::-1]
+    return max(1, min(int(counts[:lq].sum()), index.n_postings))

@@ -15,10 +15,39 @@ import jax.numpy as jnp
 NEG_INF = jnp.float32(-jnp.inf)
 
 
+def _order_key(x: jax.Array) -> jax.Array:
+    """i32 key ordered as f32's total order, the order ``lax.top_k`` compares in."""
+    i = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
 def topk(scores: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """Top-k scores and indices (descending). Static k."""
-    k = min(k, scores.shape[-1])
-    return jax.lax.top_k(scores, k)
+    """Top-k scores and indices (descending), equal scores in ascending index
+    order, on every backend. Static k.
+
+    ``lax.top_k`` documents that tie order, but the TPU compiler lowers a
+    wide top-k (k=1000 of 262,144) to sorts that compare values only and are
+    not stable, so which of several equal scores survives is left open there.
+    Only the values (which no tie order changes) are taken from
+    ``lax.top_k``; the positions are fixed here: every score above the k-th
+    value, then the lowest-index scores equal to it, found by a second
+    top-k over distinct keys, and ordered by (score desc, index asc).
+    """
+    n = scores.shape[-1]
+    k = min(k, n)
+    vals, _ = jax.lax.top_k(scores, k)
+    key = _order_key(scores)
+    kth = _order_key(vals[..., -1:])
+    above, tied = key > kth, key == kth
+    need = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    take = above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= need))
+    iota = jax.lax.broadcasted_iota(jnp.int32, scores.shape, scores.ndim - 1)
+    # exactly k positions are taken; their keys are distinct, so any top-k
+    # returns them, in ascending position order
+    _, pos = jax.lax.top_k(jnp.where(take, n - iota, -1), k)
+    sel = jnp.take_along_axis(key, pos, axis=-1)
+    _, ids = jax.lax.sort((~sel, pos), dimension=scores.ndim - 1, num_keys=2)
+    return vals, ids
 
 
 def tiled_topk(scores: jax.Array, k: int, num_tiles: int) -> Tuple[jax.Array, jax.Array]:
@@ -46,12 +75,12 @@ def tiled_topk(scores: jax.Array, k: int, num_tiles: int) -> Tuple[jax.Array, ja
     k_out = min(k, n)
     k_tile = min(k_out, tile)  # clamped k keeps whole tiles -> merge stays exact
     tiles = scores.reshape(scores.shape[:-1] + (num_tiles, tile))
-    s, i = jax.lax.top_k(tiles, k_tile)  # [..., num_tiles, k_tile]
+    s, i = topk(tiles, k_tile)  # [..., num_tiles, k_tile]
     base = (jnp.arange(num_tiles, dtype=jnp.int32) * tile)[:, None]
     gids = i.astype(jnp.int32) + base
     flat_s = s.reshape(scores.shape[:-1] + (num_tiles * k_tile,))
     flat_i = gids.reshape(scores.shape[:-1] + (num_tiles * k_tile,))
-    ms, mi = jax.lax.top_k(flat_s, k_out)
+    ms, mi = topk(flat_s, k_out)
     return ms, jnp.take_along_axis(flat_i, mi, axis=-1)
 
 
@@ -65,7 +94,7 @@ def merge_topk(
     """Merge two top-k pools (e.g. incremental DAAT chunks) into one."""
     s = jnp.concatenate([scores_a, scores_b], axis=-1)
     i = jnp.concatenate([ids_a, ids_b], axis=-1)
-    ms, mi = jax.lax.top_k(s, k)
+    ms, mi = topk(s, k)
     return ms, jnp.take_along_axis(i, mi, axis=-1)
 
 
@@ -123,7 +152,7 @@ def sharded_topk_merge(
     """
     gs = jax.lax.all_gather(local_scores, axis_name, axis=-1, tiled=True)
     gi = jax.lax.all_gather(local_ids, axis_name, axis=-1, tiled=True)
-    ms, mi = jax.lax.top_k(gs, k)
+    ms, mi = topk(gs, k)
     return ms, jnp.take_along_axis(gi, mi, axis=-1)
 
 

@@ -288,31 +288,9 @@ def constraint(x, mesh: Optional[Mesh], *spec):
 
 
 def _get_abstract_mesh():
-    """Version-tolerant ambient-mesh lookup.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in newer JAX releases; on
-    older ones the ambient mesh set by ``with mesh:`` lives in
-    ``jax._src.mesh.thread_resources``. When the new API exists but reports
-    no mesh (e.g. the scope was entered via the legacy ``with mesh:``
-    context rather than ``jax.set_mesh``), fall through to the
-    thread-resources lookup rather than trusting the empty answer. Returns
-    ``None`` when no mesh scope is active (or the private fallback is
-    unavailable), so callers degrade to the documented no-op.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        m = get()
-        if m is not None and not m.empty:
-            return m
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        m = _mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-    if m is None or m.empty:
-        return None
-    return m
+    """The ambient mesh set by ``jax.set_mesh(...)``, or ``None`` outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def current_axes() -> Optional[Axes]:

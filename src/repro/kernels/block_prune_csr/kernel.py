@@ -6,16 +6,20 @@ HBM by the engine's scatter just to be re-read by the kernel. This kernel
 walks the CSR block-max lists directly: the per-(query, slot) window offsets
 and entry counts arrive via scalar prefetch (``PrefetchScalarGridSpec`` SMEM
 operands — DMA source offsets must be known before the body runs), each
-slot's ``[M]`` window of ``bm_block``/``bm_weight`` streams HBM->VMEM with
+slot's window of ``bm_block``/``bm_weight`` streams HBM->VMEM with
 double-buffered async copies (slot ``l+1`` prefetches while slot ``l``
-densifies), and the densified ``[Lq, NBp]`` tile exists only as VMEM scratch.
+densifies), and the bound accumulates in VMEM.
 
-Parity contract: the tile is densified with the exact masked-gather
-semantics of ``repro.core.daat._gather_blockmax_lists`` (a block id appears
-at most once per per-term list, so the masked one-hot sum reproduces the
-scatter-add), and the bound is the same ``[1, Lq] x [Lq, NB]`` MXU dot the
-dense kernel runs — ``ub`` is bit-identical to ``block_prune_batched`` on the
-densified rows, so engine ids and WorkStats cannot move.
+HBM layout: the ops wrapper views each CSR array as ``[rows, 128]``, the
+TPU's tiled layout, so a window is the run of 8-row-aligned rows covering
+``[base, base + cnt)``; entries outside that range are masked by position.
+
+Parity contract: slot ``l`` adds ``q_weights[l] * blockmax[l, blk]`` to the
+bound of every block on its list, slot after slot — the order of the jnp
+engine's per-slot scatter (:func:`repro.core.daat.block_upper_bounds`). A
+block id appears at most once per list, so each slot's densified row has one
+nonzero per block and ``ub`` is bit-identical to the engine's, so engine ids
+and WorkStats cannot move.
 """
 from __future__ import annotations
 
@@ -24,36 +28,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+ALIGN_ROWS = 8  # HBM tile rows: window starts must sit on a multiple of 8 rows
+
 
 def _prune_csr_kernel_batched(
-    base_ref,  # SMEM i32[B, Lq] — scalar-prefetched window starts
-    cnt_ref,  # SMEM i32[B, Lq] — scalar-prefetched valid entry counts
-    qw_ref,  # f32[1, Lq]
-    theta_ref,  # f32[1, 1]
-    bm_block_hbm,  # i32[n_bm_pad] — stays in HBM, DMA'd per slot
-    bm_weight_hbm,  # f32[n_bm_pad] — stays in HBM, DMA'd per slot
+    base_ref,  # SMEM i32[B * Lq] — window starts (flat entry offsets)
+    cnt_ref,  # SMEM i32[B * Lq] — valid entry counts
+    qw_ref,  # SMEM f32[B * Lq] — query weights
+    theta_ref,  # SMEM f32[B] — prune thresholds
+    bm_block_hbm,  # i32[rows, 128] — stays in HBM, DMA'd per slot
+    bm_weight_hbm,  # f32[rows, 128] — stays in HBM, DMA'd per slot
     ub_ref,  # out f32[1, NBp]
     mask_ref,  # out i32[1, NBp]
-    bm_tile,  # VMEM f32[Lq, NBp] — the densified tile, never leaves VMEM
-    blk_buf,  # VMEM i32[2, M] — double-buffered block-id windows
-    w_buf,  # VMEM f32[2, M] — double-buffered block-max windows
+    blk_buf,  # VMEM i32[2, RW, 128] — double-buffered block-id windows
+    w_buf,  # VMEM f32[2, RW, 128] — double-buffered block-max windows
     sems,  # DMA semaphores (slot, block/weight)
+    *,
+    lq: int,
 ):
     b = pl.program_id(0)
-    lq, nbp = bm_tile.shape
-    m = blk_buf.shape[1]
+    nbp = ub_ref.shape[1]
+    rw = blk_buf.shape[1]
+    span = ALIGN_ROWS * LANES
+
+    def row0(l):
+        return pl.multiple_of(base_ref[b * lq + l] // span * ALIGN_ROWS, ALIGN_ROWS)
 
     def window_dma(slot, l):
-        start = base_ref[b, l]
+        rows = pl.ds(row0(l), rw)
         return (
-            pltpu.make_async_copy(
-                bm_block_hbm.at[pl.ds(start, m)], blk_buf.at[slot], sems.at[slot, 0]
-            ),
-            pltpu.make_async_copy(
-                bm_weight_hbm.at[pl.ds(start, m)], w_buf.at[slot], sems.at[slot, 1]
-            ),
+            pltpu.make_async_copy(bm_block_hbm.at[rows, :], blk_buf.at[slot], sems.at[slot, 0]),
+            pltpu.make_async_copy(bm_weight_hbm.at[rows, :], w_buf.at[slot], sems.at[slot, 1]),
         )
 
+    ub_ref[...] = jnp.zeros_like(ub_ref)
     for c in window_dma(0, 0):  # warm up the pipeline
         c.start()
     for l in range(lq):
@@ -63,73 +72,81 @@ def _prune_csr_kernel_batched(
                 c.start()
         for c in window_dma(slot, l):
             c.wait()
-        blk = blk_buf[slot]  # i32[M]
-        w = w_buf[slot].astype(jnp.float32)  # f32[M]
-        valid = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)[:, 0] < cnt_ref[b, l]
-        # a block id appears at most once per per-term list, so the masked
-        # one-hot sum IS the engine's scatter-add densification
-        onehot = (
-            blk[:, None] == jax.lax.broadcasted_iota(jnp.int32, (m, nbp), 1)
-        ) & valid[:, None]
-        bm_tile[l, :] = jnp.sum(jnp.where(onehot, w[:, None], 0.0), axis=0)
+        off = base_ref[b * lq + l] - row0(l) * LANES  # window-relative start
+        cnt = cnt_ref[b * lq + l]
+        qw = qw_ref[b * lq + l]
+        r_lo = off // LANES
+        r_hi = jnp.where(cnt > 0, (off + cnt - 1) // LANES + 1, r_lo)
 
-    # the dense kernel's exact contraction: [1, Lq] x [Lq, NBp] on the MXU
-    qw = qw_ref[...].astype(jnp.float32)
-    theta = theta_ref[0, 0]
-    ub = jnp.dot(qw, bm_tile[...], preferred_element_type=jnp.float32)
-    ub_ref[...] = ub
-    mask_ref[...] = ((ub > theta) & (ub > 0)).astype(jnp.int32)
+        def block_chunk(ci, carry, slot=slot, off=off, cnt=cnt, qw=qw, r_lo=r_lo, r_hi=r_hi):
+            blocks = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0) + ci * LANES
+
+            def window_row(r, acc):
+                pos = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) + r * LANES
+                valid = (pos >= off) & (pos < off + cnt)
+                blk = blk_buf[slot, pl.ds(r, 1), :]
+                w = w_buf[slot, pl.ds(r, 1), :]
+                return acc + jnp.where((blocks == blk) & valid, w, 0.0)
+
+            acc = jax.lax.fori_loop(
+                r_lo, r_hi, window_row, jnp.zeros((LANES, LANES), jnp.float32)
+            )
+            dense = jnp.sum(acc, axis=1)[None, :]  # one nonzero per block: exact
+            sl = pl.ds(pl.multiple_of(ci * LANES, LANES), LANES)
+            ub_ref[:, sl] = ub_ref[:, sl] + qw * dense
+            return carry
+
+        jax.lax.fori_loop(0, nbp // LANES, block_chunk, 0)
+
+    ub = ub_ref[...]
+    mask_ref[...] = ((ub > theta_ref[b]) & (ub > 0)).astype(jnp.int32)
 
 
 def block_prune_csr_batched_kernel(
-    bm_block: jax.Array,  # i32[n_bm_pad] — padded so every window is in-bounds
-    bm_weight: jax.Array,  # f32[n_bm_pad]
+    bm_block: jax.Array,  # i32[rows, 128] — padded so every window is in-bounds
+    bm_weight: jax.Array,  # f32[rows, 128]
     base: jax.Array,  # i32[B, Lq]
-    cnt: jax.Array,  # i32[B, Lq] (already clamped to M)
+    cnt: jax.Array,  # i32[B, Lq] (already clamped to m)
     q_weights: jax.Array,  # f32[B, Lq]
     theta: jax.Array,  # f32[B]
     *,
-    m: int,
+    window_rows: int,
     nbp: int,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """CSR-walking (ub, survive_mask) over doc blocks: grid over B.
 
-    ``base``/``cnt`` ride in as scalar-prefetch operands; ``bm_block`` /
-    ``bm_weight`` stay HBM-resident and are windowed in by DMA.
+    ``base``/``cnt``/``q_weights``/``theta`` ride in as scalar-prefetch
+    operands; ``bm_block`` / ``bm_weight`` stay HBM-resident and are
+    windowed in by DMA, ``window_rows`` rows of 128 entries per slot.
+    Returns ``(ub f32[B, nbp], mask i32[B, nbp])``.
     """
     B, lq = base.shape
-    row = lambda b, *_: (b, 0)  # noqa: E731 — scalar refs trail the index args
+    row = pl.BlockSpec((None, 1, nbp), lambda b, *_: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, lq), row),
-            pl.BlockSpec((1, 1), row),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # CSR block ids: DMA only
-            pl.BlockSpec(memory_space=pltpu.ANY),  # CSR block maxima: DMA only
+            pl.BlockSpec(memory_space=pl.ANY),  # CSR block ids: DMA only
+            pl.BlockSpec(memory_space=pl.ANY),  # CSR block maxima: DMA only
         ],
-        out_specs=[
-            pl.BlockSpec((1, nbp), row),
-            pl.BlockSpec((1, nbp), row),
-        ],
+        out_specs=[row, row],
         scratch_shapes=[
-            pltpu.VMEM((lq, nbp), jnp.float32),  # densified tile (VMEM-only)
-            pltpu.VMEM((2, m), jnp.int32),  # double-buffered id windows
-            pltpu.VMEM((2, m), jnp.float32),  # double-buffered max windows
+            pltpu.VMEM((2, window_rows, LANES), jnp.int32),  # id windows
+            pltpu.VMEM((2, window_rows, LANES), jnp.float32),  # max windows
             pltpu.SemaphoreType.DMA((2, 2)),  # (slot, block/weight)
         ],
     )
     ub, mask = pl.pallas_call(
-        _prune_csr_kernel_batched,
+        lambda *refs: _prune_csr_kernel_batched(*refs, lq=lq),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, nbp), jnp.float32),
-            jax.ShapeDtypeStruct((B, nbp), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, nbp), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, nbp), jnp.int32),
         ],
         interpret=interpret,
     )(
-        base, cnt, q_weights.reshape(B, lq), theta.reshape(B, 1),
-        bm_block, bm_weight,
+        base.reshape(-1), cnt.reshape(-1), q_weights.reshape(-1),
+        theta.reshape(-1), bm_block, bm_weight,
     )
-    return ub, mask
+    return ub[:, 0, :], mask[:, 0, :]

@@ -1,12 +1,13 @@
 """jit'd wrapper around the CSR-native block-prune kernel.
 
-Handles the engine <-> kernel impedance: the CSR arrays get ``M`` trailing
-zero entries so every scalar-prefetched window ``[base, base + M)`` is
-in-bounds (the sentinel term's empty list starts at the old array end), the
-block axis pads to the 128-lane multiple (pad columns densify to 0 ->
-``ub = 0`` -> never survive), and counts clamp to ``M`` defensively — the
-engine's :func:`repro.core.daat.csr_blockmax_offsets` already clamps, this
-keeps the op safe standalone.
+Handles the engine <-> kernel impedance: the CSR arrays are viewed as
+``[rows, 128]`` (the TPU's HBM tiling) with enough trailing zero rows that
+every 8-row-aligned window covering ``[base, base + M)`` is in-bounds (the
+sentinel term's empty list starts at the old array end), the block axis
+pads to the 128-lane multiple (pad columns densify to 0 -> ``ub = 0`` ->
+never survive), and counts clamp to ``M`` defensively — the engine's
+:func:`repro.core.daat.csr_blockmax_offsets` already clamps, this keeps the
+op safe standalone.
 """
 from __future__ import annotations
 
@@ -16,7 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import KernelContract, ShapeCase
-from repro.kernels.block_prune_csr.kernel import block_prune_csr_batched_kernel
+from repro.kernels.block_prune_csr.kernel import (
+    ALIGN_ROWS,
+    LANES,
+    block_prune_csr_batched_kernel,
+)
 from repro.kernels.common import interpret_default, round_up
 
 
@@ -48,27 +53,34 @@ def block_prune_csr_batched(
       theta: ``f32[B]`` per-query prune thresholds (``-inf`` = pure ub pass).
 
     Returns ``(ub f32[B, n_blocks], survive bool[B, n_blocks])`` —
-    bit-identical ``ub`` to ``block_prune_batched`` over the densified rows.
+    ``ub`` bit-identical to the engine's slot-by-slot scatter
+    (:func:`repro.core.daat.block_upper_bounds`).
     """
     if interpret is None:
         interpret = interpret_default()
     m = max_bm_per_term
     if m < 1:
         raise ValueError(f"max_bm_per_term={m} must be >= 1")
-    nbp = round_up(max(n_blocks, 1), 128)
-    pad = jnp.zeros((m,), bm_block.dtype)
-    bm_block_p = jnp.concatenate([bm_block.astype(jnp.int32), pad.astype(jnp.int32)])
-    bm_weight_p = jnp.concatenate(
-        [bm_weight.astype(jnp.float32), jnp.zeros((m,), jnp.float32)]
-    )
+    nbp = round_up(max(n_blocks, 1), LANES)
+    span = ALIGN_ROWS * LANES
+    # a window starts on the span boundary at or below `base` and must cover
+    # base + m - 1: at most m + span - 1 entries, rounded to whole spans
+    window_rows = round_up(m + span - 1, span) // LANES
+    n = bm_block.shape[0]
+    rows = round_up(n, span) // LANES + window_rows
+
+    def as_rows(x, dtype):
+        flat = jnp.zeros((rows * LANES,), dtype).at[:n].set(x.astype(dtype))
+        return flat.reshape(rows, LANES)
+
     ub, mask = block_prune_csr_batched_kernel(
-        bm_block_p,
-        bm_weight_p,
+        as_rows(bm_block, jnp.int32),
+        as_rows(bm_weight, jnp.float32),
         base.astype(jnp.int32),
         jnp.minimum(cnt.astype(jnp.int32), m),
         q_weights.astype(jnp.float32),
         jnp.asarray(theta, jnp.float32),
-        m=m,
+        window_rows=window_rows,
         nbp=nbp,
         interpret=interpret,
     )

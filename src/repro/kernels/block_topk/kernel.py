@@ -1,15 +1,16 @@
 """Pallas TPU kernel: tiled top-k over a large score vector.
 
 The JASS min-heap has no TPU analogue; the idiomatic replacement for top-k
-over millions of accumulators (8.8M docs, 1M recsys candidates) is a
-two-stage select: per-tile top-k entirely in VMEM, then a small host-side
-(or XLA) merge over ``num_tiles * k`` finalists. This kernel is stage 1; the
-``ops`` wrapper fuses stage 2 with ``lax.top_k`` over the finalists.
+over millions of accumulators is a two-stage select: per-tile top-k entirely
+in VMEM, then a small XLA merge over ``num_tiles * k`` finalists. This
+kernel is stage 1; the ``ops`` wrapper fuses stage 2 with ``lax.top_k`` over
+the finalists.
 
-Per-tile selection uses ``jax.lax.top_k`` *inside* the kernel over the VMEM
-tile — lowered by Mosaic to an on-chip sort network — so each grid step reads
-its tile from HBM exactly once: the pass is strictly memory-bound at
-``4 bytes/score``, the roofline floor for any selection algorithm.
+Each grid cell holds 8 query rows of one score tile (an f32 sublane tile)
+and runs the row-wise repeated-max selection of
+:func:`repro.kernels.impact_scatter_topk.kernel.select_topk_rows` — Mosaic
+has no ``top_k`` lowering — in ``lax.top_k``'s order (value descending,
+lowest index first among ties). Each tile is read from HBM exactly once.
 """
 from __future__ import annotations
 
@@ -19,81 +20,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _topk_kernel(scores_ref, out_s_ref, out_i_ref, *, k: int):
-    i = pl.program_id(0)
-    tile = scores_ref[0, :]  # f32[T]
-    t = tile.shape[0]
-    s, idx = jax.lax.top_k(tile, k)
-    out_s_ref[0, :] = s
-    out_i_ref[0, :] = idx.astype(jnp.int32) + i * t
+from repro.kernels.impact_scatter_topk.kernel import ROWS, select_topk_rows
 
 
 def _topk_kernel_batched(scores_ref, out_s_ref, out_i_ref, *, k: int):
     i = pl.program_id(1)
-    tile = scores_ref[0, 0, :]  # f32[T] — one (query, tile) cell
-    t = tile.shape[0]
-    s, idx = jax.lax.top_k(tile, k)
-    out_s_ref[0, 0, :] = s
-    out_i_ref[0, 0, :] = idx.astype(jnp.int32) + i * t
+    tile = scores_ref[...]  # f32[ROWS, T] — ROWS queries, one tile
+    t = tile.shape[1]
+    gid = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1) + i * t
+    s, idx = select_topk_rows(tile, gid, k)
+    out_s_ref[...] = s
+    out_i_ref[...] = idx
 
 
 def block_topk_batched_kernel(
-    scores: jax.Array,  # f32[B, n], n % tile == 0
+    scores: jax.Array,  # f32[B, n], B % 8 == 0, n % tile == 0
     *,
     k: int,
     tile: int = 8192,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Stage-1 select for a whole query batch: grid over (query, tile).
+    """Stage-1 select for a whole query batch: grid over (row group, tile).
 
-    Each grid cell reads one query's VMEM tile exactly once, so the batched
-    pass keeps the single-query kernel's memory-bound roofline while amortizing
-    one launch across the batch (DAAT chunk selection runs this every
-    while_loop iteration).
+    Returns ``(scores, ids)`` finalists of shape ``[n_tiles, B, k]``.
     """
     b, n = scores.shape
-    assert n % tile == 0 and k <= tile, (n, tile, k)
+    assert n % tile == 0 and k <= tile and b % ROWS == 0, (b, n, tile, k)
     n_tiles = n // tile
+    out = pl.BlockSpec((None, ROWS, k), lambda q, i: (i, q, 0))
     s, i = pl.pallas_call(
         functools.partial(_topk_kernel_batched, k=k),
-        grid=(b, n_tiles),
-        in_specs=[pl.BlockSpec((1, 1, tile), lambda q, i: (q, i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, k), lambda q, i: (q, i, 0)),
-        ],
+        grid=(b // ROWS, n_tiles),
+        in_specs=[pl.BlockSpec((ROWS, tile), lambda q, i: (q, i))],
+        out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((b, n_tiles, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_tiles, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, b, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, b, k), jnp.int32),
         ],
         interpret=interpret,
-    )(scores.reshape(b, n_tiles, tile))
-    return s, i
-
-
-def block_topk_kernel(
-    scores: jax.Array,  # f32[n], n % tile == 0
-    *,
-    k: int,
-    tile: int = 8192,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    n = scores.shape[0]
-    assert n % tile == 0 and k <= tile, (n, tile, k)
-    n_tiles = n // tile
-    s, i = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k),
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((1, tile), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(scores.reshape(n_tiles, tile))
+    )(scores)
     return s, i

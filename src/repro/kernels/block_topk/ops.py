@@ -7,33 +7,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import KernelContract, ShapeCase
-from repro.kernels.block_topk.kernel import block_topk_batched_kernel, block_topk_kernel
+from repro.core.topk import topk
+from repro.kernels.block_topk.kernel import block_topk_batched_kernel
 from repro.kernels.common import interpret_default, pad_axis
-
-
-@partial(jax.jit, static_argnames=("k", "tile", "interpret"))
-def block_topk(
-    scores: jax.Array,
-    k: int,
-    *,
-    tile: int = 8192,
-    interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Exact top-k over a 1-D score vector of any length. (scores, ids)."""
-    if interpret is None:
-        interpret = interpret_default()
-    n = scores.shape[0]
-    tile = min(tile, max(128, n))
-    k_eff = min(k, n)
-    s = pad_axis(scores.astype(jnp.float32), 0, tile, fill=-jnp.inf)
-    k_tile = min(max(k_eff, 1), tile)
-    ts, ti = block_topk_kernel(s, k=k_tile, tile=tile, interpret=interpret)
-    fs, fi = jax.lax.top_k(ts.reshape(-1), k_eff)
-    ids = ti.reshape(-1)[fi]
-    if k_eff < k:  # pad to requested k for shape stability
-        fs = jnp.concatenate([fs, jnp.full((k - k_eff,), -jnp.inf, fs.dtype)])
-        ids = jnp.concatenate([ids, jnp.zeros((k - k_eff,), ids.dtype)])
-    return fs, ids
+from repro.kernels.impact_scatter_topk.kernel import ROWS
 
 
 @partial(jax.jit, static_argnames=("k", "tile", "interpret"))
@@ -46,23 +23,42 @@ def block_topk_batched(
 ) -> tuple[jax.Array, jax.Array]:
     """Exact per-row top-k over ``scores [B, n]``. Returns ``([B, k], [B, k])``.
 
-    One (query, tile)-gridded kernel launch for stage 1, then one batched
-    finalist merge — no per-query vmapped programs.
+    One (row group, tile)-gridded kernel launch for stage 1, then one batched
+    finalist merge — no per-query vmapped programs. Among equal scores the
+    lower index comes first (:func:`repro.core.topk.topk`).
     """
     if interpret is None:
         interpret = interpret_default()
     b, n = scores.shape
-    tile = min(tile, max(128, n))
+    tile = min(tile, max(128, -(-n // 128) * 128))
     k_eff = min(k, n)
     s = pad_axis(scores.astype(jnp.float32), 1, tile, fill=-jnp.inf)
+    s = pad_axis(s, 0, ROWS, fill=-jnp.inf)
     k_tile = min(max(k_eff, 1), tile)
     ts, ti = block_topk_batched_kernel(s, k=k_tile, tile=tile, interpret=interpret)
-    fs, fi = jax.lax.top_k(ts.reshape(b, -1), k_eff)
-    ids = jnp.take_along_axis(ti.reshape(b, -1), fi, axis=-1)
+    # [n_tiles, B, k] -> [B, n_tiles * k]: tile-major finalists keep the
+    # lower-index-first tie order through the merge
+    ts = jnp.moveaxis(ts, 0, 1)[:b].reshape(b, -1)
+    ti = jnp.moveaxis(ti, 0, 1)[:b].reshape(b, -1)
+    fs, fi = topk(ts, k_eff)
+    ids = jnp.take_along_axis(ti, fi, axis=-1)
     if k_eff < k:  # pad to requested k for shape stability
         fs = jnp.concatenate([fs, jnp.full((b, k - k_eff), -jnp.inf, fs.dtype)], axis=-1)
         ids = jnp.concatenate([ids, jnp.zeros((b, k - k_eff), ids.dtype)], axis=-1)
     return fs, ids
+
+
+@partial(jax.jit, static_argnames=("k", "tile", "interpret"))
+def block_topk(
+    scores: jax.Array,
+    k: int,
+    *,
+    tile: int = 8192,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """Exact top-k over a 1-D score vector of any length: the batched op at B = 1."""
+    s, i = block_topk_batched(scores[None], k, tile=tile, interpret=interpret)
+    return s[0], i[0]
 
 
 def _contract_call(dims):

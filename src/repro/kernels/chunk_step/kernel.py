@@ -7,48 +7,49 @@ the remaining-ub selection finalists round-tripping HBM between them — traffic
 a skipping-hostile (wacky-weight) workload multiplies by its trip count. This
 kernel fuses the whole chunk step into one batch-gridded pass:
 
-  * remaining-ub top-``budget`` block selection (``lax.top_k`` over the
-    per-query ub row, processed blocks masked to ``-inf``);
+  * remaining-ub top-``budget`` block selection (repeated max with a
+    lowest-index argmax over the per-query ub row, processed blocks masked to
+    ``-inf``; the picks land in SMEM, where they address the DMAs);
   * live gating (``ub_c > theta`` — only these can change the top-k);
   * sparse scoring of the selected doc blocks (the ``sparse_score``
-    match-and-accumulate contraction, vocabulary-free);
+    match-and-accumulate, :func:`repro.kernels.sparse_score.kernel.score_doc_rows`);
   * candidate merge into the per-query top-k pool + the new threshold.
 
 Chunk state — the pool scores/ids, theta, the candidate tile, and the
-processed-bitmap row — lives in VMEM for the whole doc-block revisiting loop;
-only the updated state (pool, theta, processed) is written back. The selected
-blocks' doc-major rows are pulled from the HBM-resident store with
-double-buffered ``make_async_copy`` DMAs: while block ``j`` is being scored,
-block ``j+1``'s ``[bs, Tmax]`` term/weight rows are already in flight, so the
-gather latency hides behind the one-hot contraction.
+processed-bitmap row — lives in VMEM for the whole launch; only the updated
+state (pool, theta, processed) is written back. The selected blocks'
+doc-major rows are pulled from the HBM-resident store with double-buffered
+``make_async_copy`` DMAs: while block ``j`` is being scored, block ``j+1``'s
+``[bs, Tmax]`` term/weight rows are already in flight.
 
 Parity contract (the engine's ``fused_chunk`` flag relies on it): the kernel
-evaluates the numerically identical expressions, in the same order, as the
-jnp while-body in ``repro.core.daat`` — selection tie order is ``lax.top_k``'s
-(ties resolve to the lowest block id; the ``-inf`` pad lanes the ops wrapper
-appends sit at the highest ids, so they never displace a real block while
-``budget <= n_blocks``), the merge concatenates pool-then-candidates exactly
-like ``merge_topk``, and non-live / padded-doc candidates mask to ``-inf``
-before the merge. Doc ids, theta, and the processed bitmap are bit-identical
-to the jnp body; scores agree to f32 reassociation.
+evaluates the identical expressions, in the same order, as the jnp
+while-body in ``repro.core.daat`` — selection order is ``lax.top_k``'s (value
+descending, ties to the lowest block id; the ``-inf`` pad lanes the ops
+wrapper appends sit at the highest ids, so they never displace a real block
+while ``budget <= n_blocks``), the merge is ``lax.top_k`` over the pool
+followed by the candidates in block-selection order, exactly like
+``merge_topk``, and non-live / padded-doc candidates mask to ``-inf`` before
+the merge. Scores use the same fixed-order sums as the jnp scorer, so doc
+ids, scores, theta, and the processed bitmap are bit-identical to it.
 
-Multi-trip launch (``_chunk_step_multi_kernel_batched``)
---------------------------------------------------------
-The per-trip kernel above still exits to XLA on EVERY while_loop trip, so a
-skipping-collapsed (wacky-weight) query pays one launch plus a pool/theta/
+Trips per launch
+----------------
+A skipping-collapsed (wacky-weight) query pays one launch plus a pool/theta/
 processed HBM round-trip per trip — multiplied by exactly the trip counts the
-paper shows explode. The multi-trip variant runs up to ``trips`` trip bodies
+paper shows explode. The kernel therefore runs up to ``trips`` trip bodies
 inside ONE launch: the per-query state initializes the output blocks once,
 revolves in VMEM across trips, and crosses HBM once per *launch*. A
-scalar-prefetched per-row trip budget (``PrefetchScalarGridSpec``; the engine
-passes ``min(max_chunks - chunks, trips_per_launch)``, 0 for inactive rows)
-plus the in-kernel early exit — each trip body runs under
-``pl.when(t < budget AND max remaining ub > theta)``, so a row that goes
-rank-safe mid-launch skips the remaining trips' DMAs and compute entirely.
-Because each row's trip sequence never depends on other rows, running T trip
-bodies in-kernel is bit-identical to T per-trip launches; the extra
-``trips_done`` output row lets the engine advance its per-query chunk counts
-without re-deriving them.
+scalar-prefetched per-row trip budget (the engine passes ``min(max_chunks -
+chunks, trips_per_launch)``, 0 for inactive rows) plus the in-kernel early
+exit — each trip body runs under ``pl.when(t < budget AND max remaining ub >
+theta)``, so a row that goes rank-safe mid-launch skips the remaining trips'
+DMAs and compute entirely. Because each row's trip sequence never depends on
+other rows, running T trip bodies in-kernel is bit-identical to T one-trip
+launches; the ``trips_done`` output row lets the engine advance its
+per-query chunk counts. A skipped trip leaves the state exactly where an
+executed one would (no block above theta means no live candidate), so the
+one-trip launch is simply ``trips=1``.
 """
 from __future__ import annotations
 
@@ -59,18 +60,64 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.sparse_score.kernel import score_doc_rows
+
+
+def _merge(pool_s, pool_i, cand, sel_blk, *, bs: int):
+    """``lax.top_k`` over ``concat(pool, cand.flat)`` with the ids it implies.
+
+    ``pool_s/pool_i [1, k]`` precede ``cand [budget, bs]`` (row-major, doc id
+    ``sel_blk[j] * bs + lane``) in position order, so among equal scores the
+    pool wins, then the earlier-selected block, then the lower doc.
+    Returns ``(scores [1, k], ids [1, k])``.
+    """
+    k = pool_s.shape[1]
+    budget = cand.shape[0]
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    cpos = (
+        jax.lax.broadcasted_iota(jnp.int32, (budget, bs), 0) * bs
+        + jax.lax.broadcasted_iota(jnp.int32, (budget, bs), 1)
+    )
+    n_cand = budget * bs
+
+    def one(i, carry):
+        tp, tc, out_s, out_i = carry
+        ap, ac = tp == 0, tc == 0
+        m = jnp.maximum(
+            jnp.max(jnp.where(ap, pool_s, -jnp.inf)), jnp.max(jnp.where(ac, cand, -jnp.inf))
+        )
+        pp = jnp.min(jnp.where(ap & (pool_s == m), kpos, k))
+        pc = jnp.min(jnp.where(ac & (cand == m), cpos, n_cand))
+        from_pool = pp < k
+        blk = sel_blk[jnp.minimum(pc // bs, budget - 1)]
+        pid = jnp.where(
+            from_pool, jnp.sum(jnp.where(kpos == pp, pool_i, 0)), blk * bs + pc % bs
+        )
+        tp = jnp.where(from_pool & (kpos == pp), 1, tp)
+        tc = jnp.where(jnp.logical_not(from_pool) & (cpos == pc), 1, tc)
+        out_s = jnp.where(kpos == i, m, out_s)
+        out_i = jnp.where(kpos == i, pid, out_i)
+        return tp, tc, out_s, out_i
+
+    init = (
+        jnp.zeros((1, k), jnp.int32),
+        jnp.zeros((budget, bs), jnp.int32),
+        jnp.full((1, k), -jnp.inf, jnp.float32),
+        jnp.zeros((1, k), jnp.int32),
+    )
+    _, _, out_s, out_i = jax.lax.fori_loop(0, k, one, init)
+    return out_s, out_i
+
 
 def _trip_body(
-    ub,  # f32[NBp] (value, not ref — constant across trips)
-    proc,  # i32[NBp] current processed row (1 = processed / pad)
-    theta,  # f32[] current threshold
-    pool_s,  # f32[k] current pool scores
-    pool_i,  # i32[k] current pool ids
-    qt,  # i32[Lq]
-    qw,  # f32[Lq]
+    q0,  # first flat SMEM slot of this query's terms / weights
+    ub,  # f32[1, NBp] (value, not ref — constant across trips)
+    qt_ref,
+    qw_ref,
+    theta_ref,  # SMEM f32[1] running threshold
     dt_hbm,
     dw_hbm,
-    lv_hbm,  # i32[nb, bs] tombstone bitmap rows in HBM, or None
+    lv_hbm,  # i32[nb, 1, bs] tombstone rows in HBM, or None
     out_s_ref,
     out_i_ref,
     out_theta_ref,
@@ -79,31 +126,42 @@ def _trip_body(
     dw_buf,
     lv_buf,  # VMEM (2, 1, bs) live-row double buffer, or None
     cand_ref,
+    prod_ref,
+    sel_blk,  # SMEM i32[budget]
+    sel_ub,  # SMEM f32[budget]
     sems,
     *,
+    lq: int,
     budget: int,
     bs: int,
     n_live: int,
 ):
-    """ONE select+score+merge trip; writes the new state into the out refs.
-
-    Shared verbatim between the per-trip and multi-trip kernels so the parity
-    contract (bit-identical ids/theta/processed vs the jnp while-body) is
-    maintained in exactly one place.
+    """ONE select+score+merge trip; reads and writes the state in the out refs.
 
     When ``lv_hbm`` is present, each selected block's ``[bs]`` tombstone row
     rides the same double-buffered DMA pipeline as its doc-major rows (third
     semaphore lane) and masks dead docs' scores to ``-inf`` before the merge
     — the in-kernel image of the jnp body's ``live_mask`` gather.
     """
-    # ---- select: remaining-ub top-budget, entirely from the VMEM ub row ----
-    rub = jnp.where(proc != 0, -jnp.inf, ub)
-    ub_c, b_c = jax.lax.top_k(rub, budget)  # [budget], ties -> lowest block id
-    live = ub_c > theta  # only these can change the top-k
+    proc = out_proc_ref[...]
+    theta = theta_ref[0]
+    nbp = proc.shape[1]
 
-    # ---- score: doc-block revisiting loop, double-buffered HBM prefetch ----
+    # ---- select: remaining-ub top-budget, lax.top_k order, into SMEM ----
+    rub = jnp.where(proc != 0, -jnp.inf, ub)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, nbp), 1)
+    taken = jnp.zeros((1, nbp), jnp.int32)
+    for j in range(budget):
+        avail = taken == 0
+        m = jnp.max(jnp.where(avail, rub, -jnp.inf))
+        pos = jnp.min(jnp.where(avail & (rub == m), lane, nbp))
+        taken = jnp.where(lane == pos, 1, taken)
+        sel_blk[j] = pos
+        sel_ub[j] = m
+
+    # ---- score: selected doc blocks, double-buffered HBM prefetch ----
     def doc_dma(slot, j):
-        row0 = b_c[j] * bs
+        row0 = pl.multiple_of(sel_blk[j] * bs, bs)
         copies = (
             pltpu.make_async_copy(
                 dt_hbm.at[pl.ds(row0, bs), :], dt_buf.at[slot], sems.at[slot, 0]
@@ -114,9 +172,7 @@ def _trip_body(
         )
         if lv_hbm is not None:
             copies += (
-                pltpu.make_async_copy(
-                    lv_hbm.at[pl.ds(b_c[j], 1), :], lv_buf.at[slot], sems.at[slot, 2]
-                ),
+                pltpu.make_async_copy(lv_hbm.at[sel_blk[j]], lv_buf.at[slot], sems.at[slot, 2]),
             )
         return copies
 
@@ -129,102 +185,47 @@ def _trip_body(
                 c.start()
         for c in doc_dma(slot, j):
             c.wait()
-        terms = dt_buf[slot]  # i32[bs, Tmax]
-        w = dw_buf[slot].astype(jnp.float32)
-        tmax = terms.shape[-1]
-        # the sparse_score contraction: term match -> one-hot -> MXU
-        onehot = (terms.reshape(bs * tmax, 1) == qt[None, :]).astype(jnp.float32)
-        qv = jnp.dot(onehot, qw[:, None], preferred_element_type=jnp.float32)
-        s = jnp.sum(qv.reshape(bs, tmax) * w, axis=-1)  # f32[bs]
-        docs = b_c[j] * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
+        s = score_doc_rows(dt_buf.at[slot], dw_buf.at[slot], qt_ref, qw_ref, q0, lq, prod_ref)
+        docs = sel_blk[j] * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         s = jnp.where(docs < n_live, s, -jnp.inf)  # padded docs never rank
         if lv_hbm is not None:
-            s = jnp.where(lv_buf[slot][0] != 0, s, -jnp.inf)  # tombstoned docs
-        s = jnp.where(live[j], s, -jnp.inf)  # dead blocks contribute nothing
-        cand_ref[j, :] = s
+            s = jnp.where(lv_buf[slot] != 0, s, -jnp.inf)  # tombstoned docs
+        live = sel_ub[j] > theta  # only these can change the top-k
+        cand_ref[pl.ds(j, 1), :] = jnp.where(live, s, -jnp.inf)
 
-    # ---- merge: pool + candidates -> new pool/theta (merge_topk order) ----
-    k = pool_s.shape[0]
-    d_flat = (
-        b_c[:, None] * bs + jax.lax.broadcasted_iota(jnp.int32, (budget, bs), 1)
-    ).reshape(-1)
-    all_s = jnp.concatenate([pool_s, cand_ref[...].reshape(-1)])
-    all_i = jnp.concatenate([pool_i, d_flat.astype(jnp.int32)])
-    ms, mpos = jax.lax.top_k(all_s, k)
-    out_s_ref[0, :] = ms
-    out_i_ref[0, :] = jnp.take(all_i, mpos)
-    out_theta_ref[0, 0] = ms[k - 1]
+    # ---- merge: only a candidate above the pool's k-th score (the pool is
+    # sorted descending; ties keep the pool entry) can change the pool ----
+    @pl.when(jnp.max(cand_ref[...]) > jnp.min(out_s_ref[...]))
+    def _merge_pool():
+        s, i = _merge(out_s_ref[...], out_i_ref[...], cand_ref[...], sel_blk, bs=bs)
+        out_s_ref[...] = s
+        out_i_ref[...] = i
 
-    # ---- processed |= live-selected blocks (top_k ids are distinct) ----
-    nbp = proc.shape[0]
-    hit = (jax.lax.broadcasted_iota(jnp.int32, (budget, nbp), 1) == b_c[:, None]) & live[
-        :, None
-    ]
-    out_proc_ref[0, :] = jnp.maximum(proc, jnp.any(hit, axis=0).astype(proc.dtype))
+    new_theta = jnp.min(out_s_ref[...])  # the merged pool's k-th score
+    theta_ref[0] = new_theta
+    out_theta_ref[...] = jnp.full((1, 1), new_theta, jnp.float32)
+
+    # ---- processed |= live-selected blocks (picks are distinct) ----
+    for j in range(budget):
+        hit = (lane == sel_blk[j]) & (sel_ub[j] > theta)
+        proc = jnp.where(hit, 1, proc)
+    out_proc_ref[...] = proc
 
 
 def _chunk_step_kernel_batched(
+    qt_ref,  # SMEM i32[B * Lq]
+    qw_ref,  # SMEM f32[B * Lq] (pad slots zeroed)
+    theta_in_ref,  # SMEM f32[B]
+    trips_ref,  # SMEM i32[B] — per-row trip budget
     ub_ref,
     proc_ref,
     pool_s_ref,
     pool_i_ref,
-    theta_ref,
-    qt_ref,
-    qw_ref,
-    dt_hbm,
-    dw_hbm,
-    *rest,
-    budget: int,
-    bs: int,
-    n_live: int,
-    has_live: bool = False,
-):
-    if has_live:
-        (lv_hbm, out_s_ref, out_i_ref, out_theta_ref, out_proc_ref,
-         dt_buf, dw_buf, cand_ref, lv_buf, sems) = rest
-    else:
-        (out_s_ref, out_i_ref, out_theta_ref, out_proc_ref,
-         dt_buf, dw_buf, cand_ref, sems) = rest
-        lv_hbm = lv_buf = None
-    _trip_body(
-        ub_ref[0, :],
-        proc_ref[0, :],
-        theta_ref[0, 0],
-        pool_s_ref[0, :],
-        pool_i_ref[0, :],
-        qt_ref[0, :],
-        qw_ref[0, :].astype(jnp.float32),
-        dt_hbm,
-        dw_hbm,
-        lv_hbm,
-        out_s_ref,
-        out_i_ref,
-        out_theta_ref,
-        out_proc_ref,
-        dt_buf,
-        dw_buf,
-        lv_buf,
-        cand_ref,
-        sems,
-        budget=budget,
-        bs=bs,
-        n_live=n_live,
-    )
-
-
-def _chunk_step_multi_kernel_batched(
-    trips_ref,  # SMEM i32[B] — scalar-prefetched per-row trip budget
-    ub_ref,
-    proc_ref,
-    pool_s_ref,
-    pool_i_ref,
-    theta_ref,
-    qt_ref,
-    qw_ref,
     dt_hbm,
     dw_hbm,
     *rest,
     trips: int,
+    lq: int,
     budget: int,
     bs: int,
     n_live: int,
@@ -240,56 +241,36 @@ def _chunk_step_multi_kernel_batched(
     trip's DMAs and compute entirely — the in-kernel early exit.
     """
     if has_live:
-        (lv_hbm, out_s_ref, out_i_ref, out_theta_ref, out_proc_ref,
-         out_trips_ref, dt_buf, dw_buf, cand_ref, lv_buf, sems) = rest
+        (lv_hbm, out_s_ref, out_i_ref, out_theta_ref, out_proc_ref, out_trips_ref,
+         dt_buf, dw_buf, cand_ref, prod_ref, lv_buf, theta_ref, sel_blk, sel_ub,
+         sems) = rest
     else:
-        (out_s_ref, out_i_ref, out_theta_ref, out_proc_ref,
-         out_trips_ref, dt_buf, dw_buf, cand_ref, sems) = rest
+        (out_s_ref, out_i_ref, out_theta_ref, out_proc_ref, out_trips_ref,
+         dt_buf, dw_buf, cand_ref, prod_ref, theta_ref, sel_blk, sel_ub,
+         sems) = rest
         lv_hbm = lv_buf = None
     b = pl.program_id(0)
     out_s_ref[...] = pool_s_ref[...]
     out_i_ref[...] = pool_i_ref[...]
-    out_theta_ref[...] = theta_ref[...]
     out_proc_ref[...] = proc_ref[...]
-    out_trips_ref[0, 0] = 0
-
-    ub = ub_ref[0, :]
-    qt = qt_ref[0, :]
-    qw = qw_ref[0, :].astype(jnp.float32)
+    theta_ref[0] = theta_in_ref[b]
+    out_theta_ref[...] = jnp.full((1, 1), theta_in_ref[b], jnp.float32)
+    out_trips_ref[...] = jnp.zeros((1, 1), jnp.int32)
+    ub = ub_ref[...]
 
     for t in range(trips):
-        proc = out_proc_ref[0, :]
-        theta = out_theta_ref[0, 0]
-        more = jnp.max(jnp.where(proc != 0, -jnp.inf, ub)) > theta
-        active = (t < trips_ref[b]) & more
+        remaining = jnp.where(out_proc_ref[...] != 0, -jnp.inf, ub)
+        active = (t < trips_ref[b]) & (jnp.max(remaining) > theta_ref[0])
 
         @pl.when(active)
-        def _one_trip(proc=proc, theta=theta):
+        def _one_trip():
             _trip_body(
-                ub,
-                proc,
-                theta,
-                out_s_ref[0, :],
-                out_i_ref[0, :],
-                qt,
-                qw,
-                dt_hbm,
-                dw_hbm,
-                lv_hbm,
-                out_s_ref,
-                out_i_ref,
-                out_theta_ref,
-                out_proc_ref,
-                dt_buf,
-                dw_buf,
-                lv_buf,
-                cand_ref,
-                sems,
-                budget=budget,
-                bs=bs,
-                n_live=n_live,
+                b * lq, ub, qt_ref, qw_ref, theta_ref, dt_hbm, dw_hbm, lv_hbm,
+                out_s_ref, out_i_ref, out_theta_ref, out_proc_ref,
+                dt_buf, dw_buf, lv_buf, cand_ref, prod_ref, sel_blk, sel_ub, sems,
+                lq=lq, budget=budget, bs=bs, n_live=n_live,
             )
-            out_trips_ref[0, 0] = out_trips_ref[0, 0] + 1
+            out_trips_ref[...] = out_trips_ref[...] + 1
 
 
 def chunk_step_batched_kernel(
@@ -297,87 +278,7 @@ def chunk_step_batched_kernel(
     processed: jax.Array,  # i32[B, NBp] (pad lanes = 1)
     pool_s: jax.Array,  # f32[B, k]
     pool_i: jax.Array,  # i32[B, k]
-    theta: jax.Array,  # f32[B, 1]
-    q_terms: jax.Array,  # i32[B, Lq]
-    q_weights: jax.Array,  # f32[B, Lq] (pad slots already zeroed)
-    doc_terms: jax.Array,  # i32[n_docs_pad, Tmax] — stays in HBM, DMA'd
-    doc_weights: jax.Array,  # f32[n_docs_pad, Tmax] — stays in HBM, DMA'd
-    *,
-    budget: int,
-    bs: int,
-    n_live: int,
-    live: jax.Array | None = None,  # i32[nb, bs] tombstone rows — HBM, DMA'd
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One fused phase-2 chunk step for a whole query batch: grid over B.
-
-    Returns ``(pool_s, pool_i, theta, processed)`` — the only arrays that
-    cross the HBM boundary per trip. The ``[B, budget, bs]`` candidate score
-    tensor and the selection finalists never leave VMEM. ``live`` (optional)
-    is the lifecycle tombstone bitmap reshaped to block rows; like the doc
-    stores it stays in HBM and only the selected blocks' rows are DMA'd.
-    """
-    B, nbp = ub.shape
-    k = pool_s.shape[1]
-    lq = q_terms.shape[1]
-    tmax = doc_terms.shape[1]
-
-    row = lambda b: (b, 0)  # noqa: E731 — one query row per grid cell
-    in_specs = [
-        pl.BlockSpec((1, nbp), row),
-        pl.BlockSpec((1, nbp), row),
-        pl.BlockSpec((1, k), row),
-        pl.BlockSpec((1, k), row),
-        pl.BlockSpec((1, 1), row),
-        pl.BlockSpec((1, lq), row),
-        pl.BlockSpec((1, lq), row),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # doc-major store: DMA only
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
-    scratch = [
-        pltpu.VMEM((2, bs, tmax), jnp.int32),  # double-buffered doc terms
-        pltpu.VMEM((2, bs, tmax), jnp.float32),  # double-buffered doc weights
-        pltpu.VMEM((budget, bs), jnp.float32),  # candidate score tile
-        pltpu.SemaphoreType.DMA((2, 2)),  # (slot, terms/weights)
-    ]
-    args = [ub, processed, pool_s, pool_i, theta, q_terms, q_weights,
-            doc_terms, doc_weights]
-    if live is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # live rows: DMA only
-        args.append(live.astype(jnp.int32))
-        scratch.insert(3, pltpu.VMEM((2, 1, bs), jnp.int32))  # live-row buffer
-        scratch[-1] = pltpu.SemaphoreType.DMA((2, 3))  # (slot, terms/weights/live)
-    out = pl.pallas_call(
-        functools.partial(
-            _chunk_step_kernel_batched, budget=budget, bs=bs, n_live=n_live,
-            has_live=live is not None,
-        ),
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), row),
-            pl.BlockSpec((1, k), row),
-            pl.BlockSpec((1, 1), row),
-            pl.BlockSpec((1, nbp), row),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, nbp), jnp.int32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
-    return out[0], out[1], out[2], out[3]
-
-
-def chunk_step_multi_batched_kernel(
-    ub: jax.Array,  # f32[B, NBp] (pad lanes = -inf)
-    processed: jax.Array,  # i32[B, NBp] (pad lanes = 1)
-    pool_s: jax.Array,  # f32[B, k]
-    pool_i: jax.Array,  # i32[B, k]
-    theta: jax.Array,  # f32[B, 1]
+    theta: jax.Array,  # f32[B]
     q_terms: jax.Array,  # i32[B, Lq]
     q_weights: jax.Array,  # f32[B, Lq] (pad slots already zeroed)
     doc_terms: jax.Array,  # i32[n_docs_pad, Tmax] — stays in HBM, DMA'd
@@ -388,73 +289,84 @@ def chunk_step_multi_batched_kernel(
     budget: int,
     bs: int,
     n_live: int,
-    live: jax.Array | None = None,  # i32[nb, bs] tombstone rows — HBM, DMA'd
+    live: jax.Array | None = None,  # i32[nb, 1, bs] tombstone rows — HBM, DMA'd
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Up to ``trips`` fused chunk steps per query in ONE launch: grid over B.
 
-    Returns ``(pool_s, pool_i, theta, processed, trips_done)`` — the state
-    crosses the HBM boundary once per launch instead of once per trip;
-    ``trips_done[b]`` counts how many trip bodies actually ran for row ``b``
-    (the in-kernel early exit stops short of the budget once rank-safe).
+    Returns ``(pool_s [B, k], pool_i [B, k], theta [B], processed [B, NBp],
+    trips_done [B])`` — the state crosses the HBM boundary once per launch;
+    ``trips_done[b]`` counts how many trip bodies actually ran for row ``b``.
+    The ``[budget, bs]`` candidate tile and the selection never leave the
+    chip's VMEM/SMEM. ``live`` (optional) is the lifecycle tombstone bitmap
+    as block rows; like the doc stores it stays in HBM and only the selected
+    blocks' rows are DMA'd.
     """
     B, nbp = ub.shape
     k = pool_s.shape[1]
     lq = q_terms.shape[1]
     tmax = doc_terms.shape[1]
 
-    row = lambda b, *_: (b, 0)  # noqa: E731 — scalar refs trail the index args
+    def row(n):  # one query's row of an [B, 1, n] state array
+        return pl.BlockSpec((None, 1, n), lambda b, *_: (b, 0, 0))
+
     in_specs = [
-        pl.BlockSpec((1, nbp), row),
-        pl.BlockSpec((1, nbp), row),
-        pl.BlockSpec((1, k), row),
-        pl.BlockSpec((1, k), row),
-        pl.BlockSpec((1, 1), row),
-        pl.BlockSpec((1, lq), row),
-        pl.BlockSpec((1, lq), row),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # doc-major store: DMA only
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        row(nbp), row(nbp), row(k), row(k),
+        pl.BlockSpec(memory_space=pl.ANY),  # doc-major store: DMA only
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    args = [
+        q_terms.astype(jnp.int32).reshape(-1),
+        q_weights.astype(jnp.float32).reshape(-1),
+        theta.astype(jnp.float32),
+        trips_left.astype(jnp.int32),
+        ub.reshape(B, 1, nbp),
+        processed.reshape(B, 1, nbp),
+        pool_s.reshape(B, 1, k),
+        pool_i.reshape(B, 1, k),
+        doc_terms,
+        doc_weights,
     ]
     scratch = [
         pltpu.VMEM((2, bs, tmax), jnp.int32),  # double-buffered doc terms
         pltpu.VMEM((2, bs, tmax), jnp.float32),  # double-buffered doc weights
         pltpu.VMEM((budget, bs), jnp.float32),  # candidate score tile
-        pltpu.SemaphoreType.DMA((2, 2)),  # (slot, terms/weights)
+        pltpu.VMEM((bs, tmax), jnp.float32),  # qv * w products
     ]
-    args = [trips_left, ub, processed, pool_s, pool_i, theta, q_terms,
-            q_weights, doc_terms, doc_weights]
+    n_lanes = 2
     if live is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # live rows: DMA only
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # live rows: DMA only
         args.append(live.astype(jnp.int32))
-        scratch.insert(3, pltpu.VMEM((2, 1, bs), jnp.int32))  # live-row buffer
-        scratch[-1] = pltpu.SemaphoreType.DMA((2, 3))  # (slot, terms/weights/live)
+        scratch.append(pltpu.VMEM((2, 1, bs), jnp.int32))  # live-row buffer
+        n_lanes = 3  # (slot, terms/weights/live)
+    scratch += [
+        pltpu.SMEM((1,), jnp.float32),  # running theta
+        pltpu.SMEM((budget,), jnp.int32),  # selected block ids
+        pltpu.SMEM((budget,), jnp.float32),  # their upper bounds
+        pltpu.SemaphoreType.DMA((2, n_lanes)),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), row),
-            pl.BlockSpec((1, k), row),
-            pl.BlockSpec((1, 1), row),
-            pl.BlockSpec((1, nbp), row),
-            pl.BlockSpec((1, 1), row),
-        ],
+        out_specs=[row(k), row(k), row(1), row(nbp), row(1)],
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(
-            _chunk_step_multi_kernel_batched,
-            trips=trips, budget=budget, bs=bs, n_live=n_live,
+            _chunk_step_kernel_batched,
+            trips=trips, lq=lq, budget=budget, bs=bs, n_live=n_live,
             has_live=live is not None,
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, nbp), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, nbp), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(*args)
-    return out[0], out[1], out[2], out[3], out[4]
+    ps, pi, th, pr, td = (o[:, 0] for o in out)
+    return ps, pi, th[:, 0], pr, td[:, 0]

@@ -4,7 +4,7 @@ Handles the engine <-> kernel interface impedance: the processed set is a
 bool bitmap on the engine side but an i32 row inside the kernel (Mosaic has
 no bool VMEM tiles), the block axis is padded to the 128-lane multiple
 (pad lanes carry ``ub = -inf`` / ``processed = 1`` so they can never be
-selected ahead of a real block — ``lax.top_k`` breaks ``-inf`` ties toward
+selected ahead of a real block — the selection breaks ``-inf`` ties toward
 the lowest id, and every real block id sorts before every pad id), and
 interpret-mode selection mirrors the other kernel packages so one call site
 serves CPU tests and TPU deployments.
@@ -17,11 +17,45 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import KernelContract, ShapeCase
-from repro.kernels.chunk_step.kernel import (
-    chunk_step_batched_kernel,
-    chunk_step_multi_batched_kernel,
-)
+from repro.kernels.chunk_step.kernel import chunk_step_batched_kernel
 from repro.kernels.common import interpret_default, pad_axis
+
+
+def _run(doc_terms, doc_weights, q_terms, q_weights, ub, processed, pool_s,
+         pool_i, theta, trips_left, *, trips, block_budget, block_size,
+         n_live, live, interpret):
+    """Shared engine-state plumbing for the one- and multi-trip entry points."""
+    if interpret is None:
+        interpret = interpret_default()
+    B, nb = ub.shape
+    if block_budget > nb:
+        raise ValueError(
+            f"block_budget={block_budget} exceeds n_blocks={nb}; the engine "
+            "clamps budgets before the loop"
+        )
+    ubp = pad_axis(ub.astype(jnp.float32), 1, 128, fill=-jnp.inf)
+    procp = pad_axis(processed.astype(jnp.int32), 1, 128, fill=1)
+    if live is not None:
+        live = live.astype(jnp.int32)[: nb * block_size].reshape(nb, 1, block_size)
+    ps, pi, th, pr, td = chunk_step_batched_kernel(
+        ubp,
+        procp,
+        pool_s.astype(jnp.float32),
+        pool_i.astype(jnp.int32),
+        theta.astype(jnp.float32),
+        q_terms.astype(jnp.int32),
+        jnp.where(q_weights > 0, q_weights.astype(jnp.float32), 0.0),
+        doc_terms,
+        doc_weights,
+        trips_left,
+        trips=trips,
+        budget=block_budget,
+        bs=block_size,
+        n_live=n_live,
+        live=live,
+        interpret=interpret,
+    )
+    return ps, pi, th, pr[:, :nb].astype(jnp.bool_), td
 
 
 @partial(
@@ -49,7 +83,7 @@ def chunk_step_batched(
 
     Args mirror the engine's while-loop state plus the phase-0 products:
       doc_terms/doc_weights: the HBM doc-major store ``[n_docs_pad, Tmax]``.
-      q_terms/q_weights: ``[B, Lq]``; weight-``<=0`` slots must be zeroed.
+      q_terms/q_weights: ``[B, Lq]``; weight-``<=0`` slots are padding.
       ub: ``f32[B, n_blocks]`` additive block upper bounds (phase 0).
       processed: ``bool[B, n_blocks]`` blocks already scored.
       pool_s/pool_i: the current top-k pool ``[B, k]``.
@@ -61,35 +95,16 @@ def chunk_step_batched(
     dtypes to the inputs — a drop-in replacement for the jnp while-body's
     select+score+merge (see :mod:`repro.kernels.chunk_step.ref`).
     """
-    if interpret is None:
-        interpret = interpret_default()
-    B, nb = ub.shape
-    if block_budget > nb:
-        raise ValueError(
-            f"block_budget={block_budget} exceeds n_blocks={nb}; the engine "
-            "clamps budgets before the loop"
-        )
-    ubp = pad_axis(ub.astype(jnp.float32), 1, 128, fill=-jnp.inf)
-    procp = pad_axis(processed.astype(jnp.int32), 1, 128, fill=1)
-    if live is not None:
-        live = live.astype(jnp.int32)[: nb * block_size].reshape(nb, block_size)
-    ps, pi, th, pr = chunk_step_batched_kernel(
-        ubp,
-        procp,
-        pool_s.astype(jnp.float32),
-        pool_i.astype(jnp.int32),
-        theta.astype(jnp.float32).reshape(B, 1),
-        q_terms.astype(jnp.int32),
-        q_weights.astype(jnp.float32),
-        doc_terms,
-        doc_weights,
-        budget=block_budget,
-        bs=block_size,
-        n_live=n_live,
-        live=live,
-        interpret=interpret,
+    ps, pi, th, pr, td = _run(
+        doc_terms, doc_weights, q_terms, q_weights, ub, processed, pool_s,
+        pool_i, theta, jnp.ones((ub.shape[0],), jnp.int32), trips=1,
+        block_budget=block_budget, block_size=block_size, n_live=n_live,
+        live=live, interpret=interpret,
     )
-    return ps, pi, th[:, 0], pr[:, :nb].astype(jnp.bool_)
+    # a row with no remaining block above theta skips the trip in-kernel; the
+    # step it skipped would only have reset theta to the pool's k-th score
+    th = jnp.where(td > 0, th, ps[:, -1])
+    return ps, pi, th, pr
 
 
 @partial(
@@ -127,39 +142,14 @@ def chunk_step_multi_batched(
     sequential trips (the in-kernel early exit stops a row once rank-safe)
     and the per-row count of trips that actually advanced.
     """
-    if interpret is None:
-        interpret = interpret_default()
     if trips_per_launch < 1:
         raise ValueError(f"trips_per_launch={trips_per_launch} must be >= 1")
-    B, nb = ub.shape
-    if block_budget > nb:
-        raise ValueError(
-            f"block_budget={block_budget} exceeds n_blocks={nb}; the engine "
-            "clamps budgets before the loop"
-        )
-    ubp = pad_axis(ub.astype(jnp.float32), 1, 128, fill=-jnp.inf)
-    procp = pad_axis(processed.astype(jnp.int32), 1, 128, fill=1)
-    if live is not None:
-        live = live.astype(jnp.int32)[: nb * block_size].reshape(nb, block_size)
-    ps, pi, th, pr, td = chunk_step_multi_batched_kernel(
-        ubp,
-        procp,
-        pool_s.astype(jnp.float32),
-        pool_i.astype(jnp.int32),
-        theta.astype(jnp.float32).reshape(B, 1),
-        q_terms.astype(jnp.int32),
-        q_weights.astype(jnp.float32),
-        doc_terms,
-        doc_weights,
-        trips_left.astype(jnp.int32),
-        trips=trips_per_launch,
-        budget=block_budget,
-        bs=block_size,
-        n_live=n_live,
-        live=live,
-        interpret=interpret,
+    return _run(
+        doc_terms, doc_weights, q_terms, q_weights, ub, processed, pool_s,
+        pool_i, theta, trips_left.astype(jnp.int32), trips=trips_per_launch,
+        block_budget=block_budget, block_size=block_size, n_live=n_live,
+        live=live, interpret=interpret,
     )
-    return ps, pi, th[:, 0], pr[:, :nb].astype(jnp.bool_), td[:, 0]
 
 
 def _contract_call(dims):

@@ -2,16 +2,18 @@
 
 This is the batched DAAT engine's phase-2 while-body, verbatim: the exact
 selection (``lax.top_k`` over the masked ub row), the exact ``score_blocks``
-gather-reduce through a dense query vector, and the exact ``merge_topk``
-pool+candidates concatenation. The fused kernel must be indistinguishable
-from this function in doc ids, theta, and the processed bitmap (bitwise),
-and in scores to f32 reassociation — which is exactly the engine-level
-``fused_chunk`` parity contract.
+gather-reduce through a dense query vector (summed in the fixed order of
+``tree_sum_last``), and the exact ``merge_topk`` pool+candidates
+concatenation. The fused kernel must be indistinguishable from this function
+in doc ids, scores, theta, and the processed bitmap (bitwise) — which is
+exactly the engine-level ``fused_chunk`` parity contract.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.common import tree_sum_last
 
 
 def chunk_step_batched_ref(
@@ -50,7 +52,7 @@ def chunk_step_batched_ref(
     terms = doc_terms[docs]  # [B, budget, bs, Tmax]
     w = doc_weights[docs]
     qv = qvec[rows[..., None, None], terms]
-    s_c = jnp.sum(qv * w, axis=-1)
+    s_c = tree_sum_last(qv * w)
     s_c = jnp.where(docs < n_live, s_c, -jnp.inf)
     s_c = jnp.where(live[..., None], s_c, -jnp.inf)
 

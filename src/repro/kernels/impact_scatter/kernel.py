@@ -1,23 +1,24 @@
-"""Pallas TPU kernel: SAAT accumulator scatter-add as one-hot matmul.
+"""Pallas TPU kernel: SAAT accumulator scatter-add as a VMEM one-hot.
 
 TPUs have no fast random scatter; the idiomatic translation is a *one-hot
-matmul*: for a VMEM tile of postings ``(doc_ids[TP], contribs[TP])`` and an
+contraction*: for a VMEM tile of postings ``(doc_ids[TP], vals[TP])`` and an
 accumulator block of ``BD`` documents, the partial update is
 
-    acc[BD] += onehot(doc_ids - block_start)[BD, TP] @ contribs[TP, 1]
+    acc[blk, :] += sum_t where(iota[BD, TP] == doc_ids - blk * BD, vals, 0)
 
-which runs on the MXU. The grid is (doc_blocks x posting_tiles); the
-accumulator block stays resident in VMEM across the inner posting-tile loop
-(output revisiting), so HBM traffic is one read of the postings plus one
-write of the accumulator.
+The grid is (query, posting tile); the query's whole ``[n_blocks, BD]``
+accumulator stays resident in VMEM across the tile axis (output revisiting),
+so HBM traffic is one read of the postings plus one write of the
+accumulator. The ops wrapper sorts postings by doc id, so a tile only spans
+the blocks between its smallest and largest doc — the kernel loops over
+exactly those blocks (``fori_loop`` with data-dependent bounds) instead of
+visiting every (block, tile) pair.
 
-Skip optimization (the SAAT analogue of postings being doc-sorted inside a
-segment): when the caller pre-sorts postings by doc id it also passes per-tile
-[min_doc, max_doc+1) ranges; tiles that do not overlap the current accumulator
-block skip the matmul entirely via ``pl.when``. For contribution-ordered
-(unsorted) postings the ranges degenerate to [0, n_docs) and every (block,
-tile) cell does work — correct, just slower, mirroring CPU JASS where the
-accumulator table absorbs the random access.
+Exactness: the wrapper has already folded each document's postings into ONE
+nonzero value (:func:`repro.kernels.common.canonical_doc_sums`), so every
+accumulator cell receives at most one nonzero addend — the select-and-sum is
+exact in any order, and the accumulator is bit-identical to XLA's scatter of
+the same values.
 """
 from __future__ import annotations
 
@@ -28,79 +29,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _scatter_kernel(ranges_ref, docs_ref, contribs_ref, acc_ref, *, block_d: int):
-    d = pl.program_id(0)
-    t = pl.program_id(1)
+def accumulate_tile(docs, vals, acc_ref, *, block_d: int) -> None:
+    """Add one doc-sorted posting tile ``(1, TP)`` into ``acc_ref [nb, BD]``."""
+    tp = docs.shape[1]
+    lo = jnp.min(docs) // block_d
+    hi = jnp.max(docs) // block_d
 
-    @pl.when(t == 0)
+    def one_block(blk, carry):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_d, tp), 0) + blk * block_d
+        part = jnp.sum(jnp.where(rows == docs, vals, 0.0), axis=1)  # f32[BD]
+        acc_ref[pl.ds(blk, 1), :] += part[None, :]
+        return carry
+
+    jax.lax.fori_loop(lo, hi + 1, one_block, 0)
+
+
+def _scatter_kernel(docs_ref, vals_ref, acc_ref, *, block_d: int):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    block_start = d * block_d
-    tile_lo = ranges_ref[0, 0]
-    tile_hi = ranges_ref[0, 1]
-    overlaps = (tile_lo < block_start + block_d) & (tile_hi > block_start)
-
-    @pl.when(overlaps)
-    def _accumulate():
-        docs = docs_ref[0, :]  # i32[TP]
-        c = contribs_ref[0, :]  # f32[TP]
-        local = docs - block_start
-        bd = acc_ref.shape[1]
-        tp = docs.shape[0]
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (bd, tp), 0)
-        onehot = (row_ids == local[None, :]).astype(jnp.float32)
-        partial = jnp.dot(onehot, c[:, None], preferred_element_type=jnp.float32)  # [BD, 1]
-        acc_ref[0, :] += partial[:, 0]
-
-
-def _scatter_kernel_batched(ranges_ref, docs_ref, contribs_ref, acc_ref, *, block_d: int):
-    d = pl.program_id(1)
-    t = pl.program_id(2)
-
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    block_start = d * block_d
-    tile_lo = ranges_ref[0, 0, 0]
-    tile_hi = ranges_ref[0, 0, 1]
-    overlaps = (tile_lo < block_start + block_d) & (tile_hi > block_start)
-
-    @pl.when(overlaps)
-    def _accumulate():
-        docs = docs_ref[0, 0, :]  # i32[TP]
-        c = contribs_ref[0, 0, :]  # f32[TP]
-        local = docs - block_start
-        bd = acc_ref.shape[2]
-        tp = docs.shape[0]
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (bd, tp), 0)
-        onehot = (row_ids == local[None, :]).astype(jnp.float32)
-        partial = jnp.dot(onehot, c[:, None], preferred_element_type=jnp.float32)  # [BD, 1]
-        acc_ref[0, 0, :] += partial[:, 0]
+    accumulate_tile(docs_ref[...], vals_ref[...], acc_ref, block_d=block_d)
 
 
 def impact_scatter_batched_kernel(
     doc_ids: jax.Array,
-    contribs: jax.Array,
-    tile_ranges: jax.Array,
+    vals: jax.Array,
     *,
     n_docs: int,
     block_d: int = 512,
     tile_p: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Batched scatter-add: one grid axis over queries, then (blocks x tiles).
-
-    The per-query accumulator block is revisited across the innermost tile
-    axis exactly as in the single-query kernel, so VMEM residency and the
-    skip-range optimization carry over unchanged; queries never share an
-    accumulator, so no cross-query reduction is needed.
+    """Batched scatter-add: grid over (query, posting tile).
 
     Args:
-      doc_ids: i32[B, P], P % tile_p == 0, values in [0, n_docs).
-      contribs: f32[B, P].
-      tile_ranges: i32[B, P // tile_p, 2] per-(query, tile) doc-id bounds.
+      doc_ids: i32[B, P] sorted per row, P % tile_p == 0, values in [0, n_docs).
+      vals: f32[B, P], at most one nonzero per (row, doc).
       n_docs: accumulator length; must be % block_d == 0.
 
     Returns:
@@ -111,64 +76,18 @@ def impact_scatter_batched_kernel(
     assert n_docs % block_d == 0, (n_docs, block_d)
     n_tiles = P // tile_p
     n_blocks = n_docs // block_d
-
-    grid = (B, n_blocks, n_tiles)
-    docs3d = doc_ids.reshape(B, n_tiles, tile_p)
-    c3d = contribs.astype(jnp.float32).reshape(B, n_tiles, tile_p)
-
-    out = pl.pallas_call(
-        functools.partial(_scatter_kernel_batched, block_d=block_d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 2), lambda b, d, t: (b, t, 0)),
-            pl.BlockSpec((1, 1, tile_p), lambda b, d, t: (b, t, 0)),
-            pl.BlockSpec((1, 1, tile_p), lambda b, d, t: (b, t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_d), lambda b, d, t: (b, d, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, n_blocks, block_d), jnp.float32),
-        interpret=interpret,
-    )(tile_ranges, docs3d, c3d)
-    return out.reshape(B, n_docs)
-
-
-def impact_scatter_kernel(
-    doc_ids: jax.Array,
-    contribs: jax.Array,
-    tile_ranges: jax.Array,
-    *,
-    n_docs: int,
-    block_d: int = 512,
-    tile_p: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Scatter-add ``contribs`` into a dense accumulator. See module docstring.
-
-    Args:
-      doc_ids: i32[P], P % tile_p == 0, values in [0, n_docs).
-      contribs: f32[P].
-      tile_ranges: i32[P // tile_p, 2] per-tile [min_doc, max_doc+1) bounds.
-      n_docs: accumulator length; must be % block_d == 0.
-    """
-    P = doc_ids.shape[0]
-    assert P % tile_p == 0, (P, tile_p)
-    assert n_docs % block_d == 0, (n_docs, block_d)
-    n_tiles = P // tile_p
-    n_blocks = n_docs // block_d
-
-    grid = (n_blocks, n_tiles)
-    docs2d = doc_ids.reshape(n_tiles, tile_p)
-    c2d = contribs.astype(jnp.float32).reshape(n_tiles, tile_p)
-
+    # a unit axis makes each (query, tile) block's last two dims equal the
+    # array's, which Mosaic accepts for any tile width
+    tile = pl.BlockSpec((None, None, 1, tile_p), lambda b, t: (b, t, 0, 0))
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, block_d=block_d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda d, t: (t, 0)),
-            pl.BlockSpec((1, tile_p), lambda d, t: (t, 0)),
-            pl.BlockSpec((1, tile_p), lambda d, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda d, t: (d, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, block_d), jnp.float32),
+        grid=(B, n_tiles),
+        in_specs=[tile, tile],
+        out_specs=pl.BlockSpec((None, n_blocks, block_d), lambda b, t: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n_blocks, block_d), jnp.float32),
         interpret=interpret,
-    )(tile_ranges, docs2d, c2d)
-    return out.reshape(n_docs)
+    )(
+        doc_ids.reshape(B, n_tiles, 1, tile_p),
+        vals.astype(jnp.float32).reshape(B, n_tiles, 1, tile_p),
+    )
+    return out.reshape(B, n_docs)

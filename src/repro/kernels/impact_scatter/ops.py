@@ -1,8 +1,9 @@
 """jit'd wrapper around the impact-scatter Pallas kernel.
 
-Handles padding, the optional doc-sort (which enables the kernel's
-(block x tile) skip ranges), and interpret-mode selection so the same call
-site works on CPU tests and TPU deployments.
+Handles the doc-sort + canonical per-document fold (which make the kernel's
+one-hot exact and let each tile visit only the blocks it spans), padding,
+and interpret-mode selection so the same call site works on CPU tests and
+TPU deployments.
 """
 from __future__ import annotations
 
@@ -13,52 +14,12 @@ import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import KernelContract, ShapeCase
 from repro.kernels.common import interpret_default, round_up, sorted_posting_tiles
-from repro.kernels.impact_scatter.kernel import (
-    impact_scatter_batched_kernel,
-    impact_scatter_kernel,
-)
+from repro.kernels.impact_scatter.kernel import impact_scatter_batched_kernel
 
 
 @partial(
     jax.jit,
-    static_argnames=("n_docs", "block_d", "tile_p", "sort_by_doc", "interpret"),
-)
-def impact_scatter(
-    doc_ids: jax.Array,
-    contribs: jax.Array,
-    n_docs: int,
-    *,
-    block_d: int = 512,
-    tile_p: int = 512,
-    sort_by_doc: bool = True,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """acc[d] = sum of contribs with doc_id == d, via the Pallas kernel.
-
-    ``sort_by_doc=True`` sorts postings by doc id first so each posting tile
-    covers a narrow doc range and the kernel skips non-overlapping accumulator
-    blocks — turning the O(blocks x tiles) grid into an effectively linear
-    pass. The sort itself is a standard XLA sort (fused, HBM-bandwidth bound).
-    """
-    if interpret is None:
-        interpret = interpret_default()
-    n_docs_pad = round_up(max(n_docs, block_d), block_d)
-    docs, c, ranges, _ = sorted_posting_tiles(doc_ids, contribs, n_docs_pad, tile_p, sort_by_doc)
-    acc = impact_scatter_kernel(
-        docs,
-        c,
-        ranges,
-        n_docs=n_docs_pad,
-        block_d=block_d,
-        tile_p=tile_p,
-        interpret=interpret,
-    )
-    return acc[:n_docs]
-
-
-@partial(
-    jax.jit,
-    static_argnames=("n_docs", "block_d", "tile_p", "sort_by_doc", "interpret"),
+    static_argnames=("n_docs", "block_d", "tile_p", "max_run", "interpret"),
 )
 def impact_scatter_batched(
     doc_ids: jax.Array,
@@ -67,31 +28,49 @@ def impact_scatter_batched(
     *,
     block_d: int = 512,
     tile_p: int = 512,
-    sort_by_doc: bool = True,
+    max_run: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """acc[b, d] = sum of contribs[b] with doc_ids[b] == d, natively batched.
 
     The whole batch runs as ONE kernel launch with a grid axis over queries —
-    the batched SAAT engine's hot loop. ``sort_by_doc=True`` applies a single
-    batched argsort along the posting axis so each (query, tile) covers a
-    narrow doc range and the kernel skips non-overlapping accumulator blocks,
-    exactly as in the single-query path.
+    the batched SAAT engine's hot loop. Each document's contributions are
+    summed in the canonical order of
+    :func:`repro.kernels.common.canonical_doc_sums` (stable doc sort, then a
+    segmented doubling scan); ``max_run`` bounds how many postings one
+    document can have in a row (the engines pass the query width; ``None``
+    assumes the whole row).
     """
     if interpret is None:
         interpret = interpret_default()
     n_docs_pad = round_up(max(n_docs, block_d), block_d)
-    docs, c, ranges, _ = sorted_posting_tiles(doc_ids, contribs, n_docs_pad, tile_p, sort_by_doc)
+    docs, c, _ = sorted_posting_tiles(doc_ids, contribs, tile_p, max_run)
     acc = impact_scatter_batched_kernel(
-        docs,
-        c,
-        ranges,
-        n_docs=n_docs_pad,
-        block_d=block_d,
-        tile_p=tile_p,
+        docs, c, n_docs=n_docs_pad, block_d=block_d, tile_p=tile_p,
         interpret=interpret,
     )
     return acc[:, :n_docs]
+
+
+@partial(
+    jax.jit,
+    static_argnames=("n_docs", "block_d", "tile_p", "max_run", "interpret"),
+)
+def impact_scatter(
+    doc_ids: jax.Array,
+    contribs: jax.Array,
+    n_docs: int,
+    *,
+    block_d: int = 512,
+    tile_p: int = 512,
+    max_run: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """acc[d] = sum of contribs with doc_id == d: the batched op at B = 1."""
+    return impact_scatter_batched(
+        doc_ids[None], contribs[None], n_docs, block_d=block_d, tile_p=tile_p,
+        max_run=max_run, interpret=interpret,
+    )[0]
 
 
 def _contract_call(dims):
@@ -99,7 +78,7 @@ def _contract_call(dims):
     sds = jax.ShapeDtypeStruct
     kw = dict(
         n_docs=dims["n_docs"], block_d=dims["block_d"], tile_p=dims["tile_p"],
-        sort_by_doc=True, interpret=True,
+        interpret=True,
     )
     if "batch" in dims:
         shape = (dims["batch"], dims["n_postings"])

@@ -3,13 +3,18 @@
 The unfused SAAT pipeline writes the full ``[B, n_docs]`` accumulator to HBM
 (``impact_scatter_batched``) and immediately reads it back for top-k — twice
 the accumulator's worth of HBM traffic for a result that is only ``k`` entries
-wide. This kernel fuses the selection into the scatter's output-revisiting
-loop: the accumulator *block* lives in VMEM scratch, is revisited across the
-posting-tile grid axis exactly as in ``impact_scatter``, and at the LAST tile
-the kernel runs ``jax.lax.top_k`` over the finished block and emits only that
-block's ``k`` best candidates (ids globalized to document space, scores f32).
-What crosses the HBM boundary is the candidate pool ``[B, n_blocks * k]`` —
-never the accumulator.
+wide. This kernel fuses the selection into the scatter: the query's
+accumulator lives in VMEM scratch, is filled tile by tile exactly as in
+``impact_scatter``, and after the LAST tile the kernel selects each
+accumulator block's ``k`` best candidates (ids globalized to document space,
+scores f32). What crosses the HBM boundary is the candidate pool
+``[B, n_blocks, k]`` — never the accumulator.
+
+Selection is a repeated row-wise max with a lowest-index argmax (Mosaic has
+no ``top_k`` lowering): ``k`` passes over 8 accumulator blocks at a time,
+each taking the largest untaken value and, among equal values, the lowest
+doc — exactly ``lax.top_k``'s order, ``-inf`` ties included. When ``k``
+equals the block width the block is emitted whole, in doc order.
 
 Rank safety of the two-stage select: a block of ``block_d`` documents can
 contribute at most ``min(k, block_d)`` entries to the global top-k, so keeping
@@ -24,15 +29,7 @@ engine's ``_mask_pad_docs`` + ``topk`` semantics. The index lifecycle's
 tombstone bitmap rides the same gate: an optional ``[n_blocks, block_d]``
 i32 live input (nonzero = live, i32 because Mosaic has no bool VMEM tiles)
 is ANDed into the pad mask at selection time, so deleted documents score
-``-inf`` without touching the accumulation — and therefore without
-perturbing the surviving docs' bit-exact f32 sums. Masking only at select
-(not during accumulate) is what keeps the candidate pool rank-safe AND
-bit-identical to the unfused engine's masked accumulator.
-
-The skip-range optimization carries over unchanged from ``impact_scatter``:
-per-(query, tile) [min_doc, max_doc+1) bounds let non-overlapping (block,
-tile) cells skip the one-hot matmul; the t==last selection step still runs so
-every block emits its candidates.
+``-inf`` without touching the accumulation.
 """
 from __future__ import annotations
 
@@ -43,11 +40,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.impact_scatter.kernel import accumulate_tile
+
+ROWS = 8  # accumulator blocks selected per pass: one f32 sublane tile
+
+
+def select_topk_rows(x: jax.Array, ids: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """Row-wise top-k of ``x [R, W]`` in ``lax.top_k`` order, built for Mosaic.
+
+    Each of ``k`` passes takes the largest untaken value per row and, among
+    equal values, the lowest column (so ``-inf`` entries come out in column
+    order once the finite ones are exhausted). Returns ``(values [R, k],
+    ids[row, column] [R, k])``. Requires ``k <= W``.
+    """
+    R, W = x.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (R, k), 1)
+
+    def one(i, carry):
+        taken, vals, out_ids = carry
+        avail = taken == 0
+        m = jnp.max(jnp.where(avail, x, -jnp.inf), axis=1, keepdims=True)
+        pos = jnp.min(jnp.where(avail & (x == m), col, W), axis=1, keepdims=True)
+        sel = col == pos
+        picked = jnp.sum(jnp.where(sel, ids, 0), axis=1, keepdims=True)
+        taken = jnp.where(sel, 1, taken)
+        vals = jnp.where(kcol == i, m, vals)
+        out_ids = jnp.where(kcol == i, picked, out_ids)
+        return taken, vals, out_ids
+
+    init = (
+        jnp.zeros((R, W), jnp.int32),
+        jnp.full((R, k), -jnp.inf, jnp.float32),
+        jnp.zeros((R, k), jnp.int32),
+    )
+    _, vals, out_ids = jax.lax.fori_loop(0, k, one, init)
+    return vals, out_ids
+
 
 def _scatter_topk_kernel(
-    ranges_ref,
     docs_ref,
-    contribs_ref,
+    vals_ref,
     *rest,
     block_d: int,
     n_tiles: int,
@@ -62,174 +95,42 @@ def _scatter_topk_kernel(
     else:
         live_ref = None
         out_s_ref, out_i_ref, acc_ref = rest
-    d = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    block_start = d * block_d
-    tile_lo = ranges_ref[0, 0]
-    tile_hi = ranges_ref[0, 1]
-    overlaps = (tile_lo < block_start + block_d) & (tile_hi > block_start)
-
-    @pl.when(overlaps)
-    def _accumulate():
-        docs = docs_ref[0, :]  # i32[TP]
-        c = contribs_ref[0, :]  # f32[TP]
-        local = docs - block_start
-        bd = acc_ref.shape[1]
-        tp = docs.shape[0]
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (bd, tp), 0)
-        onehot = (row_ids == local[None, :]).astype(jnp.float32)
-        partial = jnp.dot(onehot, c[:, None], preferred_element_type=jnp.float32)
-        acc_ref[0, :] += partial[:, 0]
+    accumulate_tile(docs_ref[...], vals_ref[...], acc_ref, block_d=block_d)
 
     @pl.when(t == n_tiles - 1)
     def _select():
-        k = out_s_ref.shape[1]
-        # 2-D iota: Mosaic rejects 1-D iota on real TPUs (same convention as
-        # the scatter kernels' broadcasted_iota row ids)
-        gid = block_start + jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
-        mask = gid < n_live
-        if live_ref is not None:
-            mask = mask & (live_ref[...] != 0)
-        scores = jnp.where(mask, acc_ref[...], -jnp.inf)
-        s, i = jax.lax.top_k(scores[0], k)
-        out_s_ref[0, :] = s
-        out_i_ref[0, :] = i.astype(jnp.int32) + block_start
+        n_blocks, k = out_s_ref.shape
 
+        def rows(g, carry):
+            r0 = pl.multiple_of(g * ROWS, ROWS)
+            sl = pl.ds(r0, ROWS)
+            gid = (
+                jax.lax.broadcasted_iota(jnp.int32, (ROWS, block_d), 0) + r0
+            ) * block_d + jax.lax.broadcasted_iota(jnp.int32, (ROWS, block_d), 1)
+            mask = gid < n_live
+            if live_ref is not None:
+                mask = mask & (live_ref[sl, :] != 0)
+            scores = jnp.where(mask, acc_ref[sl, :], -jnp.inf)
+            if k == block_d:  # the whole block survives: emit it in doc order
+                s, i = scores, gid
+            else:
+                s, i = select_topk_rows(scores, gid, k)
+            out_s_ref[sl, :] = s
+            out_i_ref[sl, :] = i
+            return carry
 
-def _scatter_topk_kernel_batched(
-    ranges_ref,
-    docs_ref,
-    contribs_ref,
-    *rest,
-    block_d: int,
-    n_tiles: int,
-    n_live: int,
-    has_live: bool = False,
-):
-    if has_live:
-        live_ref, out_s_ref, out_i_ref, acc_ref = rest
-    else:
-        live_ref = None
-        out_s_ref, out_i_ref, acc_ref = rest
-    d = pl.program_id(1)
-    t = pl.program_id(2)
-
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    block_start = d * block_d
-    tile_lo = ranges_ref[0, 0, 0]
-    tile_hi = ranges_ref[0, 0, 1]
-    overlaps = (tile_lo < block_start + block_d) & (tile_hi > block_start)
-
-    @pl.when(overlaps)
-    def _accumulate():
-        docs = docs_ref[0, 0, :]  # i32[TP]
-        c = contribs_ref[0, 0, :]  # f32[TP]
-        local = docs - block_start
-        bd = acc_ref.shape[1]
-        tp = docs.shape[0]
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (bd, tp), 0)
-        onehot = (row_ids == local[None, :]).astype(jnp.float32)
-        partial = jnp.dot(onehot, c[:, None], preferred_element_type=jnp.float32)
-        acc_ref[0, :] += partial[:, 0]
-
-    @pl.when(t == n_tiles - 1)
-    def _select():
-        k = out_s_ref.shape[2]
-        # 2-D iota: Mosaic rejects 1-D iota on real TPUs
-        gid = block_start + jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
-        mask = gid < n_live
-        if live_ref is not None:
-            mask = mask & (live_ref[...] != 0)
-        scores = jnp.where(mask, acc_ref[...], -jnp.inf)
-        s, i = jax.lax.top_k(scores[0], k)
-        out_s_ref[0, 0, :] = s
-        out_i_ref[0, 0, :] = i.astype(jnp.int32) + block_start
-
-
-def impact_scatter_topk_kernel(
-    doc_ids: jax.Array,
-    contribs: jax.Array,
-    tile_ranges: jax.Array,
-    *,
-    n_docs: int,
-    n_live: int,
-    k: int,
-    block_d: int = 512,
-    tile_p: int = 512,
-    live: jax.Array | None = None,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused scatter → per-block top-k for one query. See module docstring.
-
-    Args:
-      doc_ids: i32[P], P % tile_p == 0, values in [0, n_docs).
-      contribs: f32[P].
-      tile_ranges: i32[P // tile_p, 2] per-tile [min_doc, max_doc+1) bounds.
-      n_docs: accumulator length; must be % block_d == 0.
-      n_live: real document count; ids >= n_live are masked to -inf.
-      k: candidates kept per accumulator block; must be <= block_d.
-      live: optional i32[n_docs] tombstone bitmap (nonzero = live), ANDed
-        into the pad mask at selection time.
-
-    Returns:
-      (cand_scores f32[n_blocks, k], cand_ids i32[n_blocks, k]) — the only
-      arrays that leave VMEM; the accumulator never reaches HBM.
-    """
-    P = doc_ids.shape[0]
-    assert P % tile_p == 0, (P, tile_p)
-    assert n_docs % block_d == 0, (n_docs, block_d)
-    assert 0 < k <= block_d, (k, block_d)
-    n_tiles = P // tile_p
-    n_blocks = n_docs // block_d
-
-    grid = (n_blocks, n_tiles)
-    docs2d = doc_ids.reshape(n_tiles, tile_p)
-    c2d = contribs.astype(jnp.float32).reshape(n_tiles, tile_p)
-
-    in_specs = [
-        pl.BlockSpec((1, 2), lambda d, t: (t, 0)),
-        pl.BlockSpec((1, tile_p), lambda d, t: (t, 0)),
-        pl.BlockSpec((1, tile_p), lambda d, t: (t, 0)),
-    ]
-    inputs = [tile_ranges, docs2d, c2d]
-    if live is not None:
-        assert live.shape == (n_docs,), (live.shape, n_docs)
-        in_specs.append(pl.BlockSpec((1, block_d), lambda d, t: (d, 0)))
-        inputs.append(live.astype(jnp.int32).reshape(n_blocks, block_d))
-
-    out_s, out_i = pl.pallas_call(
-        functools.partial(
-            _scatter_topk_kernel, block_d=block_d, n_tiles=n_tiles,
-            n_live=n_live, has_live=live is not None,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), lambda d, t: (d, 0)),
-            pl.BlockSpec((1, k), lambda d, t: (d, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, k), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        interpret=interpret,
-    )(*inputs)
-    return out_s, out_i
+        jax.lax.fori_loop(0, n_blocks // ROWS, rows, 0)
 
 
 def impact_scatter_topk_batched_kernel(
     doc_ids: jax.Array,
-    contribs: jax.Array,
-    tile_ranges: jax.Array,
+    vals: jax.Array,
     *,
     n_docs: int,
     n_live: int,
@@ -239,58 +140,52 @@ def impact_scatter_topk_batched_kernel(
     live: jax.Array | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Batched fused scatter → per-block top-k: grid over (query, block, tile).
+    """Batched fused scatter → per-block top-k: grid over (query, tile).
 
     Args:
-      doc_ids: i32[B, P], P % tile_p == 0, values in [0, n_docs).
-      contribs: f32[B, P].
-      tile_ranges: i32[B, P // tile_p, 2] per-(query, tile) doc-id bounds.
-      n_docs: accumulator length; must be % block_d == 0.
+      doc_ids: i32[B, P] sorted per row, P % tile_p == 0, values in [0, n_docs).
+      vals: f32[B, P], at most one nonzero per (row, doc).
+      n_docs: accumulator length; must be % (8 * block_d) == 0.
       n_live: real document count; ids >= n_live are masked to -inf.
       k: candidates kept per accumulator block; must be <= block_d.
       live: optional i32[n_docs] tombstone bitmap shared by the whole batch.
 
     Returns:
-      (cand_scores f32[B, n_blocks, k], cand_ids i32[B, n_blocks, k]).
+      (cand_scores f32[B, n_blocks, k], cand_ids i32[B, n_blocks, k]) — the
+      only arrays that leave VMEM; the accumulator never reaches HBM.
     """
     B, P = doc_ids.shape
     assert P % tile_p == 0, (P, tile_p)
-    assert n_docs % block_d == 0, (n_docs, block_d)
+    assert n_docs % (ROWS * block_d) == 0, (n_docs, block_d)
     assert 0 < k <= block_d, (k, block_d)
     n_tiles = P // tile_p
     n_blocks = n_docs // block_d
 
-    grid = (B, n_blocks, n_tiles)
-    docs3d = doc_ids.reshape(B, n_tiles, tile_p)
-    c3d = contribs.astype(jnp.float32).reshape(B, n_tiles, tile_p)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, 2), lambda b, d, t: (b, t, 0)),
-        pl.BlockSpec((1, 1, tile_p), lambda b, d, t: (b, t, 0)),
-        pl.BlockSpec((1, 1, tile_p), lambda b, d, t: (b, t, 0)),
+    tile = pl.BlockSpec((None, None, 1, tile_p), lambda b, t: (b, t, 0, 0))
+    in_specs = [tile, tile]
+    inputs = [
+        doc_ids.reshape(B, n_tiles, 1, tile_p),
+        vals.astype(jnp.float32).reshape(B, n_tiles, 1, tile_p),
     ]
-    inputs = [tile_ranges, docs3d, c3d]
     if live is not None:
         assert live.shape == (n_docs,), (live.shape, n_docs)
-        in_specs.append(pl.BlockSpec((1, block_d), lambda b, d, t: (d, 0)))
+        in_specs.append(pl.BlockSpec((n_blocks, block_d), lambda b, t: (0, 0)))
         inputs.append(live.astype(jnp.int32).reshape(n_blocks, block_d))
 
+    cand = pl.BlockSpec((None, n_blocks, k), lambda b, t: (b, 0, 0))
     out_s, out_i = pl.pallas_call(
         functools.partial(
-            _scatter_topk_kernel_batched, block_d=block_d, n_tiles=n_tiles,
+            _scatter_topk_kernel, block_d=block_d, n_tiles=n_tiles,
             n_live=n_live, has_live=live is not None,
         ),
-        grid=grid,
+        grid=(B, n_tiles),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda b, d, t: (b, d, 0)),
-            pl.BlockSpec((1, 1, k), lambda b, d, t: (b, d, 0)),
-        ],
+        out_specs=[cand, cand],
         out_shape=[
             jax.ShapeDtypeStruct((B, n_blocks, k), jnp.float32),
             jax.ShapeDtypeStruct((B, n_blocks, k), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n_blocks, block_d), jnp.float32)],
         interpret=interpret,
     )(*inputs)
     return out_s, out_i

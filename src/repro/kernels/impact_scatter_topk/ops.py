@@ -5,12 +5,12 @@ candidate pools ``[B, n_blocks * k]`` (the only arrays that touch HBM), the
 merge pass (``repro.core.topk.tiled_topk`` over the pool) recovers the exact
 global top-k. Results are bit-identical in doc ids — including ``-inf`` tie
 order — to ``top_k`` over the dense ``impact_scatter`` accumulator, and
-bit-identical in scores to the unfused Pallas scatter (same accumulation
-order per block).
+bit-identical in scores (both accumulate the same canonical per-document
+sums, :func:`repro.kernels.common.canonical_doc_sums`).
 
-Like ``impact_scatter``'s wrappers: padding, the optional doc-sort feeding the
-kernel's (block x tile) skip ranges, and interpret-mode selection are handled
-here so one call site serves CPU tests and TPU deployments.
+Like ``impact_scatter``'s wrappers: the doc-sort and fold, padding, and
+interpret-mode selection are handled here so one call site serves CPU tests
+and TPU deployments.
 """
 from __future__ import annotations
 
@@ -28,8 +28,8 @@ from repro.kernels.common import (
     sorted_posting_tiles,
 )
 from repro.kernels.impact_scatter_topk.kernel import (
+    ROWS,
     impact_scatter_topk_batched_kernel,
-    impact_scatter_topk_kernel,
 )
 
 
@@ -51,9 +51,9 @@ def _merge_pool(
 
 @partial(
     jax.jit,
-    static_argnames=("n_docs", "k", "n_live", "block_d", "tile_p", "sort_by_doc", "interpret"),
+    static_argnames=("n_docs", "k", "n_live", "block_d", "tile_p", "max_run", "interpret"),
 )
-def impact_scatter_topk(
+def impact_scatter_topk_batched(
     doc_ids: jax.Array,
     contribs: jax.Array,
     n_docs: int,
@@ -63,34 +63,32 @@ def impact_scatter_topk(
     live: jax.Array | None = None,
     block_d: int = 512,
     tile_p: int = 512,
-    sort_by_doc: bool = True,
+    max_run: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused top-k of the scatter accumulator for one query.
+    """Batched fused top-k: the batched SAAT engine's ``fused_topk`` hot path.
 
     Equivalent to ``top_k(mask(impact_scatter(doc_ids, contribs, n_docs)), k)``
     with ids >= ``n_live`` masked to ``-inf`` — but the dense accumulator
-    never leaves VMEM. Returns ``(scores, ids)`` of width ``min(k, n_docs)``
-    (the same clamp as ``repro.core.topk.topk``).
-
-    ``live`` is the index lifecycle's tombstone bitmap (i32/bool, length
-    <= the padded accumulator; nonzero = live), ANDed into the pad mask at
-    in-kernel selection time so deleted docs score ``-inf``.
+    never leaves VMEM. One kernel launch grids over (query, tile). Returns
+    ``([B, min(k, n_docs)]`` score/id pairs. ``live`` is the optional
+    tombstone bitmap (i32/bool, length <= the padded accumulator; nonzero =
+    live) shared by the whole batch, ANDed into the pad mask at in-kernel
+    selection time. ``max_run`` is as in ``impact_scatter_batched``.
     """
     if interpret is None:
         interpret = interpret_default()
     if n_live is None:
         n_live = n_docs
-    n_docs_pad = round_up(max(n_docs, block_d), block_d)
+    n_docs_pad = round_up(max(n_docs, block_d), ROWS * block_d)
     k_out = min(k, n_docs)
     k_blk = min(k_out, block_d)  # a block holds at most block_d of the top-k
-    docs, c, ranges, _ = sorted_posting_tiles(doc_ids, contribs, n_docs_pad, tile_p, sort_by_doc)
+    docs, c, _ = sorted_posting_tiles(doc_ids, contribs, tile_p, max_run)
     if live is not None:
         live = pad_axis(live.astype(jnp.int32), 0, n_docs_pad)[:n_docs_pad]
-    cand_s, cand_i = impact_scatter_topk_kernel(
+    cand_s, cand_i = impact_scatter_topk_batched_kernel(
         docs,
         c,
-        ranges,
         n_docs=n_docs_pad,
         n_live=min(n_live, n_docs),
         k=k_blk,
@@ -104,9 +102,9 @@ def impact_scatter_topk(
 
 @partial(
     jax.jit,
-    static_argnames=("n_docs", "k", "n_live", "block_d", "tile_p", "sort_by_doc", "interpret"),
+    static_argnames=("n_docs", "k", "n_live", "block_d", "tile_p", "max_run", "interpret"),
 )
-def impact_scatter_topk_batched(
+def impact_scatter_topk(
     doc_ids: jax.Array,
     contribs: jax.Array,
     n_docs: int,
@@ -116,39 +114,15 @@ def impact_scatter_topk_batched(
     live: jax.Array | None = None,
     block_d: int = 512,
     tile_p: int = 512,
-    sort_by_doc: bool = True,
+    max_run: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Batched fused top-k: the batched SAAT engine's ``fused_topk`` hot path.
-
-    One kernel launch grids over (query, block, tile); per-query accumulator
-    blocks live in VMEM scratch and only the ``[B, n_blocks * k]`` candidate
-    pool reaches HBM. Returns ``([B, min(k, n_docs)]`` score/id pairs.
-    ``live`` is the optional tombstone bitmap, shared by the whole batch.
-    """
-    if interpret is None:
-        interpret = interpret_default()
-    if n_live is None:
-        n_live = n_docs
-    n_docs_pad = round_up(max(n_docs, block_d), block_d)
-    k_out = min(k, n_docs)
-    k_blk = min(k_out, block_d)
-    docs, c, ranges, _ = sorted_posting_tiles(doc_ids, contribs, n_docs_pad, tile_p, sort_by_doc)
-    if live is not None:
-        live = pad_axis(live.astype(jnp.int32), 0, n_docs_pad)[:n_docs_pad]
-    cand_s, cand_i = impact_scatter_topk_batched_kernel(
-        docs,
-        c,
-        ranges,
-        n_docs=n_docs_pad,
-        n_live=min(n_live, n_docs),
-        k=k_blk,
-        block_d=block_d,
-        tile_p=tile_p,
-        live=live,
-        interpret=interpret,
+    """Fused top-k of the scatter accumulator for one query (B = 1)."""
+    s, i = impact_scatter_topk_batched(
+        doc_ids[None], contribs[None], n_docs, k, n_live=n_live, live=live,
+        block_d=block_d, tile_p=tile_p, max_run=max_run, interpret=interpret,
     )
-    return _merge_pool(cand_s, cand_i, k_out)
+    return s[0], i[0]
 
 
 def _contract_call(dims):
@@ -156,7 +130,7 @@ def _contract_call(dims):
     sds = jax.ShapeDtypeStruct
     kw = dict(
         n_docs=dims["n_docs"], k=dims["k"], block_d=dims["block_d"],
-        tile_p=dims["tile_p"], sort_by_doc=True, interpret=True,
+        tile_p=dims["tile_p"], interpret=True,
     )
     live_sds = sds((dims["n_docs"],), jnp.int32) if dims.get("live") else None
     if "batch" in dims:

@@ -7,39 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import KernelContract, ShapeCase
-from repro.kernels.common import interpret_default, pad_axis
-from repro.kernels.sparse_score.kernel import (
-    sparse_score_batched_kernel,
-    sparse_score_kernel,
-)
-
-
-@partial(jax.jit, static_argnames=("block_d", "interpret"))
-def sparse_score(
-    doc_terms: jax.Array,
-    doc_weights: jax.Array,
-    q_terms: jax.Array,
-    q_weights: jax.Array,
-    *,
-    block_d: int = 128,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Scores for N docs vs one query via the Pallas kernel. f32[N].
-
-    Pads N to the doc-block multiple and Lq to the lane width; padded query
-    slots must carry weight 0 (ops enforces it), padded doc rows score 0 and
-    are sliced off.
-    """
-    if interpret is None:
-        interpret = interpret_default()
-    n = doc_terms.shape[0]
-    dt = pad_axis(doc_terms.astype(jnp.int32), 0, block_d, fill=-1)
-    dw = pad_axis(doc_weights.astype(jnp.float32), 0, block_d, fill=0.0)
-    qt = pad_axis(q_terms.astype(jnp.int32), 0, 128, fill=-2)
-    qw = pad_axis(q_weights.astype(jnp.float32), 0, 128, fill=0.0)
-    qw = jnp.where(qt == -2, 0.0, qw)
-    scores = sparse_score_kernel(dt, dw, qt, qw, block_d=block_d, interpret=interpret)
-    return scores[:n]
+from repro.kernels.common import interpret_default, next_pow2, pad_axis
+from repro.kernels.sparse_score.kernel import sparse_score_batched_kernel
 
 
 @partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -55,20 +24,39 @@ def sparse_score_batched(
     """Per-query scores for ``doc_terms [B, N, Tmax]`` vs queries ``[B, Lq]``.
 
     One (query, doc-block)-gridded launch — the DAAT phase-2 chunk scorer.
-    Padding mirrors the single-query wrapper: doc rows to the block multiple
-    with sentinel term -1, query slots to the lane width with sentinel -2 and
-    weight forced to 0. f32[B, N].
+    Doc rows pad to the block multiple and the term axis to a power of two
+    (sentinel term -1, weight 0: both add exactly ``+0.0``); query slots
+    with weight ``<= 0`` are zeroed. f32[B, N].
     """
     if interpret is None:
         interpret = interpret_default()
-    n = doc_terms.shape[1]
+    n, tmax = doc_terms.shape[1:]
     dt = pad_axis(doc_terms.astype(jnp.int32), 1, block_d, fill=-1)
     dw = pad_axis(doc_weights.astype(jnp.float32), 1, block_d, fill=0.0)
-    qt = pad_axis(q_terms.astype(jnp.int32), 1, 128, fill=-2)
-    qw = pad_axis(q_weights.astype(jnp.float32), 1, 128, fill=0.0)
-    qw = jnp.where(qt == -2, 0.0, qw)
-    scores = sparse_score_batched_kernel(dt, dw, qt, qw, block_d=block_d, interpret=interpret)
+    dt = pad_axis(dt, 2, next_pow2(tmax), fill=-1)
+    dw = pad_axis(dw, 2, next_pow2(tmax), fill=0.0)
+    qw = jnp.where(q_weights > 0, q_weights.astype(jnp.float32), 0.0)
+    scores = sparse_score_batched_kernel(
+        dt, dw, q_terms, qw, block_d=block_d, interpret=interpret
+    )
     return scores[:, :n]
+
+
+@partial(jax.jit, static_argnames=("block_d", "interpret"))
+def sparse_score(
+    doc_terms: jax.Array,
+    doc_weights: jax.Array,
+    q_terms: jax.Array,
+    q_weights: jax.Array,
+    *,
+    block_d: int = 128,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Scores for N docs vs one query via the batched op at B = 1. f32[N]."""
+    return sparse_score_batched(
+        doc_terms[None], doc_weights[None], q_terms[None], q_weights[None],
+        block_d=block_d, interpret=interpret,
+    )[0]
 
 
 def _contract_call(dims):

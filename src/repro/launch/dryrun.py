@@ -38,19 +38,6 @@ HBM_BW = 819e9  # bytes/s per chip
 ICI_BW = 50e9  # bytes/s per link (1 active link assumed — conservative)
 
 
-def _mesh_scope(mesh):
-    """Version-tolerant ambient-mesh scope.
-
-    ``jax.set_mesh`` only exists in newer JAX releases; on older ones the
-    ``Mesh`` object itself is the context manager that sets the ambient mesh
-    (which ``repro.distributed.sharding._get_abstract_mesh`` reads back).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def run_cell(arch_id: str, shape_name: str, mesh_name: str) -> dict:
     spec = get_arch(arch_id)
     cell = spec.cells[shape_name]
@@ -69,7 +56,7 @@ def run_cell(arch_id: str, shape_name: str, mesh_name: str) -> dict:
     t0 = time.time()
     try:
         plan = build_cell_plan(spec, shape_name, mesh)
-        with _mesh_scope(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(plan.fn, in_shardings=plan.in_shardings).lower(*plan.args)
             t_lower = time.time() - t0
             compiled = lowered.compile()

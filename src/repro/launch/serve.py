@@ -190,6 +190,9 @@ def main() -> None:
     if args.counters_port is not None and not args.counters:
         ap.error("--counters-port scrapes the counter families; add --counters")
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     corpus = generate_corpus(CorpusConfig(n_docs=args.docs, n_queries=args.queries))
     enc = apply_treatment(corpus, args.model)
     max_q = max(len(t) for t in enc.query_terms)

@@ -40,6 +40,7 @@ from repro.serving.sharded import (  # noqa: F401
     make_bucketed_serve_step,
     make_pod_serve_step,
     make_sharded_serve_step,
+    place_index_stack,
     shard_corpus,
     shard_live_stack,
     stack_indexes,

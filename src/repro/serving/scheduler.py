@@ -48,7 +48,7 @@ import numpy as np
 from repro.core.daat import daat_search_batched, max_blocks_per_term
 from repro.core.impact_index import META_FIELDS, ImpactIndex
 from repro.core.index_handle import IndexHandle
-from repro.core.saat import max_segments_per_term, saat_search
+from repro.core.saat import exact_rho, max_segments_per_term, saat_search
 from repro.metrics.latency import Clock, LatencyStats, SystemClock, summarize_latencies
 from repro.serving.bucketing import bucketize_batch, normalize_buckets, pad_to_width
 
@@ -250,17 +250,35 @@ class AnytimeServer:
     def _bind_main_segment(self):
         """(Re)derive everything that depends on the current main segment:
         the plan bounds (build-time metadata — no device sync) and the rho
-        ladder cap (the exact level IS the main segment's posting count).
-        Called at construction and on every :meth:`swap_index`.
+        ladder cap. The exact level is what one query of the widest Lq
+        bucket can touch (:func:`repro.core.saat.exact_rho`: the sum of that
+        many longest posting lists — one small read of the per-term counts);
+        without buckets the width is unbounded and the level is the main
+        segment's posting count. Called at construction and on every
+        :meth:`swap_index`.
         """
         index = self.handle.main if self.handle is not None else self.index
         self.index = index
         self.max_segs = max_segments_per_term(index)
         self.max_bm = max_blocks_per_term(index)
-        # cap the ladder at the index's own posting count (exact level)
-        exact = index.n_postings
+        self._ladder_width = None
+        if self.lq_buckets is not None and self.handle is None:
+            self._ladder_width = self.lq_buckets[-1]
+        exact = exact_rho(index, self._ladder_width)
         ladder = sorted({min(r, exact) for r in self.cfg.rho_ladder} | {exact})
         self.rho_ladder = tuple(ladder)
+
+    def _served_rho(self, rho: int, lq_bucket: int) -> int:
+        """The budget a SAAT dispatch at ``lq_bucket`` really runs with.
+
+        The exact level is sized for the ladder's width; a batch that
+        overflows the bucket ladder (its own, wider executable) gets the
+        exact budget for ITS width, so the top level stays rank-safe.
+        """
+        w = self._ladder_width
+        if rho == self.rho_ladder[-1] and w is not None and lq_bucket > w:
+            return exact_rho(self.index, lq_bucket)
+        return rho
 
     # -------------------------- index lifecycle ----------------------------
 
@@ -436,21 +454,6 @@ class AnytimeServer:
 
     # ----------------------------- serving --------------------------------
 
-    def _daat_search(self, q_terms: jax.Array, q_weights: jax.Array):
-        return daat_search_batched(
-            self.index,
-            q_terms,
-            q_weights,
-            k=self.cfg.k,
-            est_blocks=self.cfg.daat_est_blocks,
-            block_budget=self.cfg.daat_block_budget,
-            max_bm_per_term=self.max_bm,
-            exact=self.cfg.daat_exact,
-            use_kernels=self.cfg.daat_use_kernels,
-            fused_chunk=self.cfg.daat_fused_chunk,
-            trips_per_launch=self.cfg.daat_trips_per_launch,
-        )
-
     def engine_fn(self, rho: Optional[int] = None):
         """The pure engine dispatch for one executable: ``(qt, qw) -> result``.
 
@@ -469,7 +472,18 @@ class AnytimeServer:
         if self.handle is not None:
             return self._handle_engine(rho)
         if self.cfg.engine == "daat":
-            return self._daat_search
+            return functools.partial(
+                daat_search_batched,
+                self.index,
+                k=self.cfg.k,
+                est_blocks=self.cfg.daat_est_blocks,
+                block_budget=self.cfg.daat_block_budget,
+                max_bm_per_term=self.max_bm,
+                exact=self.cfg.daat_exact,
+                use_kernels=self.cfg.daat_use_kernels,
+                fused_chunk=self.cfg.daat_fused_chunk,
+                trips_per_launch=self.cfg.daat_trips_per_launch,
+            )
         if rho is None:
             rho = self.rho_ladder[-1]
         return functools.partial(
@@ -538,8 +552,9 @@ class AnytimeServer:
                 cfg.daat_fused_chunk, cfg.daat_trips_per_launch,
             )
         else:
+            rho = self.rho_ladder[-1] if rho is None else rho
             statics = (
-                "saat", cfg.k, self.rho_ladder[-1] if rho is None else rho,
+                "saat", cfg.k, self._served_rho(rho, lq_bucket),
                 self.max_segs, cfg.scatter_impl, cfg.fused_topk,
             )
         return statics + self._index_signature() + (int(lq_bucket), int(batch_size))
@@ -611,7 +626,7 @@ class AnytimeServer:
             )
         t0 = self.clock.now()  # bucketize is service cost: keep it timed
         q_terms, q_weights, bucket = self._bucketize(q_terms, q_weights)
-        res = self.engine_fn(rho)(q_terms, q_weights)
+        res = self.engine_fn(self._served_rho(rho, bucket))(q_terms, q_weights)
         jax.block_until_ready(res.scores)
         elapsed = (self.clock.now() - t0) * 1e3
         per_query = elapsed / q_terms.shape[0]

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.daat import daat_search_batched
 from repro.core.impact_index import ImpactIndex, META_FIELDS as _META_FIELDS, build_impact_index
@@ -153,6 +152,20 @@ def stack_indexes(shards: list[ImpactIndex]) -> ImpactIndex:
     stacked["doc_terms"] = jnp.asarray(dt)
     stacked["doc_weights"] = jnp.asarray(dw)
     return ImpactIndex(**stacked, **meta)
+
+
+def place_index_stack(index_stack: ImpactIndex, mesh: Mesh) -> ImpactIndex:
+    """Put each shard of a stacked index on its own device of ``mesh``.
+
+    Every array's leading shard axis is partitioned over the ``model`` axis
+    (replicated over the data axes) with a ``NamedSharding`` — the layout
+    the serve steps' ``P("model")`` in-specs expect, so a call moves no index
+    bytes between devices.
+    """
+    sharding = NamedSharding(mesh, P("model"))
+    data = {k: jax.device_put(v, sharding) for k, v in _index_data_dict(index_stack).items()}
+    meta = {k: getattr(index_stack, k) for k in _META_FIELDS}
+    return ImpactIndex(**data, **meta)
 
 
 def abstract_stacked_index(
@@ -436,9 +449,9 @@ def make_sharded_serve_step(
         )
         return canonical_topk_merge(pool_s, pool_i, k, "model")
 
-    sm = shard_map(
+    sm = jax.shard_map(
         body_live if live_masked else body,
-        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False,
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )
 
     def serve(index_stack: ImpactIndex, q_terms, q_weights, live_stack=None):
@@ -593,9 +606,9 @@ def make_pod_serve_step(
     def body_live(idx_data: dict, live, qt, qw):
         return body(idx_data, qt, qw, live=live)
 
-    sm = shard_map(
+    sm = jax.shard_map(
         body_live if live_masked else body,
-        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False,
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )
 
     def serve(index_stack: ImpactIndex, q_terms, q_weights, live_stack=None):

@@ -89,7 +89,7 @@ def _dma_kernel_jaxpr(wait_before_read: bool):
     f = pl.pallas_call(
         kern,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
         out_shape=_SDS((8, 128), jnp.float32),
         scratch_shapes=[
@@ -146,7 +146,7 @@ def _dst_reuse_kernel_jaxpr(wait_between: bool):
     f = pl.pallas_call(
         kern,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
         out_shape=_SDS((8, 128), jnp.float32),
         scratch_shapes=[

@@ -197,6 +197,36 @@ def test_tiled_topk_k_equals_pool_size():
     assert s.shape == (16,)
 
 
+def _ties_last_first_top_k(real):
+    """``lax.top_k`` with its ties reversed: the highest index first, as an
+    unstable sort may return them."""
+
+    def top_k(x, k):
+        n = x.shape[-1]
+        s, i = real(x[..., ::-1], k)
+        return s, (n - 1 - i).astype(i.dtype)
+
+    return top_k
+
+
+@pytest.mark.parametrize("shape,k", [((4096,), 1000), ((3, 700), 64), ((2, 5, 48), 48)])
+def test_topk_ties_lowest_index_first_whatever_lax_top_k_does(monkeypatch, shape, k):
+    """Equal scores come back in ascending index order even when the
+    backend's ``lax.top_k`` orders ties otherwise (as the TPU compiler's
+    unstable sort lowering of a wide top-k does)."""
+    rng = np.random.default_rng(5)
+    x = rng.integers(0, 6, size=shape).astype(np.float32)  # heavy ties
+    x[..., ::11] = -np.inf
+    monkeypatch.setattr(jax.lax, "top_k", _ties_last_first_top_k(jax.lax.top_k))
+    s, i = topk(jnp.asarray(x), k)
+    want_i = np.argsort(-x, axis=-1, kind="stable")[..., :k]
+    np.testing.assert_array_equal(np.asarray(i), want_i)
+    np.testing.assert_array_equal(np.asarray(s), np.take_along_axis(x, want_i, axis=-1))
+    # the two-stage merge inherits the order
+    ts, ti = tiled_topk(jnp.asarray(x), k, num_tiles=4)
+    np.testing.assert_array_equal(np.asarray(ti), want_i)
+
+
 def test_merge_topk():
     sa = jnp.asarray([9.0, 5.0, 1.0])
     ia = jnp.asarray([1, 2, 3], jnp.int32)

@@ -41,12 +41,14 @@ pytestmark = pytest.mark.kernels
 
 @pytest.mark.parametrize("n_postings", SCATTER_CONTRACT.sweep_values("n_postings", exclude=("batch",)))
 @pytest.mark.parametrize("n_docs", SCATTER_CONTRACT.sweep_values("n_docs", exclude=("batch",)))
-@pytest.mark.parametrize("sort_by_doc", [True, False])
-def test_impact_scatter_sweep(n_postings, n_docs, sort_by_doc):
+@pytest.mark.parametrize("presorted", [True, False])
+def test_impact_scatter_sweep(n_postings, n_docs, presorted):
     rng = np.random.default_rng(n_postings + n_docs)
     docs = jnp.asarray(rng.integers(0, n_docs, n_postings), jnp.int32)
+    if presorted:  # already doc-ordered input: the wrapper's sort is a no-op
+        docs = jnp.sort(docs)
     contribs = jnp.asarray(rng.gamma(2.0, 1.0, n_postings), jnp.float32)
-    got = impact_scatter(docs, contribs, n_docs, block_d=256, tile_p=128, sort_by_doc=sort_by_doc, interpret=True)
+    got = impact_scatter(docs, contribs, n_docs, block_d=256, tile_p=128, interpret=True)
     want = impact_scatter_ref(docs, contribs, n_docs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -70,14 +72,16 @@ def test_impact_scatter_zero_contrib_padding():
 
 @pytest.mark.parametrize("batch", SCATTER_CONTRACT.sweep_values("batch"))
 @pytest.mark.parametrize("n_postings", SCATTER_CONTRACT.sweep_values("n_postings", require=("batch",)))
-@pytest.mark.parametrize("sort_by_doc", [True, False])
-def test_impact_scatter_batched_sweep(batch, n_postings, sort_by_doc):
+@pytest.mark.parametrize("presorted", [True, False])
+def test_impact_scatter_batched_sweep(batch, n_postings, presorted):
     (n_docs,) = SCATTER_CONTRACT.sweep_values("n_docs", require=("batch",))
     rng = np.random.default_rng(batch * 1000 + n_postings)
     docs = jnp.asarray(rng.integers(0, n_docs, (batch, n_postings)), jnp.int32)
+    if presorted:
+        docs = jnp.sort(docs, axis=-1)
     contribs = jnp.asarray(rng.gamma(2.0, 1.0, (batch, n_postings)), jnp.float32)
     got = impact_scatter_batched(
-        docs, contribs, n_docs, block_d=256, tile_p=128, sort_by_doc=sort_by_doc, interpret=True
+        docs, contribs, n_docs, block_d=256, tile_p=128, interpret=True
     )
     want = impact_scatter_batched_ref(docs, contribs, n_docs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -142,19 +146,20 @@ def test_impact_scatter_topk_sweep(n_postings, n_docs, k):
 
 
 @pytest.mark.parametrize("batch", SCATTER_TOPK_CONTRACT.sweep_values("batch"))
-@pytest.mark.parametrize("sort_by_doc", [True, False])
-def test_impact_scatter_topk_batched_sweep(batch, sort_by_doc):
-    """Non-divisible n_docs/tile_p shapes, with and without skip ranges."""
+@pytest.mark.parametrize("presorted", [True, False])
+def test_impact_scatter_topk_batched_sweep(batch, presorted):
+    """Non-divisible n_docs/tile_p shapes, doc-ordered input or not."""
     # 700 % 256 != 0, 1000 % 128 != 0 (the contract's ragged batched case)
     (n_docs,) = SCATTER_TOPK_CONTRACT.sweep_values("n_docs", require=("batch",))
     (n_postings,) = SCATTER_TOPK_CONTRACT.sweep_values("n_postings", require=("batch",))
     (k,) = SCATTER_TOPK_CONTRACT.sweep_values("k", require=("batch",))
     rng = np.random.default_rng(batch * 1000)
     docs = jnp.asarray(rng.integers(0, n_docs, (batch, n_postings)), jnp.int32)
+    if presorted:
+        docs = jnp.sort(docs, axis=-1)
     contribs = jnp.asarray(rng.gamma(2.0, 1.0, (batch, n_postings)), jnp.float32)
     got = impact_scatter_topk_batched(
-        docs, contribs, n_docs, k, block_d=256, tile_p=128,
-        sort_by_doc=sort_by_doc, interpret=True,
+        docs, contribs, n_docs, k, block_d=256, tile_p=128, interpret=True,
     )
     want = impact_scatter_topk_batched_ref(docs, contribs, n_docs, n_docs, k)
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
@@ -234,10 +239,10 @@ def test_impact_scatter_topk_matches_unfused_pallas_bitwise():
 
 
 def test_impact_scatter_topk_single_query_bitwise_with_duplicate_docs():
-    """Fused and unfused single-query wrappers share ONE sort primitive
-    (``sorted_posting_tiles``), so heavy doc-id duplication — where an
-    unstable vs stable sort would permute equal-key payloads and reorder the
-    f32 accumulation — still yields BIT-equal scores."""
+    """Fused and unfused single-query wrappers share ONE canonical fold
+    (``sorted_posting_tiles``), so heavy doc-id duplication — where any
+    backend-chosen addend order would perturb the f32 accumulation — still
+    yields BIT-equal scores."""
     rng = np.random.default_rng(17)
     docs = jnp.asarray(rng.integers(0, 10, 512), jnp.int32)  # ~51 postings/doc
     contribs = jnp.asarray(rng.gamma(2.0, 1.0, 512), jnp.float32)

@@ -406,10 +406,12 @@ def _overload_server(index, *, clock):
 
     Ladder has three levels; only the smallest and the full budget are
     *calibrated* (directly measured) — the middle level exists but was never
-    timed, so the degrade policy must never pick it on faith.
+    timed, so the degrade policy must never pick it on faith. Both fixed
+    levels sit below the exact level of a width-4 bucket.
     """
-    cfg = ServingConfig(k=10, rho_ladder=(200, 1000) + EXACT, lq_buckets=(4,))
+    cfg = ServingConfig(k=10, rho_ladder=(100, 250) + EXACT, lq_buckets=(4,))
     srv = AnytimeServer(index, cfg, clock=clock)
+    assert len(srv.rho_ladder) == 3, srv.rho_ladder
     small, full = srv.rho_ladder[0], srv.rho_ladder[-1]
     srv._bucket_ms.update(
         {
@@ -444,7 +446,7 @@ def test_overload_replay_violates_without_degradation(bm25_index):
     assert [f.rho for f in q.flush_log] == [full] * len(q.flush_log)
     # at max rho, queue-served ids stay bit-identical to direct serving
     ref = AnytimeServer(
-        bm25_index, ServingConfig(k=10, rho_ladder=(200, 1000) + EXACT, lq_buckets=(4,))
+        bm25_index, ServingConfig(k=10, rho_ladder=(100, 250) + EXACT, lq_buckets=(4,))
     )
     direct = ref.search_batch(jnp.asarray(ts[0][None, :]), jnp.asarray(ws[0][None, :]))
     direct_ids = np.asarray(direct.doc_ids)[0]
@@ -471,7 +473,7 @@ def test_overload_replay_degrades_instead_of_violating(bm25_index):
         assert c.rho in (small, full)
     # degraded ids match direct serving at the SAME degraded budget
     ref = AnytimeServer(
-        bm25_index, ServingConfig(k=10, rho_ladder=(200, 1000) + EXACT, lq_buckets=(4,))
+        bm25_index, ServingConfig(k=10, rho_ladder=(100, 250) + EXACT, lq_buckets=(4,))
     )
     direct = ref.search_batch(
         jnp.asarray(ts[0][None, :]), jnp.asarray(ws[0][None, :]), rho=small
@@ -496,7 +498,7 @@ def test_pick_degraded_rho_prefers_largest_calibrated_fit(bm25_index):
     # nothing calibrated at all -> defer to pick_rho's deadline logic
     cold = AnytimeServer(
         bm25_index,
-        ServingConfig(k=10, rho_ladder=(200, 1000) + EXACT, lq_buckets=(4,)),
+        ServingConfig(k=10, rho_ladder=(100, 250) + EXACT, lq_buckets=(4,)),
         clock=SimulatedClock(),
     )
     assert cold.pick_degraded_rho(4, 4, 25.0) == cold.pick_rho(deadline_ms=25.0)

@@ -31,6 +31,26 @@ def test_server_ladder_capped_at_exact(bm25_index):
     assert srv.rho_ladder[0] == 100
 
 
+def test_bucketed_exact_rung_is_bounded_and_exact(bm25_index, bm25_queries):
+    """With Lq buckets the exact level is the widest bucket's reach — the sum
+    of that many longest posting lists, not the whole store — and serving at
+    it still equals exhaustive scoring (and the store-sized level, bitwise)."""
+    qt, qw = bm25_queries
+    L = qt.shape[1]
+    cfg = dict(k=10, rho_ladder=(10**9,), batch_size=8)
+    srv = AnytimeServer(bm25_index, ServingConfig(**cfg, lq_buckets=(L,)))
+    longest = np.sort(np.asarray(bm25_index.term_post_count))[::-1]
+    assert srv.rho_ladder[-1] == int(longest[:L].sum()) < bm25_index.n_postings
+    scores, ids = run_query_stream(srv, qt, qw)
+    ex = exhaustive_search(bm25_index, jnp.asarray(qt), jnp.asarray(qw), k=10)
+    np.testing.assert_allclose(scores, np.asarray(ex.scores), rtol=1e-4, atol=1e-4)
+    full = AnytimeServer(bm25_index, ServingConfig(**cfg))
+    assert full.rho_ladder[-1] == bm25_index.n_postings
+    f_scores, f_ids = run_query_stream(full, qt, qw)
+    np.testing.assert_array_equal(ids, f_ids)
+    np.testing.assert_array_equal(scores, f_scores)
+
+
 def test_deadline_controller_picks_rho(bm25_index, bm25_queries):
     qt, qw = bm25_queries
     srv = AnytimeServer(
