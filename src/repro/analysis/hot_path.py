@@ -237,7 +237,8 @@ def lint_server(
             for rho in dict.fromkeys(rhos):
                 case = f"lq{bucket}_b{B}" + ("" if rho is None else f"_rho{rho}")
                 vs, fp = lint_trace(
-                    server.engine_fn(rho), _query_structs(B, bucket), label, case
+                    server.engine_fn(server.served_rho(rho, bucket)),
+                    _query_structs(B, bucket), label, case,
                 )
                 out.extend(vs)
                 if fp is None:

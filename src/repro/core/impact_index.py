@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quantization import QuantConfig, dequantize, quantize
-from repro.kernels.common import next_pow2
 
 
 def _pad_to(x: np.ndarray, n: int, fill) -> np.ndarray:
@@ -45,15 +44,15 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-# The doc-major store is never narrower than one 128-lane tile. A TPU lays a
-# narrower i32/f32 array out padded to 128 lanes in HBM anyway, so the floor
-# costs the chip no bytes; and Mosaic DMAs a block of the store's rows (the
-# fused DAAT chunk step) only when its width is a whole lane tile.
-DOC_STORE_MIN_WIDTH = 128
+# The doc-major store's width is a whole number of 128-lane tiles. Mosaic
+# DMAs a block of the store's rows (the fused DAAT chunk step) only when its
+# width is a whole lane tile, and a TPU lays a narrower i32/f32 array out
+# padded to 128 lanes in HBM anyway, so the rounding costs the chip no bytes.
+DOC_STORE_LANES = 128
 
 
 def _store_width(max_doc_terms: int) -> int:
-    return next_pow2(max(max_doc_terms, DOC_STORE_MIN_WIDTH))
+    return _round_up(max(max_doc_terms, 1), DOC_STORE_LANES)
 
 
 # Static (non-array) fields of ImpactIndex. The single source of truth for
@@ -194,9 +193,7 @@ def build_impact_index(
       pad_postings_to: pad the posting store to this multiple (TPU alignment).
       max_doc_terms: longest document the doc-major store keeps (defaults
         to the longest doc; longer ones are truncated). The store's width is
-        this rounded up to a power of two, the shape the engines' fixed-order
-        row sum (``repro.kernels.common.tree_sum_last``) reduces without
-        padding, and to at least ``DOC_STORE_MIN_WIDTH`` slots.
+        this rounded up to a multiple of ``DOC_STORE_LANES`` slots.
     """
     doc_idx = np.asarray(doc_idx, dtype=np.int64)
     term_idx = np.asarray(term_idx, dtype=np.int64)

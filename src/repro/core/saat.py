@@ -331,17 +331,17 @@ def saat_search_vmap(
     return jax.vmap(one)(q_terms, q_weights)
 
 
-def exact_rho(index: ImpactIndex, lq: int | None = None) -> int:
-    """A rho that guarantees rank-safe evaluation.
-
-    With ``lq=None``: for any query (the whole posting store). With a query
-    width ``lq``: for any query of at most ``lq`` distinct terms — the sum of
-    the ``lq`` longest posting lists, which is all such a query can touch.
-    Budget buffers are ``[B, rho]``, so on a deployment-size corpus this
-    bound, not the store size, is what fits the device. Reads the per-term
-    posting counts back from the device once.
+def posting_reach(index: ImpactIndex) -> np.ndarray:
+    """``reach[w - 1]``: the most postings a query of ``w`` distinct terms
+    can touch — the sum of the ``w`` longest posting lists (for a stacked
+    index, the largest over its shards). Host int64, one entry per term
+    slot; reads the per-term posting counts back from the device once.
     """
-    if lq is None:
-        return index.n_postings
-    counts = np.sort(np.asarray(jax.device_get(index.term_post_count)))[::-1]
-    return max(1, min(int(counts[:lq].sum()), index.n_postings))
+    counts = np.asarray(jax.device_get(index.term_post_count), dtype=np.int64)
+    reach = np.cumsum(-np.sort(-counts, axis=-1), axis=-1)
+    return reach.max(axis=0) if reach.ndim == 2 else reach
+
+
+def exact_rho(index: ImpactIndex) -> int:
+    """A rho that guarantees rank-safe evaluation for any query."""
+    return index.n_postings

@@ -67,15 +67,21 @@ def pad_axis(x: jax.Array, axis: int, multiple: int, fill=0) -> jax.Array:
 def tree_sum_last(x: jax.Array) -> jax.Array:
     """Sum over the last axis by pairwise halving: ``x[..., :h] + x[..., h:]``.
 
-    The width is zero-padded to a power of two first (adding ``+0.0`` is
-    exact). Only elementwise adds of static slices are used, so the result is
-    bit-identical wherever it is evaluated — inside a Pallas kernel or in
-    XLA, on any backend. Returns ``x.shape[:-1]``.
+    The sum is that of the width zero-padded to a power of two (adding
+    ``+0.0`` is exact), but the padding is never built: the width splits
+    into chunks of its largest power-of-two divisor (128 lanes for a
+    doc-major store), and chunk ``i`` adds chunk ``i + h`` only where that
+    chunk exists. Only elementwise adds of static, chunk-aligned slices are
+    used, so the result is bit-identical wherever it is evaluated — inside a
+    Pallas kernel or in XLA, on any backend. Returns ``x.shape[:-1]``.
     """
     w = x.shape[-1]
-    p = next_pow2(w)
-    if p != w:
-        x = pad_axis(x, x.ndim - 1, p, fill=0)
+    g = w & -w
+    chunks = [x[..., i * g : (i + 1) * g] for i in range(w // g)]
+    while len(chunks) > 1:
+        h = next_pow2(len(chunks)) // 2
+        chunks = [c + chunks[i + h] if i + h < len(chunks) else c for i, c in enumerate(chunks[:h])]
+    x = chunks[0]
     while x.shape[-1] > 2:
         h = x.shape[-1] // 2
         x = x[..., :h] + x[..., h:]

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import KernelContract, ShapeCase
-from repro.kernels.common import interpret_default, next_pow2, pad_axis
+from repro.kernels.common import interpret_default, next_pow2, pad_axis, round_up
 from repro.kernels.sparse_score.kernel import sparse_score_batched_kernel
 
 
@@ -24,17 +24,19 @@ def sparse_score_batched(
     """Per-query scores for ``doc_terms [B, N, Tmax]`` vs queries ``[B, Lq]``.
 
     One (query, doc-block)-gridded launch — the DAAT phase-2 chunk scorer.
-    Doc rows pad to the block multiple and the term axis to a power of two
-    (sentinel term -1, weight 0: both add exactly ``+0.0``); query slots
-    with weight ``<= 0`` are zeroed. f32[B, N].
+    Doc rows pad to the block multiple and the term axis to whole 128-lane
+    tiles, or to a power of two when narrower (sentinel term -1, weight 0:
+    both add exactly ``+0.0``); query slots with weight ``<= 0`` are zeroed.
+    f32[B, N].
     """
     if interpret is None:
         interpret = interpret_default()
     n, tmax = doc_terms.shape[1:]
     dt = pad_axis(doc_terms.astype(jnp.int32), 1, block_d, fill=-1)
     dw = pad_axis(doc_weights.astype(jnp.float32), 1, block_d, fill=0.0)
-    dt = pad_axis(dt, 2, next_pow2(tmax), fill=-1)
-    dw = pad_axis(dw, 2, next_pow2(tmax), fill=0.0)
+    lanes = next_pow2(tmax) if tmax < 128 else round_up(tmax, 128)
+    dt = pad_axis(dt, 2, lanes, fill=-1)
+    dw = pad_axis(dw, 2, lanes, fill=0.0)
     qw = jnp.where(q_weights > 0, q_weights.astype(jnp.float32), 0.0)
     scores = sparse_score_batched_kernel(
         dt, dw, q_terms, qw, block_d=block_d, interpret=interpret

@@ -15,8 +15,11 @@ the wall times it prints are single runs, not measurements.
    ``scatter_impl="sort"`` (oracle) vs ``fused_topk=True``, and DAAT jnp
    (oracle) vs ``use_kernels + fused_chunk + trips_per_launch``. Each kernel
    mode's ids must equal its oracle's (the slow jnp DAAT oracle serves the
-   first batch only); on a TPU its executable must contain the Mosaic kernel
-   (``tpu_custom_call``).
+   first rows of the first batch only; a row's answer never depends on the
+   rest of its batch), and every kernel-mode answer must pass
+   :class:`HostReference`, exhaustive scoring on the host that shares no
+   code with the device path. On a TPU each kernel mode's executable must
+   contain the Mosaic kernel (``tpu_custom_call``).
 4. ``queue`` — a short seeded-Poisson replay through
    :class:`~repro.serving.queue.AdmissionQueue` on a ``HybridClock``, as
    ``python -m repro.launch.serve --queue`` runs it.
@@ -34,11 +37,12 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import build_impact_index, pad_queries
-from repro.core.saat import exact_rho
+from repro.core.saat import posting_reach
 from repro.data.synthetic import CorpusConfig, generate_corpus
 from repro.metrics.latency import HybridClock
 from repro.models.treatments import apply_treatment
 from repro.serving import AnytimeServer, ServingConfig
+from repro.serving.bucketing import merge_repeated_terms
 from repro.serving.queue import AdmissionQueue, replay_arrivals
 from repro.serving.sharded import (
     make_bucketed_serve_step,
@@ -51,14 +55,14 @@ from repro.serving.sharded import (
 # tests/test_tpu_compile.py compiles the kernels at. 262,144 passages is one
 # chip's share of an MS MARCO-size collection split over a 32-chip slice
 # (8.8M / 32 ~ 275k); the doc-major widths are the stores the generator's
-# longest spladev2 / bm25 passages round up to (a power of two, at least one
-# 128-lane tile).
+# longest spladev2 / bm25 passages round up to (whole 128-lane tiles).
 DEPLOYMENT = dict(
     treatments=("spladev2", "bm25"),
     n_docs=262_144,
     n_queries=256,
     batch=32,
     n_batches=2,
+    daat_oracle_rows=8,
     lq_buckets=(8, 16, 40),
     lq=40,
     rho=262_144,
@@ -68,9 +72,9 @@ DEPLOYMENT = dict(
     est_blocks=8,
     block_budget=16,
     trips_per_launch=4,
-    tmax=(128, 1024),
+    tmax=(128, 768),
     n_bm=20_000_000,  # block-max cells: ~19.2M in the spladev2 index
-    queue_requests=96,
+    queue_requests=16,
     queue_qps=400.0,
     queue_deadline_ms=2000.0,
     # two lanes, one flush shape: each (lane, shape) is a compile at the
@@ -86,6 +90,7 @@ TINY = dict(
     n_queries=48,
     batch=8,
     n_batches=1,
+    daat_oracle_rows=4,
     lq_buckets=(8, 40),
     rho=2048,
     ks=(10, 200),
@@ -140,9 +145,90 @@ def _modes(cfg: dict, k: int) -> dict[str, tuple[ServingConfig, ServingConfig]]:
 
 def _executable_text(server: AnytimeServer, qt, qw, rho) -> str:
     """Compiled text of the executable ``search_batch`` just ran."""
-    fn = server.engine_fn(rho)  # a partial of the engine's jitted function
-    qt, qw, _ = server._bucketize(qt, qw)
+    qt, qw, bucket = server._bucketize(qt, qw)
+    fn = server.engine_fn(server.served_rho(rho, bucket))  # a partial of a jitted engine
     return fn.func.lower(*fn.args, qt, qw, **fn.keywords).compile().as_text()
+
+
+# Host float64 sums against the device's f32 ones: at most 40 addends of
+# 8-bit impacts times query weights, far inside these tolerances.
+_RTOL, _ATOL = 1e-5, 1e-5
+
+
+class HostReference:
+    """The serving semantics re-done on the host: numpy over the posting store.
+
+    A query's postings are taken in JASS order — its segments by
+    contribution (``impact * query weight``, f32) descending, ties in slot
+    then segment order — up to a budget of ``rho`` postings (all of them
+    when ``rho`` is None: exhaustive scoring), and summed per document by a
+    float64 ``bincount``. No plan, sort network, kernel or jitted code of
+    the serving path runs here, so it witnesses that path rather than
+    repeating it. :meth:`check` accepts an answer when it is a valid top-k:
+    its scores are the k largest reference scores, each returned doc really
+    has its score, and no doc appears twice. Which of several docs tied at
+    the k-th score an engine keeps is left open.
+    """
+
+    def __init__(self, index):
+        def get(a):
+            return np.asarray(jax.device_get(a))
+
+        self.doc_ids = get(index.doc_ids)
+        self.seg_weight = get(index.seg_weight)
+        self.seg_start = get(index.seg_start).astype(np.int64)
+        self.seg_len = get(index.seg_len).astype(np.int64)
+        self.term_seg_start = get(index.term_seg_start)
+        self.term_seg_count = get(index.term_seg_count)
+        self.n_docs, self.n_terms = index.n_docs, index.n_terms
+
+    def scores(self, qt: np.ndarray, qw: np.ndarray, rho: int | None = None) -> np.ndarray:
+        """f64[B, n_docs]: each query's score for every document."""
+        out = np.zeros((qt.shape[0], self.n_docs))
+        for b in range(qt.shape[0]):
+            segs, contrib = [], []
+            for t, w in zip(qt[b], qw[b]):
+                if t == self.n_terms or w <= 0:
+                    continue
+                s0, c = self.term_seg_start[t], self.term_seg_count[t]
+                segs.append(np.arange(s0, s0 + c))
+                contrib.append(self.seg_weight[s0 : s0 + c] * np.float32(w))
+            if not segs:
+                continue
+            segs, contrib = np.concatenate(segs), np.concatenate(contrib)
+            order = np.argsort(-contrib, kind="stable")
+            segs, contrib = segs[order], contrib[order]
+            take = self.seg_len[segs]
+            if rho is not None:  # the budget cuts the plan after rho postings
+                before = np.cumsum(take) - take
+                take = np.clip(rho - before, 0, take)
+            ends = np.cumsum(take)
+            pos = np.arange(ends[-1]) - np.repeat(ends - take, take)
+            docs = self.doc_ids[np.repeat(self.seg_start[segs], take) + pos]
+            w = np.repeat(contrib.astype(np.float64), take)
+            out[b] = np.bincount(docs, w, minlength=self.n_docs)
+        return out
+
+    @staticmethod
+    def check(ref: np.ndarray, scores: np.ndarray, ids: np.ndarray) -> bool:
+        """Is ``(scores, ids) [B, k]`` a valid top-k of ``ref [B, n_docs]``?
+
+        ``-inf`` slots (fewer than k docs reached) stand for score 0.
+        """
+        k = scores.shape[1]
+        for r in range(scores.shape[0]):
+            top = -np.sort(np.partition(-ref[r], k - 1)[:k])
+            s = scores[r].astype(np.float64)
+            live = np.isfinite(s)
+            got = ids[r][live]
+            if not (
+                np.allclose(np.where(live, s, 0.0), top, rtol=_RTOL, atol=_ATOL)
+                and np.all((got >= 0) & (got < ref.shape[1]))
+                and np.allclose(ref[r][got], s[live], rtol=_RTOL, atol=_ATOL)
+                and np.unique(got).size == got.size
+            ):
+                return False
+        return True
 
 
 def _serve(name: str, index, cfg: dict, qt: np.ndarray, qw: np.ndarray) -> None:
@@ -151,17 +237,24 @@ def _serve(name: str, index, cfg: dict, qt: np.ndarray, qw: np.ndarray) -> None:
     batches = [
         (qt[i * B : (i + 1) * B], qw[i * B : (i + 1) * B]) for i in range(cfg["n_batches"])
     ]
+    host = HostReference(index)
+    refs = {  # SAAT at its posting budget; DAAT is exact
+        engine: [host.scores(bt, bw, rho) for bt, bw in batches]
+        for engine, rho in (("saat", cfg["rho"]), ("daat", None))
+    }
     for k in cfg["ks"]:
         for engine, (oracle_cfg, kernel_cfg) in _modes(cfg, k).items():
             ids = {}
             for label, scfg in (("oracle", oracle_cfg), ("kernel", kernel_cfg)):
                 server = AnytimeServer(index, scfg)
                 rho = cfg["rho"] if engine == "saat" else None
-                # the jnp DAAT oracle scores every selected block in XLA and is
-                # by far the slowest mode: it serves the first batch only
-                n = 1 if (engine, label) == ("daat", "oracle") else len(batches)
+                served = batches
+                if (engine, label) == ("daat", "oracle"):
+                    # by far the slowest mode: the first rows of one batch
+                    r = cfg["daat_oracle_rows"]
+                    served = [(batches[0][0][:r], batches[0][1][:r])]
                 walls, buckets, out = [], [], []
-                for bt, bw in batches[:n]:
+                for bt, bw in served:
                     t0 = time.perf_counter()
                     res = server.search_batch(jnp.asarray(bt), jnp.asarray(bw), rho=rho)
                     ids_b = np.asarray(res.doc_ids)  # blocks until the batch is done
@@ -169,24 +262,36 @@ def _serve(name: str, index, cfg: dict, qt: np.ndarray, qw: np.ndarray) -> None:
                     buckets.append(server._bucketize(bt, bw)[2])
                     out.append((ids_b, np.asarray(res.scores)))
                 ids[label] = out
-                custom = None
-                if label == "kernel" and on_tpu:
-                    text = _executable_text(server, jnp.asarray(batches[0][0]),
-                                            jnp.asarray(batches[0][1]), rho)
-                    custom = "tpu_custom_call" in text
-                    assert custom, f"{name} {engine} k={k}: no Mosaic kernel in the executable"
+                extra = {}
+                if label == "kernel":
+                    extra["host_witness"] = all(
+                        HostReference.check(ref, s, i)
+                        for ref, (i, s) in zip(refs[engine], out)
+                    )
+                    if on_tpu:
+                        text = _executable_text(server, jnp.asarray(batches[0][0]),
+                                                jnp.asarray(batches[0][1]), rho)
+                        extra["tpu_custom_call"] = "tpu_custom_call" in text
                 # a batch whose Lq bucket is new to the server compiles first
                 _log(
                     "serve", treatment=name, engine=engine, mode=label, k=k,
+                    rows=",".join(str(bt.shape[0]) for bt, _ in served),
                     lq_buckets=",".join(map(str, buckets)),
                     batch_s_incl_new_bucket_compile=",".join(f"{w:.4f}" for w in walls),
-                    **({} if custom is None else {"tpu_custom_call": custom}),
+                    **extra,
+                )
+                assert extra.get("host_witness", True), (
+                    f"{name} {engine} k={k}: kernel answer is not the exhaustive top-k"
+                )
+                assert extra.get("tpu_custom_call", True), (
+                    f"{name} {engine} k={k}: no Mosaic kernel in the executable"
                 )
             for b, ((oi, os_), (ki, ks_)) in enumerate(zip(ids["oracle"], ids["kernel"])):
-                same_ids = np.array_equal(oi, ki)
-                same_scores = np.array_equal(os_, ks_)
+                r = oi.shape[0]  # the kernel batch's first r rows
+                same_ids = np.array_equal(oi, ki[:r])
+                same_scores = np.array_equal(os_, ks_[:r])
                 _log(
-                    "parity", treatment=name, engine=engine, k=k, batch=b,
+                    "parity", treatment=name, engine=engine, k=k, batch=b, rows=r,
                     ids_equal=same_ids, scores_equal=same_scores,
                 )
                 assert same_ids, f"{name} {engine} k={k} batch {b}: kernel ids != oracle ids"
@@ -195,9 +300,9 @@ def _serve(name: str, index, cfg: dict, qt: np.ndarray, qw: np.ndarray) -> None:
 def _queue(index, cfg: dict, qt: np.ndarray, qw: np.ndarray, seed: int) -> None:
     """A short arrival replay, as ``python -m repro.launch.serve --queue`` runs it.
 
-    The ladder is the exact level alone (the store size caps to it): the
-    replay serves rank-safe answers at the budget the widest bucket needs,
-    which must fit the device at the largest flush shape.
+    The ladder is the exact level alone: each flush serves rank-safe
+    answers at the reach of its Lq bucket, which must fit the device at the
+    largest flush shape.
     """
     clock = HybridClock()
     scfg = ServingConfig(
@@ -227,7 +332,10 @@ def _queue(index, cfg: dict, qt: np.ndarray, qw: np.ndarray, seed: int) -> None:
     same_ids = np.array_equal(np.stack([c.doc_ids for c in first]), np.asarray(direct.doc_ids))
     _log(
         "queue", requests=n, completed=queue.n_completed, flushes=len(queue.flush_log),
-        violations=queue.n_violations, rho_ladder=",".join(map(str, server.rho_ladder)),
+        violations=queue.n_violations,
+        exact_rho_served=",".join(
+            f"{b}:{server.served_rho(server.rho_ladder[-1], b)}" for b in server.lq_buckets
+        ),
         warmup_s=f"{warm_s:.3f}", ids_equal_direct=same_ids,
     )
     assert same_ids, "queue-served ids != direct serving ids"
@@ -247,6 +355,12 @@ def _generate(cfg: dict, seed: int):
     return corpus, encs
 
 
+def _queries(enc, cfg: dict) -> tuple[np.ndarray, np.ndarray]:
+    """The treatment's queries padded to ``cfg["lq"]``, each term once."""
+    qt, qw = pad_queries(enc.query_terms, enc.query_weights, cfg["lq"], enc.n_terms)
+    return merge_repeated_terms(qt, qw, enc.n_terms)
+
+
 def _build(enc, n_docs: int, device):
     index = build_impact_index(enc.doc_idx, enc.term_idx, enc.weights, n_docs, enc.n_terms)
     return jax.block_until_ready(jax.device_put(index, device))
@@ -264,22 +378,21 @@ def run(cfg: dict, seed: int) -> None:
             device_bytes_in_use=_device_bytes_in_use(),
             seconds=f"{time.perf_counter() - t0:.1f}",
         )
-        qt, qw = pad_queries(enc.query_terms, enc.query_weights, cfg["lq"], enc.n_terms)
+        qt, qw = _queries(enc, cfg)
         _serve(name, index, cfg, qt, qw)
         if name == cfg["treatments"][-1]:
             _queue(index, cfg, qt, qw, seed)
         del index
 
 
-def _sharded_modes(cfg: dict, k: int, shards: list) -> dict:
-    """(unsharded oracle config, sharded step kwargs) per engine, both exact."""
+def _sharded_modes(cfg: dict, k: int, stack) -> dict:
+    """(unsharded oracle config, sharded step kwargs) per engine, both exact.
+
+    DAAT first: it is the half whose cross-chip run matters most, and it
+    must not wait behind the other.
+    """
     lq = cfg["lq_buckets"][-1]
     return {
-        "saat": (
-            ServingConfig(k=k, rho_ladder=(cfg["rho"],), lq_buckets=cfg["lq_buckets"],
-                          scatter_impl="sort"),
-            dict(fused_topk=True, rho_per_shard=max(exact_rho(s, lq) for s in shards)),
-        ),
         "daat": (
             ServingConfig(k=k, engine="daat", lq_buckets=cfg["lq_buckets"],
                           daat_est_blocks=cfg["est_blocks"],
@@ -290,12 +403,19 @@ def _sharded_modes(cfg: dict, k: int, shards: list) -> dict:
                 daat_fused_chunk=True, daat_trips_per_launch=cfg["trips_per_launch"],
             ),
         ),
+        "saat": (
+            ServingConfig(k=k, rho_ladder=(cfg["rho"],), lq_buckets=cfg["lq_buckets"],
+                          scatter_impl="sort"),
+            # exact on every shard: the widest bucket's reach on the fullest one
+            dict(fused_topk=True, rho_per_shard=int(posting_reach(stack)[lq - 1])),
+        ),
     }
 
 
-def _same_up_to_ties(scores, ids, want_s, want_i) -> bool:
+def _same_above_kth(scores, ids, want_s, want_i) -> bool:
     """Bit-equal scores, and the same ids wherever a score is above the row's
-    k-th score (which docs share the k-th score is the engine's choice)."""
+    k-th score (which of the docs tied at the k-th score a pool keeps is the
+    engine's choice)."""
     if not np.array_equal(scores, want_s):
         return False
     above = scores > scores[:, -1:]
@@ -309,10 +429,14 @@ def run_sharded(cfg: dict, seed: int) -> None:
 
     The stacked index is placed one shard per device (checked through each
     array's ``addressable_shards``), one batch is served through
-    :func:`~repro.serving.sharded.make_bucketed_serve_step` in SAAT
-    ``fused_topk`` and DAAT ``fused_chunk`` modes at their exact budgets,
-    and the ids must equal the unsharded oracle's on the same corpus: bit
-    for bit in SAAT, up to the order of tied scores in DAAT.
+    :func:`~repro.serving.sharded.make_bucketed_serve_step` in DAAT
+    ``fused_chunk`` and SAAT ``fused_topk`` modes at their exact budgets, and
+    each answer is held to the unsharded oracle on the same corpus and to
+    :class:`HostReference`. SAAT must equal the oracle bit for bit. DAAT
+    must equal it in every score and in every id above the k-th score: a
+    DAAT pool keeps, of the docs tied at the k-th score, those its traversal
+    reaches first, and sharding changes the traversal, so there the host
+    witness checks that each returned doc really has that score.
     """
     n = cfg["n_shards"]
     devices = jax.devices()[:n]
@@ -341,10 +465,11 @@ def run_sharded(cfg: dict, seed: int) -> None:
             ),
             seconds=f"{time.perf_counter() - t0:.1f}",
         )
-        qt, qw = pad_queries(enc.query_terms, enc.query_weights, cfg["lq"], enc.n_terms)
+        qt, qw = _queries(enc, cfg)
         qt, qw = qt[:B], qw[:B]
+        ref = HostReference(full).scores(qt, qw)
         for k in cfg["ks"]:
-            for engine, (oracle_cfg, step_kw) in _sharded_modes(cfg, k, shards).items():
+            for engine, (oracle_cfg, step_kw) in _sharded_modes(cfg, k, stack).items():
                 oracle = AnytimeServer(full, oracle_cfg)
                 rho = oracle.rho_ladder[-1] if engine == "saat" else None
                 want = oracle.search_batch(jnp.asarray(qt), jnp.asarray(qw), rho=rho)
@@ -360,15 +485,14 @@ def run_sharded(cfg: dict, seed: int) -> None:
                 wall = time.perf_counter() - t0
                 want_s, want_i = np.asarray(want.scores), np.asarray(want.doc_ids)
                 same_ids = np.array_equal(ids, want_i)
-                same_untied = _same_up_to_ties(scores, ids, want_s, want_i)
+                same_above = _same_above_kth(scores, ids, want_s, want_i)
+                witness = HostReference.check(ref, scores, ids)
                 _log(
                     "sharded", treatment=name, engine=engine, k=k,
                     batch_s_incl_compile=f"{wall:.3f}", ids_equal_unsharded=same_ids,
-                    ids_equal_up_to_ties=same_untied,
-                    scores_equal=np.array_equal(scores, want_s),
+                    ids_equal_above_kth=same_above,
+                    scores_equal=np.array_equal(scores, want_s), host_witness=witness,
                 )
-                # SAAT ranks ties by doc id on both sides; a DAAT pool keeps
-                # tied docs in traversal order, which sharding changes
-                exact = same_ids if engine == "saat" else same_untied
-                assert exact, f"{name} {engine} k={k}: sharded ids != unsharded oracle ids"
+                exact = same_ids if engine == "saat" else same_above
+                assert exact and witness, f"{name} {engine} k={k}: sharded != unsharded oracle"
         del full, stack

@@ -100,6 +100,34 @@ def sentinel_rows(n_rows: int, width: int, n_terms: int) -> Tuple[np.ndarray, np
     )
 
 
+def merge_repeated_terms(
+    q_terms: np.ndarray, q_weights: np.ndarray, n_terms: int
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Each live term once per ``[B, Lq]`` row: a repeated term's weights sum
+    (in slot order, f32) into its first slot and its later slots become
+    pads. The query is a sparse vector, so this is the score DAAT's dense
+    query vector already gives; it lets a query of width ``w`` touch at most
+    the ``w`` longest posting lists. Rows without a repeat are returned
+    unchanged (copies).
+    """
+    qt = np.array(q_terms, dtype=np.int32)
+    qw = np.array(q_weights, dtype=np.float32)
+    live = (qt != n_terms) & (qw > 0)
+    # dead slots get distinct negative keys, so only live repeats compare equal
+    key = np.where(live, qt.astype(np.int64), -1 - np.arange(qt.shape[-1]))
+    srt = np.sort(key, axis=-1)
+    for r in np.nonzero((srt[:, 1:] == srt[:, :-1]).any(axis=-1))[0]:
+        first: dict = {}
+        for j in np.nonzero(live[r])[0]:
+            t = int(qt[r, j])
+            if t in first:
+                qw[r, first[t]] += qw[r, j]
+                qt[r, j], qw[r, j] = n_terms, 0.0
+            else:
+                first[t] = j
+    return qt, qw
+
+
 def bucketize_batch(
     q_terms: np.ndarray,
     q_weights: np.ndarray,

@@ -282,6 +282,11 @@ class PodServer(AnytimeServer):
 
         return fn
 
+    def served_rho(self, rho: Optional[int], lq_bucket: int) -> Optional[int]:
+        # the pod ladder is already per shard: its top level is each shard's
+        # whole posting store, the budget every rank's scan runs with
+        return rho
+
     def _daat_search(self, q_terms, q_weights):
         return self._pod_dispatch(q_terms, q_weights, None)
 

@@ -48,9 +48,14 @@ import numpy as np
 from repro.core.daat import daat_search_batched, max_blocks_per_term
 from repro.core.impact_index import META_FIELDS, ImpactIndex
 from repro.core.index_handle import IndexHandle
-from repro.core.saat import exact_rho, max_segments_per_term, saat_search
+from repro.core.saat import max_segments_per_term, posting_reach, saat_search
 from repro.metrics.latency import Clock, LatencyStats, SystemClock, summarize_latencies
-from repro.serving.bucketing import bucketize_batch, normalize_buckets, pad_to_width
+from repro.serving.bucketing import (
+    bucketize_batch,
+    merge_repeated_terms,
+    normalize_buckets,
+    pad_to_width,
+)
 
 _UNSET = object()  # pick_rho sentinel: "use cfg.deadline_ms"
 
@@ -249,36 +254,35 @@ class AnytimeServer:
 
     def _bind_main_segment(self):
         """(Re)derive everything that depends on the current main segment:
-        the plan bounds (build-time metadata — no device sync) and the rho
-        ladder cap. The exact level is what one query of the widest Lq
-        bucket can touch (:func:`repro.core.saat.exact_rho`: the sum of that
-        many longest posting lists — one small read of the per-term counts);
-        without buckets the width is unbounded and the level is the main
-        segment's posting count. Called at construction and on every
-        :meth:`swap_index`.
+        the plan bounds (build-time metadata — no device sync), the rho
+        ladder cap (the exact level is the main segment's posting count) and
+        the per-width reach that every dispatch's budget is capped at (see
+        :meth:`served_rho`; one small read of the per-term counts). Called
+        at construction and on every :meth:`swap_index`.
         """
         index = self.handle.main if self.handle is not None else self.index
         self.index = index
         self.max_segs = max_segments_per_term(index)
         self.max_bm = max_blocks_per_term(index)
-        self._ladder_width = None
-        if self.lq_buckets is not None and self.handle is None:
-            self._ladder_width = self.lq_buckets[-1]
-        exact = exact_rho(index, self._ladder_width)
+        self._reach = posting_reach(index)
+        exact = index.n_postings
         ladder = sorted({min(r, exact) for r in self.cfg.rho_ladder} | {exact})
         self.rho_ladder = tuple(ladder)
 
-    def _served_rho(self, rho: int, lq_bucket: int) -> int:
-        """The budget a SAAT dispatch at ``lq_bucket`` really runs with.
+    def served_rho(self, rho: Optional[int], lq_bucket: int) -> Optional[int]:
+        """The budget a SAAT dispatch of width ``lq_bucket`` really runs with.
 
-        The exact level is sized for the ladder's width; a batch that
-        overflows the bucket ladder (its own, wider executable) gets the
-        exact budget for ITS width, so the top level stays rank-safe.
+        A ladder level capped at what a query of that width can touch (the
+        sum of its ``lq_bucket`` longest posting lists, with each term once —
+        :meth:`_bucketize` merges repeated terms): the engine stops at the
+        query's own total, so the cap changes no answer, and it keeps the
+        ``[B, rho]`` budget buffers of the exact level the size the
+        executable's width needs rather than the store's. ``None`` (DAAT)
+        passes through.
         """
-        w = self._ladder_width
-        if rho == self.rho_ladder[-1] and w is not None and lq_bucket > w:
-            return exact_rho(self.index, lq_bucket)
-        return rho
+        if rho is None or self.cfg.engine == "daat":
+            return rho
+        return min(int(rho), int(self._reach[min(int(lq_bucket), self._reach.size) - 1]))
 
     # -------------------------- index lifecycle ----------------------------
 
@@ -554,7 +558,7 @@ class AnytimeServer:
         else:
             rho = self.rho_ladder[-1] if rho is None else rho
             statics = (
-                "saat", cfg.k, self._served_rho(rho, lq_bucket),
+                "saat", cfg.k, self.served_rho(rho, lq_bucket),
                 self.max_segs, cfg.scatter_impl, cfg.fused_topk,
             )
         return statics + self._index_signature() + (int(lq_bucket), int(batch_size))
@@ -587,15 +591,17 @@ class AnytimeServer:
         one-executable-per-key contract ``executable_key`` promises. The
         casts are host-side (pre-dispatch), so the traced hot path always
         sees ``i32/f32`` strong types — which is what the analysis lint
-        asserts.
+        asserts. A term repeated in a query row is merged into one slot
+        (weights summed): a query is a sparse vector, and :meth:`served_rho`
+        budgets each term once.
         """
-        if self.lq_buckets is None:
-            qt = jnp.asarray(q_terms, jnp.int32)
-            qw = jnp.asarray(q_weights, jnp.float32)
-            return qt, qw, int(qt.shape[-1])
-        qt, qw, bucket = bucketize_batch(
-            np.asarray(q_terms), np.asarray(q_weights), self.lq_buckets, self.index.n_terms
+        qt, qw = merge_repeated_terms(
+            np.asarray(q_terms), np.asarray(q_weights), self.index.n_terms
         )
+        if self.lq_buckets is None:
+            bucket = int(qt.shape[-1])
+        else:
+            qt, qw, bucket = bucketize_batch(qt, qw, self.lq_buckets, self.index.n_terms)
         return jnp.asarray(qt, jnp.int32), jnp.asarray(qw, jnp.float32), bucket
 
     def search_batch(self, q_terms: jax.Array, q_weights: jax.Array, rho: Optional[int] = None):
@@ -626,7 +632,7 @@ class AnytimeServer:
             )
         t0 = self.clock.now()  # bucketize is service cost: keep it timed
         q_terms, q_weights, bucket = self._bucketize(q_terms, q_weights)
-        res = self.engine_fn(self._served_rho(rho, bucket))(q_terms, q_weights)
+        res = self.engine_fn(self.served_rho(rho, bucket))(q_terms, q_weights)
         jax.block_until_ready(res.scores)
         elapsed = (self.clock.now() - t0) * 1e3
         per_query = elapsed / q_terms.shape[0]
@@ -674,7 +680,7 @@ class AnytimeServer:
                 for rho in self.rho_ladder:
                     for _ in range(repeats):
                         t0 = self.clock.now()
-                        res = self.engine_fn(rho)(qt, qw)
+                        res = self.engine_fn(self.served_rho(rho, bucket))(qt, qw)
                         jax.block_until_ready(res.scores)
                         batch_ms = (self.clock.now() - t0) * 1e3
                     self._cost.update(rho, batch_ms * 1e3 / B)

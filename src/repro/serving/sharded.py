@@ -667,6 +667,10 @@ def make_bucketed_serve_step(
     buckets = normalize_buckets(lq_buckets)
     step = make_pod_serve_step if "pod" in mesh.axis_names else make_sharded_serve_step
     serve, in_specs, out_specs = step(mesh, **kwargs)
+    # One program per (B, bucket) shape. Called bare, a shard_map runs op by
+    # op, compiling and dispatching every primitive across the mesh on its
+    # own. The stack's static meta is part of the jit key (pytree aux data).
+    jitted = jax.jit(serve)
 
     def serve_bucketed(index_stack: ImpactIndex, q_terms, q_weights, live_stack=None):
         qt, qw, _ = bucketize_batch(
@@ -674,7 +678,7 @@ def make_bucketed_serve_step(
         )
         # strong i32/f32, pre-dispatch: same compile-cache invariant as
         # AnytimeServer._bucketize (see its docstring)
-        return serve(
+        return jitted(
             index_stack, jnp.asarray(qt, jnp.int32), jnp.asarray(qw, jnp.float32),
             live_stack=live_stack,
         )

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import build_impact_index
+from repro.core import build_impact_index, pad_queries
 from repro.core.daat import (
     daat_plan,
     daat_search_batched,
@@ -331,6 +331,28 @@ def test_engine_fused_chunk_parity(bm25_index, bm25_queries, exact):
         bm25_index, jnp.asarray(qt), jnp.asarray(qw),
         k=10, est_blocks=2, block_budget=2, exact=exact,
     )
+
+
+def test_engine_fused_chunk_parity_on_three_tile_store(tiny_corpus, splade_collection):
+    """A doc-major store three 128-lane tiles wide (not a power of two): the
+    kernels' fixed-order row sum folds the tiles exactly as the jnp scorer
+    does, so scores are bitwise equal too."""
+    enc = splade_collection
+    idx = build_impact_index(
+        enc.doc_idx, enc.term_idx, enc.weights, tiny_corpus.n_docs, enc.n_terms,
+        max_doc_terms=300,
+    )
+    assert idx.doc_terms.shape[1] == 384
+    qt, qw = pad_queries(enc.query_terms[:4], enc.query_weights[:4], 16, enc.n_terms)
+    f = _assert_engine_parity(
+        idx, jnp.asarray(qt), jnp.asarray(qw), k=10, est_blocks=1, block_budget=1,
+        exact=True,
+    )
+    j = daat_search_batched(
+        idx, jnp.asarray(qt), jnp.asarray(qw), k=10, est_blocks=1, block_budget=1,
+        exact=True, max_bm_per_term=max_blocks_per_term(idx),
+    )
+    np.testing.assert_array_equal(np.asarray(f.scores), np.asarray(j.scores))
 
 
 def test_engine_fused_chunk_ragged_batch(bm25_index, bm25_queries):

@@ -71,6 +71,34 @@ def test_index_invariants(bm25_index, bm25_collection):
     assert float(np.asarray(idx.term_max_weight).max()) > 0
 
 
+def test_doc_store_width_is_whole_lane_tiles(tiny_corpus, splade_collection):
+    enc = splade_collection
+    for cap, width in ((1, 128), (128, 128), (129, 256), (300, 384), (640, 640)):
+        idx = build_impact_index(
+            enc.doc_idx, enc.term_idx, enc.weights, tiny_corpus.n_docs, enc.n_terms,
+            max_doc_terms=cap,
+        )
+        assert idx.max_doc_terms == idx.doc_terms.shape[1] == width
+
+
+@pytest.mark.parametrize("width", [1, 2, 7, 128, 384, 640, 768, 1024])
+def test_tree_sum_last_equals_zero_padded_halving(width):
+    """The fold is the pairwise halving of the width zero-padded to a power of
+    two, bit for bit, without building the padding."""
+    from repro.kernels.common import next_pow2, tree_sum_last
+
+    rng = np.random.default_rng(width)
+    x = (rng.standard_normal((3, width)) * 10.0 ** rng.integers(-3, 4, (3, width)))
+    x = x.astype(np.float32)
+    want = np.zeros((3, next_pow2(width)), np.float32)
+    want[:, :width] = x
+    while want.shape[1] > 1:
+        h = want.shape[1] // 2
+        want = want[:, :h] + want[:, h:]
+    got = np.asarray(tree_sum_last(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, want[:, 0])
+
+
 def test_index_size_accounting(bm25_index):
     assert bm25_index.posting_store_nbytes() < bm25_index.nbytes()
 

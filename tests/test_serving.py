@@ -32,23 +32,53 @@ def test_server_ladder_capped_at_exact(bm25_index):
 
 
 def test_bucketed_exact_rung_is_bounded_and_exact(bm25_index, bm25_queries):
-    """With Lq buckets the exact level is the widest bucket's reach — the sum
-    of that many longest posting lists, not the whole store — and serving at
-    it still equals exhaustive scoring (and the store-sized level, bitwise)."""
+    """The exact level is served at the executable's reach — the sum of its
+    width's longest posting lists, not the whole store — and serving at it
+    still equals exhaustive scoring (and an uncapped dispatch, bitwise)."""
     qt, qw = bm25_queries
     L = qt.shape[1]
     cfg = dict(k=10, rho_ladder=(10**9,), batch_size=8)
     srv = AnytimeServer(bm25_index, ServingConfig(**cfg, lq_buckets=(L,)))
+    top = srv.rho_ladder[-1]
+    assert top == bm25_index.n_postings
     longest = np.sort(np.asarray(bm25_index.term_post_count))[::-1]
-    assert srv.rho_ladder[-1] == int(longest[:L].sum()) < bm25_index.n_postings
+    reach = int(longest[:L].sum())
+    assert srv.served_rho(top, L) == reach < bm25_index.n_postings
+    assert srv.executable_key(L, 8, top)[2] == reach
     scores, ids = run_query_stream(srv, qt, qw)
     ex = exhaustive_search(bm25_index, jnp.asarray(qt), jnp.asarray(qw), k=10)
     np.testing.assert_allclose(scores, np.asarray(ex.scores), rtol=1e-4, atol=1e-4)
-    full = AnytimeServer(bm25_index, ServingConfig(**cfg))
-    assert full.rho_ladder[-1] == bm25_index.n_postings
-    f_scores, f_ids = run_query_stream(full, qt, qw)
-    np.testing.assert_array_equal(ids, f_ids)
-    np.testing.assert_array_equal(scores, f_scores)
+    full = srv.engine_fn(top)(jnp.asarray(qt), jnp.asarray(qw))
+    np.testing.assert_array_equal(ids, np.asarray(full.doc_ids))
+    np.testing.assert_array_equal(scores, np.asarray(full.scores))
+
+
+@pytest.mark.parametrize("lq_buckets", [None, (4,)])
+def test_repeated_query_terms_stay_rank_safe_at_exact_rung(bm25_index, lq_buckets):
+    """A query that repeats its longest term would touch more postings than
+    its width's reach; the server merges repeats into one slot (weights
+    summed), so the exact level still processes every posting and scores the
+    query as the sparse vector exhaustive scoring sees."""
+    counts = np.asarray(bm25_index.term_post_count)
+    t0, t1 = (int(t) for t in np.argsort(-counts)[:2])
+    n = bm25_index.n_terms
+    qt = np.array([[t0, t0, t0, t1], [t1, n, t1, t0]], np.int32)
+    qw = np.array([[0.5, 0.25, 1.0, 2.0], [1.0, 0.0, 0.5, 0.75]], np.float32)
+    srv = AnytimeServer(
+        bm25_index, ServingConfig(k=10, rho_ladder=(10**9,), lq_buckets=lq_buckets)
+    )
+    assert srv.served_rho(srv.rho_ladder[-1], 4) < 3 * int(counts[t0]) + int(counts[t1])
+    res = srv.search_batch(jnp.asarray(qt), jnp.asarray(qw))
+    np.testing.assert_array_equal(np.asarray(res.postings_processed), np.asarray(res.total_postings))
+    merged_t = np.array([[t0, n, n, t1], [t1, n, n, t0]], np.int32)
+    merged_w = np.array([[1.75, 0, 0, 2.0], [1.5, 0, 0, 0.75]], np.float32)
+    want = srv.search_batch(jnp.asarray(merged_t), jnp.asarray(merged_w))
+    np.testing.assert_array_equal(np.asarray(res.doc_ids), np.asarray(want.doc_ids))
+    np.testing.assert_array_equal(np.asarray(res.scores), np.asarray(want.scores))
+    ex = exhaustive_search(bm25_index, jnp.asarray(qt), jnp.asarray(qw), k=10)
+    np.testing.assert_allclose(
+        np.asarray(res.scores), np.asarray(ex.scores), rtol=1e-5, atol=1e-5
+    )
 
 
 def test_deadline_controller_picks_rho(bm25_index, bm25_queries):
