@@ -35,7 +35,7 @@ def main():
         srv.reset_stats()
         _, ids = run_query_stream(srv, qt, qw)
         stats = srv.stats()
-        rho_used = int(np.median(srv._rhos)) if srv._rhos else 0
+        rho_used = int(np.median([d.rho for d in srv.dispatch_log])) if srv.dispatch_log else 0
         print(
             f"deadline={str(deadline):>6} ms | median rho={rho_used:>9,} | "
             f"RR@10={mrr_at_k(ids, corpus.qrels, 10):.3f} | "

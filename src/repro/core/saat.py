@@ -159,20 +159,29 @@ def _gather_postings(
 def _gather_postings_batched(
     index: ImpactIndex, plan: SaatPlan, rho: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Batched slot -> posting map: one histogram searchsorted over [B, rho]."""
+    """Batched slot -> posting map: one histogram searchsorted over [B, rho].
+
+    Two device phases, each under its own scope: ``saat.slots`` (the slot ->
+    plan-entry search) and ``saat.gather`` (the posting reads).
+    """
     B, n_cand = plan.cum_len.shape
-    p = jnp.broadcast_to(jnp.arange(rho, dtype=jnp.int32), (B, rho))
-    j = _batched_searchsorted_slots(plan.cum_len, rho)
-    j = jnp.minimum(j, n_cand - 1)
-    prev_cum = jnp.take_along_axis(plan.cum_len, jnp.maximum(j - 1, 0), axis=-1)
-    prev = jnp.where(j > 0, prev_cum, 0)
-    offset = p - prev
-    pidx = jnp.take_along_axis(plan.starts, j, axis=-1) + offset
-    valid = p < plan.total_postings[:, None]
-    docs = index.doc_ids[jnp.where(valid, pidx, 0)]
-    contribs = jnp.where(valid, jnp.take_along_axis(plan.contribs, j, axis=-1), 0.0)
-    docs = jnp.where(valid, docs, 0)
-    n_processed = jnp.minimum(plan.total_postings, rho).astype(jnp.int32)
+    # the slot ids come first, as the traced program (and the hot-path
+    # lint's fingerprint of it) has always had them
+    with jax.named_scope("saat.gather"):
+        p = jnp.broadcast_to(jnp.arange(rho, dtype=jnp.int32), (B, rho))
+    with jax.named_scope("saat.slots"):
+        j = _batched_searchsorted_slots(plan.cum_len, rho)
+    with jax.named_scope("saat.gather"):
+        j = jnp.minimum(j, n_cand - 1)
+        prev_cum = jnp.take_along_axis(plan.cum_len, jnp.maximum(j - 1, 0), axis=-1)
+        prev = jnp.where(j > 0, prev_cum, 0)
+        offset = p - prev
+        pidx = jnp.take_along_axis(plan.starts, j, axis=-1) + offset
+        valid = p < plan.total_postings[:, None]
+        docs = index.doc_ids[jnp.where(valid, pidx, 0)]
+        contribs = jnp.where(valid, jnp.take_along_axis(plan.contribs, j, axis=-1), 0.0)
+        docs = jnp.where(valid, docs, 0)
+        n_processed = jnp.minimum(plan.total_postings, rho).astype(jnp.int32)
     return docs, contribs, n_processed
 
 
@@ -273,6 +282,9 @@ def saat_search(
     The whole batch is one executable per (k, rho, scatter_impl): the planner
     runs one batched argsort, the gather one batched binary search, and the
     scatter one batch-aware kernel launch — no per-query vmapped programs.
+    Its device work falls in four named scopes, which a profiler trace
+    keeps on each operation: ``saat.plan``, ``saat.slots``, ``saat.gather``
+    and ``saat.select`` (scatter, pad mask and top-k, fused or not).
 
     ``fused_topk=True`` replaces scatter-then-select with the fused
     ``impact_scatter_topk`` kernel: the accumulator never materializes in HBM
@@ -289,16 +301,19 @@ def saat_search(
     """
     if q_terms.ndim != 2:
         raise ValueError(f"expected [B, Lq] query batch, got shape {q_terms.shape}")
-    plan = saat_plan(index, q_terms, q_weights, max_segs_per_term)
+    with jax.named_scope("saat.plan"):
+        plan = saat_plan(index, q_terms, q_weights, max_segs_per_term)
     docs, contribs, n_proc = _gather_postings_batched(index, plan, rho)
-    if fused_topk:
-        scores, ids = _fused_scatter_topk_batched(
-            index, docs, contribs, k, q_terms.shape[-1], live_mask
-        )
-    else:
-        acc = _accumulate_batched(index, docs, contribs, scatter_impl, q_terms.shape[-1])
-        scores, ids = topk(_mask_pad_docs(index, acc, live_mask), k)
-    return SaatResult(scores, ids.astype(jnp.int32), n_proc, plan.total_postings)
+    with jax.named_scope("saat.select"):
+        if fused_topk:
+            scores, ids = _fused_scatter_topk_batched(
+                index, docs, contribs, k, q_terms.shape[-1], live_mask
+            )
+        else:
+            acc = _accumulate_batched(index, docs, contribs, scatter_impl, q_terms.shape[-1])
+            scores, ids = topk(_mask_pad_docs(index, acc, live_mask), k)
+        ids = ids.astype(jnp.int32)
+    return SaatResult(scores, ids, n_proc, plan.total_postings)
 
 
 @partial(jax.jit, static_argnames=("k", "rho", "max_segs_per_term", "scatter_impl"))

@@ -28,12 +28,18 @@ derives the counter families fresh from the live server/queue objects —
 including the index lifecycle gauges (``repro_index_generation``,
 ``repro_index_tombstones``, ``repro_index_delta_docs``) when the corpus is
 mutable. Port 0 picks an ephemeral port (printed to stderr).
+
+``--trace-dir DIR`` (with ``--queue``) runs the arrival replay under
+``jax.profiler.trace``: the trace holds the queue's ``serve.*`` host spans
+and the SAAT step's ``saat.*`` device scopes (``serving/README.md``).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -160,6 +166,11 @@ def main() -> None:
         "this includes the admission-queue flush/violation/served-rho "
         "families, otherwise the server-side families only",
     )
+    ap.add_argument(
+        "--trace-dir", default=None, metavar="DIR",
+        help="with --queue: write a jax.profiler trace of the arrival replay "
+        "under DIR (serve.* host spans, saat.* device scopes)",
+    )
     ap.add_argument("--seed", type=int, default=0, help="arrival-schedule RNG seed")
     args = ap.parse_args()
     if args.queue and args.lq_buckets is None:
@@ -189,6 +200,8 @@ def main() -> None:
         ap.error("--mutate-qps must be positive")
     if args.counters_port is not None and not args.counters:
         ap.error("--counters-port scrapes the counter families; add --counters")
+    if args.trace_dir is not None and (not args.queue or args.mutate_qps is not None):
+        ap.error("--trace-dir traces the arrival replay of --queue (without --mutate-qps)")
 
     from repro.launch.compile_cache import configure_compile_cache
 
@@ -284,13 +297,15 @@ def _serve_queue(args, corpus, index, enc, cfg: ServingConfig, qt, qw) -> None:
     gaps = rng.exponential(1.0 / args.arrival_qps, size=n)
     arrivals = np.cumsum(gaps)
     order = rng.integers(0, qt.shape[0], size=n)
-    completions = replay_arrivals(
-        queue,
-        arrivals.tolist(),
-        [qt[i] for i in order],
-        [qw[i] for i in order],
-        [args.request_deadline_ms] * n,
-    )
+    traced = jax.profiler.trace(args.trace_dir) if args.trace_dir else contextlib.nullcontext()
+    with traced:
+        completions = replay_arrivals(
+            queue,
+            arrivals.tolist(),
+            [qt[i] for i in order],
+            [qw[i] for i in order],
+            [args.request_deadline_ms] * n,
+        )
     waits = summarize_latencies([c.wait_ms for c in completions])
     by_rid = sorted(completions, key=lambda c: c.rid)
     ids = np.stack([c.doc_ids for c in by_rid])

@@ -31,6 +31,7 @@ from repro.serving.lifecycle import (  # noqa: F401
 )
 from repro.serving.scheduler import (  # noqa: F401
     AnytimeServer,
+    DispatchRecord,
     ServingConfig,
     index_static_signature,
     run_query_stream,

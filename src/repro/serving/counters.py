@@ -36,6 +36,12 @@ Counter families (see also ``serving/README.md``):
       flushes served below the full budget.
   ``repro_queue_depth{bucket}``
       gauge: requests pending per bucket lane at scrape time.
+  ``repro_queue_slow_flush_total``
+      flushes whose search took over ``SLOW_FLUSH_FACTOR`` (1.5) times the
+      service the queue predicted for them: stalls, not slower paths.
+  ``repro_queue_flush_seconds{part}``
+      histogram of each flush's service by part (``pad`` | ``prep`` |
+      ``dispatch`` | ``wait`` | ``fetch``) and in all (``service``).
   ``repro_pod_dispatch_total{host, engine, rho}`` /
   ``repro_pod_merge_fanin{host, rho}``
       pod serve-step dispatches and the candidates-per-cross-host-merge

@@ -68,6 +68,20 @@ the arrival schedule: tests advance time explicitly (``clock.advance_to``)
 between ``submit``/``poll`` calls and can replay hundreds of Poisson
 arrivals with zero flakiness. Production constructs the queue with the
 default :class:`~repro.metrics.latency.SystemClock`.
+
+Spans and timings
+-----------------
+``submit`` runs under the host span ``serve.submit`` (tag ``rid``) and each
+flush under ``serve.flush`` (tags ``flush``, its index in ``flush_log``,
+and ``bucket``, ``shape``, ``n_real``, ``rho``), whose children are
+``serve.pad``, the server's ``serve.prep`` / ``serve.dispatch`` /
+``serve.wait``, and ``serve.fetch``. They are ``jax.profiler``
+annotations: free unless a profiler trace is running, and then on the
+trace's own clock beside the device's operations. The same parts are timed
+on the queue's clock into each ``FlushRecord`` (``pad_ms`` ... ``fetch_ms``,
+``service_ms``), so ``export_counters`` can report them, and a flush whose
+search took over ``SLOW_FLUSH_FACTOR`` times its prediction counts as slow:
+a stall of the host or the runtime, told apart from a slower path.
 """
 from __future__ import annotations
 
@@ -90,6 +104,18 @@ from repro.serving.counters import CounterRegistry
 from repro.serving.scheduler import AnytimeServer
 
 _EPS_S = 1e-9  # float tolerance when judging "flushed after its due instant"
+
+# A flush is slow when its search (prep + dispatch + wait, the interval its
+# predicted_ms predicts) took more than this many times the prediction: far
+# past the calibrated EMA's noise, as a runtime stall is.
+SLOW_FLUSH_FACTOR = 1.5
+
+# The parts of a flush's service, in the order they run (FlushRecord.<part>_ms).
+FLUSH_PARTS = ("pad", "prep", "dispatch", "wait", "fetch")
+
+# repro_queue_flush_seconds buckets: Prometheus' default latency buckets
+_FLUSH_SECONDS_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.075, 0.1, 0.25, 0.5, 0.75, 1.0, 2.5,
+                          5.0, 7.5, 10.0)
 
 
 class SurvivorPredictor:
@@ -202,6 +228,30 @@ class FlushRecord:
     # server). Monotone non-decreasing across flush_log: swaps happen only
     # between flushes, never under one — the hot-swap tests pin this.
     generation: int = 0
+    # the flush's service by part, in ms on the queue's clock (the server's
+    # by default): padding the rows (from the flush's start), the server's
+    # prep, dispatch and wait (its DispatchRecord), then fetching the
+    # answers to the host; each part runs under the serve.<part> host span
+    pad_ms: float = 0.0
+    prep_ms: float = 0.0
+    dispatch_ms: float = 0.0
+    wait_ms: float = 0.0
+    fetch_ms: float = 0.0
+
+    @property
+    def service_ms(self) -> float:
+        return sum(getattr(self, f"{part}_ms") for part in FLUSH_PARTS)
+
+    @property
+    def search_ms(self) -> float:
+        """The interval ``predicted_ms`` predicts: the server's part."""
+        return self.prep_ms + self.dispatch_ms + self.wait_ms
+
+    @property
+    def slow(self) -> bool:
+        """Searched for over ``SLOW_FLUSH_FACTOR`` times its prediction. A
+        flush with no prediction (an uncalibrated shape) is never slow."""
+        return 0.0 < self.predicted_ms * SLOW_FLUSH_FACTOR < self.search_ms
 
 
 class AdmissionQueue:
@@ -297,6 +347,10 @@ class AdmissionQueue:
         flushes when the bucket fills, when a deadlined neighbor is due, or
         at the ``max_wait_s`` age bound.
         """
+        with jax.profiler.TraceAnnotation("serve.submit", rid=self._next_rid):
+            return self._admit(q_terms, q_weights, deadline_ms)
+
+    def _admit(self, q_terms, q_weights, deadline_ms: Optional[float]) -> int:
         if deadline_ms is not None and deadline_ms <= 0:
             raise ValueError(f"deadline_ms must be positive, got {deadline_ms}")
         qt = np.asarray(q_terms, dtype=np.int32).reshape(-1)
@@ -438,13 +492,6 @@ class AdmissionQueue:
             # sit in one batch so the while_loop tail tracks the batch, not
             # the stream (stable sort: FIFO among equal predictions)
             batch.sort(key=lambda r: self.survivors.predict(r.lq_eff))
-        # rows [n:] stay inert sentinels (all pad ids, zero weights): cheaper
-        # than repeating the last request, which burned DAAT while_loop work
-        # on a duplicate's survivors
-        qt, qw = sentinel_rows(shape, bucket, self.server.index.n_terms)
-        for i, r in enumerate(batch):
-            t, w = pad_to_width(r.q_terms, r.q_weights, bucket, self.server.index.n_terms)
-            qt[i], qw[i] = t, w
         r_oldest = min(batch, key=lambda r: r.deadline_s)
         oldest = r_oldest.deadline_s
         rho: Optional[int] = None
@@ -466,49 +513,70 @@ class AdmissionQueue:
         # infeasibility judgement below must account degradation as meeting
         # the deadline it was chosen to meet, not as missing full-rho's
         predicted_ms = self.server.predict_service_ms(shape, bucket, rho=rho)
-        res = self.server.search_batch(qt, qw, rho=rho)
-        scores = np.asarray(jax.device_get(res.scores))
-        ids = np.asarray(jax.device_get(res.doc_ids))
-        # the pod serve step returns only the merged (scores, ids) — per-rank
-        # WorkStats never cross the merge — so survivor feedback is best-effort
-        stats = getattr(res, "stats", None) if daat else None
-        if stats is not None:
-            survivors = np.asarray(jax.device_get(stats.n_survivors))
+        span = jax.profiler.TraceAnnotation
+        with span("serve.flush", flush=len(self.flush_log), bucket=bucket, shape=shape,
+                  n_real=n, rho=rho):
+            n_terms = self.server.index.n_terms
+            with span("serve.pad"):
+                # rows [n:] stay inert sentinels (all pad ids, zero weights):
+                # cheaper than repeating the last request, which burned DAAT
+                # while_loop work on a duplicate's survivors
+                qt, qw = sentinel_rows(shape, bucket, n_terms)
+                for i, r in enumerate(batch):
+                    qt[i], qw[i] = pad_to_width(r.q_terms, r.q_weights, bucket, n_terms)
+            padded = self.clock.now()
+            res = self.server.search_batch(qt, qw, rho=rho)
+            searched = self.server.dispatch_log[-1]
+            with span("serve.fetch"):
+                scores = np.asarray(jax.device_get(res.scores))
+                ids = np.asarray(jax.device_get(res.doc_ids))
+            # fetch: the rest of the time since padding, past search_batch's parts
+            fetch_ms = (self.clock.now() - padded) * 1e3 - searched.search_ms
+            # the pod serve step returns only the merged (scores, ids) — per-rank
+            # WorkStats never cross the merge — so survivor feedback is best-effort
+            stats = getattr(res, "stats", None) if daat else None
+            if stats is not None:
+                survivors = np.asarray(jax.device_get(stats.n_survivors))
+                for i, r in enumerate(batch):
+                    self.survivors.observe(r.lq_eff, float(survivors[i]))
             for i, r in enumerate(batch):
-                self.survivors.observe(r.lq_eff, float(survivors[i]))
-        for i, r in enumerate(batch):
-            self._completions.append(
-                Completion(
-                    rid=r.rid,
-                    scores=scores[i],
-                    doc_ids=ids[i],
-                    arrival_s=r.arrival_s,
+                self._completions.append(
+                    Completion(
+                        rid=r.rid,
+                        scores=scores[i],
+                        doc_ids=ids[i],
+                        arrival_s=r.arrival_s,
+                        flush_s=now,
+                        deadline_s=r.deadline_s,
+                        bucket=bucket,
+                        batch_shape=shape,
+                        rho=rho,
+                    )
+                )
+            self.n_completed += n
+            due = oldest - predicted_ms / 1e3  # violation boundary excludes safety headroom
+            infeasible = due <= r_oldest.arrival_s + _EPS_S  # unmeetable at admission
+            self.flush_log.append(
+                FlushRecord(
                     flush_s=now,
-                    deadline_s=r.deadline_s,
                     bucket=bucket,
                     batch_shape=shape,
+                    n_real=n,
+                    rids=tuple(r.rid for r in batch),
                     rho=rho,
+                    predicted_ms=predicted_ms,
+                    oldest_deadline_s=oldest,
+                    reason=reason,
+                    violation=bool(now > due + _EPS_S) and not infeasible and reason != "drain",
+                    infeasible=infeasible,
+                    generation=getattr(self.server, "generation", 0),
+                    pad_ms=(padded - now) * 1e3,
+                    prep_ms=searched.prep_ms,
+                    dispatch_ms=searched.dispatch_ms,
+                    wait_ms=searched.wait_ms,
+                    fetch_ms=fetch_ms,
                 )
             )
-        self.n_completed += n
-        due = oldest - predicted_ms / 1e3  # violation boundary excludes safety headroom
-        infeasible = due <= r_oldest.arrival_s + _EPS_S  # unmeetable at admission
-        self.flush_log.append(
-            FlushRecord(
-                flush_s=now,
-                bucket=bucket,
-                batch_shape=shape,
-                n_real=n,
-                rids=tuple(r.rid for r in batch),
-                rho=rho,
-                predicted_ms=predicted_ms,
-                oldest_deadline_s=oldest,
-                reason=reason,
-                violation=bool(now > due + _EPS_S) and not infeasible and reason != "drain",
-                infeasible=infeasible,
-                generation=getattr(self.server, "generation", 0),
-            )
-        )
 
     # ------------------------------ reporting ------------------------------
 
@@ -519,6 +587,10 @@ class AdmissionQueue:
     @property
     def n_infeasible(self) -> int:
         return sum(1 for f in self.flush_log if f.infeasible)
+
+    @property
+    def n_slow(self) -> int:
+        return sum(1 for f in self.flush_log if f.slow)
 
     @property
     def n_degraded(self) -> int:
@@ -574,6 +646,19 @@ class AdmissionQueue:
             "repro_queue_degraded_total",
             "Flushes served below the full posting budget",
         ).labels(**base).inc(self.n_degraded)
+        reg.counter(
+            "repro_queue_slow_flush_total",
+            f"Flushes whose search took over {SLOW_FLUSH_FACTOR}x their predicted service",
+        ).labels(**base).inc(self.n_slow)
+        seconds = reg.histogram(
+            "repro_queue_flush_seconds",
+            "Flush service seconds by part (pad, prep, dispatch, wait, fetch) and in all "
+            "(service)",
+            buckets=_FLUSH_SECONDS_BUCKETS,
+        )
+        for f in self.flush_log:
+            for part in FLUSH_PARTS + ("service",):
+                seconds.labels(**base, part=part).observe(getattr(f, f"{part}_ms") / 1e3)
         depth = reg.gauge("repro_queue_depth", "Pending requests per Lq bucket lane")
         for bucket, lane in sorted(self._pending.items()):
             depth.labels(**base, bucket=str(bucket)).set(len(lane))

@@ -113,6 +113,24 @@ class ServingConfig:
     lq_buckets: Optional[tuple[int, ...]] = None
 
 
+@dataclasses.dataclass(frozen=True)
+class DispatchRecord:
+    """One :meth:`AnytimeServer.search_batch` call, by part, in ms on the
+    server's clock. The three parts tile the call; each runs under the host
+    span of the same name (``serve.prep``, ``serve.dispatch``,
+    ``serve.wait``)."""
+
+    batch: int  # rows dispatched, pad rows included
+    rho: Optional[int]  # ladder level served; None for the daat engine
+    prep_ms: float  # merge repeated terms, bucketize, copy to the device
+    dispatch_ms: float  # the engine call, which returns before the device ends
+    wait_ms: float  # block_until_ready: the device finishing the batch
+
+    @property
+    def search_ms(self) -> float:
+        return self.prep_ms + self.dispatch_ms + self.wait_ms
+
+
 @dataclasses.dataclass
 class _CostModel:
     """us per million postings, learned online per rho level.
@@ -231,8 +249,7 @@ class AnytimeServer:
         self.cfg = cfg
         self.clock: Clock = clock if clock is not None else SystemClock()
         self.generation = self.handle.generation if self.handle is not None else 0
-        self._latencies_ms: list[float] = []
-        self._rhos: list[int] = []
+        self.dispatch_log: list[DispatchRecord] = []
         self._cost = _CostModel({}, cfg.ema_alpha, clock=self.clock)
         # whole-batch wall-ms EMA keyed by (engine, Lq bucket, batch shape,
         # rho): a batch runs as ONE executable whose wall time is far from
@@ -605,42 +622,42 @@ class AnytimeServer:
         return jnp.asarray(qt, jnp.int32), jnp.asarray(qw, jnp.float32), bucket
 
     def search_batch(self, q_terms: jax.Array, q_weights: jax.Array, rho: Optional[int] = None):
+        """Serve one ``[B, Lq]`` batch and wait for it; appends its
+        :class:`DispatchRecord` to ``dispatch_log``."""
         if self.cfg.engine == "daat":
             if rho is not None:
                 raise ValueError(
                     "rho is a SAAT posting budget; the daat engine's cost is "
                     "data-dependent and cannot honor it"
                 )
-            t0 = self.clock.now()  # bucketize is service cost: keep it timed
-            q_terms, q_weights, bucket = self._bucketize(q_terms, q_weights)
-            res = self.engine_fn()(q_terms, q_weights)
-            jax.block_until_ready(res.scores)
-            elapsed = (self.clock.now() - t0) * 1e3
-            per_query = elapsed / q_terms.shape[0]
-            self._latencies_ms.extend([per_query] * q_terms.shape[0])
-            self._rhos.extend([0] * q_terms.shape[0])
-            self._observe_bucket_ms(bucket, q_terms.shape[0], elapsed)
-            return res
         # an explicit rho must be a real ladder level: `rho or pick_rho()`
         # silently routed rho=0 (any falsy budget) to the controller
-        if rho is None:
+        elif rho is None:
             rho = self.pick_rho()
         elif rho not in self.rho_ladder:
             raise ValueError(
                 f"rho={rho!r} is not a ladder level {self.rho_ladder}; explicit "
                 "budgets must hit a pre-compiled executable"
             )
+        span = jax.profiler.TraceAnnotation
         t0 = self.clock.now()  # bucketize is service cost: keep it timed
-        q_terms, q_weights, bucket = self._bucketize(q_terms, q_weights)
-        res = self.engine_fn(self.served_rho(rho, bucket))(q_terms, q_weights)
-        jax.block_until_ready(res.scores)
-        elapsed = (self.clock.now() - t0) * 1e3
-        per_query = elapsed / q_terms.shape[0]
-        for _ in range(q_terms.shape[0]):
-            self._latencies_ms.append(per_query)
-            self._rhos.append(rho)
-        self._cost.update(rho, per_query * 1e3)
-        self._observe_bucket_ms(bucket, q_terms.shape[0], elapsed, rho=rho)
+        with span("serve.prep"):
+            q_terms, q_weights, bucket = self._bucketize(q_terms, q_weights)
+        t1 = self.clock.now()
+        with span("serve.dispatch"):
+            res = self.engine_fn(self.served_rho(rho, bucket))(q_terms, q_weights)
+        t2 = self.clock.now()
+        with span("serve.wait"):
+            jax.block_until_ready(res.scores)
+        t3 = self.clock.now()
+        rec = DispatchRecord(
+            batch=int(q_terms.shape[0]), rho=rho, prep_ms=(t1 - t0) * 1e3,
+            dispatch_ms=(t2 - t1) * 1e3, wait_ms=(t3 - t2) * 1e3,
+        )
+        self.dispatch_log.append(rec)
+        if rho is not None:
+            self._cost.update(rho, rec.search_ms / rec.batch * 1e3)
+        self._observe_bucket_ms(bucket, rec.batch, rec.search_ms, rho=rho)
         return res
 
     def warmup(
@@ -689,11 +706,14 @@ class AnytimeServer:
                     self._observe_bucket_ms(bucket, B, batch_ms, rho=rho)
 
     def stats(self) -> LatencyStats:
-        return summarize_latencies(self._latencies_ms)
+        """Per-query latency: each dispatch's search time over its rows, once
+        for every row."""
+        return summarize_latencies(
+            [d.search_ms / d.batch for d in self.dispatch_log for _ in range(d.batch)]
+        )
 
     def reset_stats(self):
-        self._latencies_ms.clear()
-        self._rhos.clear()
+        self.dispatch_log.clear()
 
     def export_counters(self, registry=None):
         """Scrape-time serving counters for this server's dispatch surface.
@@ -708,7 +728,7 @@ class AnytimeServer:
         reg = registry if registry is not None else CounterRegistry()
         reg.counter(
             "repro_server_queries_total", "Queries served (per-request rows)"
-        ).labels(engine=self.cfg.engine).inc(len(self._latencies_ms))
+        ).labels(engine=self.cfg.engine).inc(sum(d.batch for d in self.dispatch_log))
         cal = reg.gauge(
             "repro_server_calibrated_shapes",
             "Directly measured (bucket, batch-shape, rho) executables",

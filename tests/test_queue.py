@@ -989,3 +989,88 @@ def test_replay_effectiveness_empty_schedule(bm25_index, bm25_queries):
     assert rep["violations"] == 0 and rep["infeasible"] == 0
     assert rep["overall"]["mrr"] == 0.0 and rep["overall"]["recall"] == 0.0
     assert rep["wait_ms"]["p99_ms"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# flush timings, the slow-flush judgement and their counter families
+# ---------------------------------------------------------------------------
+
+
+def _timed_engine(srv, clock, seconds):
+    """Make each engine dispatch take the next of ``seconds`` on ``clock``."""
+    inner, todo = srv.engine_fn, iter(seconds)
+
+    def engine_fn(rho=None):
+        fn = inner(rho)
+
+        def run(qt, qw):
+            clock.advance(next(todo))
+            return fn(qt, qw)
+
+        return run
+
+    srv.engine_fn = engine_fn
+
+
+def test_flush_parts_tile_the_flush_on_a_real_clock(bm25_index, bm25_queries):
+    qt, qw = bm25_queries
+    clock = HybridClock()
+    srv = _queue_server(bm25_index, qt.shape[1], clock=clock)
+    q = AdmissionQueue(srv, batch_shapes=(2, 4), clock=clock)
+    t3, w3 = np.array([1, 2, 3], np.int32), np.ones(3, np.float32)
+    q.submit(t3, w3, deadline_ms=100.0)
+    before = clock.now()
+    q.drain()
+    after = clock.now()
+    (f,) = q.flush_log
+    parts = [f.pad_ms, f.prep_ms, f.dispatch_ms, f.wait_ms, f.fetch_ms]
+    assert min(parts) >= 0.0 and f.dispatch_ms > 0.0
+    assert f.service_ms == pytest.approx(sum(parts))
+    assert f.service_ms <= (after - before) * 1e3
+    # the server's own record of the dispatch is the flush's middle
+    d = srv.dispatch_log[-1]
+    assert (d.batch, d.prep_ms, d.dispatch_ms, d.wait_ms) == (
+        2, f.prep_ms, f.dispatch_ms, f.wait_ms)
+    assert f.search_ms == pytest.approx(d.search_ms)
+
+
+def test_a_flush_twice_its_prediction_counts_as_slow(bm25_index, bm25_queries):
+    qt, qw = bm25_queries
+    clock = SimulatedClock()
+    srv = _queue_server(bm25_index, qt.shape[1], clock=clock)
+    q = AdmissionQueue(srv, batch_shapes=(2,), clock=clock)
+    # the first flush has no prediction and calibrates the shape to 100 ms;
+    # the second takes 2x that; the third 1.2x the EMA the second left (120)
+    _timed_engine(srv, clock, [0.100, 0.200, 0.144])
+    t3, w3 = np.array([1, 2, 3], np.int32), np.ones(3, np.float32)
+    for _ in range(6):
+        q.submit(t3, w3, deadline_ms=1e6)
+    first, second, third = q.flush_log
+    assert first.predicted_ms == 0.0 and not first.slow
+    assert second.predicted_ms == pytest.approx(100.0)
+    assert second.dispatch_ms == pytest.approx(200.0) and second.slow
+    assert third.predicted_ms == pytest.approx(120.0)
+    assert third.search_ms == pytest.approx(144.0) and not third.slow
+    # on a simulated clock only the scripted device time passes
+    assert [f.service_ms for f in q.flush_log] == pytest.approx([100.0, 200.0, 144.0])
+    assert q.n_slow == 1
+    d = q.export_counters().as_dict()
+    assert d["repro_queue_slow_flush_total"]["samples"] == [{"labels": {}, "value": 1.0}]
+    by_part = {s["labels"]["part"]: s for s in d["repro_queue_flush_seconds"]["samples"]}
+    assert set(by_part) == {"pad", "prep", "dispatch", "wait", "fetch", "service"}
+    assert by_part["dispatch"]["count"] == 3
+    assert by_part["service"]["sum"] == pytest.approx(0.444)
+    assert by_part["dispatch"]["buckets"]["0.1"] == 1  # only the first took <= 100 ms
+
+
+def test_slow_and_flush_seconds_families_exist_before_any_flush(bm25_index, bm25_queries):
+    qt, _ = bm25_queries
+    q = AdmissionQueue(_queue_server(bm25_index, qt.shape[1]), batch_shapes=(2,))
+    reg = q.export_counters(labels={"host": "0"})
+    d = reg.as_dict()
+    assert d["repro_queue_slow_flush_total"]["samples"] == [
+        {"labels": {"host": "0"}, "value": 0.0}]
+    assert d["repro_queue_flush_seconds"]["type"] == "histogram"
+    text = reg.render()
+    assert 'repro_queue_slow_flush_total{host="0"} 0' in text
+    assert "# TYPE repro_queue_flush_seconds histogram" in text

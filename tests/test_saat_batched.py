@@ -133,3 +133,49 @@ def test_max_segments_cached_without_device_sync(bm25_index):
     assert bm25_index.max_segs > 0
     assert max_segments_per_term(bm25_index) == bm25_index.max_segs
     assert bm25_index.max_segs == int(np.asarray(bm25_index.term_seg_count).max())
+
+
+SAAT_PHASES = {"saat.plan", "saat.slots", "saat.gather", "saat.select"}
+_TRIVIAL_OPS = {"parameter", "constant", "tuple", "get-tuple-element"}
+
+
+def _entry_scopes(hlo_text: str) -> list[tuple[str, str, set]]:
+    """(instruction, opcode, its saat.* scopes) for each non-trivial
+    instruction of a module's entry computation that carries an op_name
+    (``None`` in place of the set where it carries none)."""
+    import re
+
+    entry = hlo_text[hlo_text.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    out = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\((%?[\w.\-]*)", line)
+        if m is None or m.group(2) in _TRIVIAL_OPS:
+            continue
+        if m.group(2) == "broadcast" and m.group(3).lstrip("%").startswith("constant"):
+            continue  # a splat of a literal
+        op = re.search(r'op_name="([^"]*)"', line)
+        scopes = None if op is None else {p for p in op.group(1).split("/") if p in SAAT_PHASES}
+        out.append((m.group(1), m.group(2), scopes))
+    return out
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+def test_every_saat_op_lies_in_one_phase_scope(bm25_index, bm25_queries, fused):
+    """A profiler trace names the SAAT step's device time by phase from the
+    ``saat.*`` scope on each operation's metadata. Every operation the engine
+    emits lies in exactly one of the four phases, and every phase has some;
+    after the compiler's fusions, every entry instruction that keeps a name
+    keeps its phase."""
+    qt, qw = bm25_queries
+    lowered = saat_search.lower(
+        bm25_index, jnp.asarray(qt[:4]), jnp.asarray(qw[:4]), k=10, rho=500,
+        max_segs_per_term=max_segments_per_term(bm25_index), scatter_impl="sort",
+        fused_topk=fused,
+    )
+    emitted = _entry_scopes(lowered.as_text(dialect="hlo", debug_info=True))
+    assert [(n, op) for n, op, s in emitted if s is None or len(s) != 1] == []
+    assert set().union(*(s for _, _, s in emitted)) == SAAT_PHASES
+    compiled = _entry_scopes(lowered.compile().as_text())
+    assert [(n, op) for n, op, s in compiled if s is not None and len(s) != 1] == []
+    assert set().union(*(s for _, _, s in compiled if s)) == SAAT_PHASES

@@ -115,7 +115,7 @@ def test_search_batch_rejects_off_ladder_rho(bm25_index, bm25_queries):
         srv.search_batch(jnp.asarray(qt[:2]), jnp.asarray(qw[:2]), rho=777)
     # a real ladder level is honored verbatim
     srv.search_batch(jnp.asarray(qt[:2]), jnp.asarray(qw[:2]), rho=100)
-    assert srv._rhos[-2:] == [100, 100]
+    assert (srv.dispatch_log[-1].rho, srv.dispatch_log[-1].batch) == (100, 2)
 
 
 @pytest.mark.serving
@@ -164,7 +164,7 @@ def test_run_query_stream_ragged_final_batch(bm25_index, bm25_queries):
     np.testing.assert_array_equal(ids, np.asarray(one.doc_ids))
     np.testing.assert_array_equal(scores, np.asarray(one.scores))
     # the repeated pad rows were served but never reported
-    assert len(srv._latencies_ms) == 12 + N  # 3 batches of 4, then the direct call
+    assert [d.batch for d in srv.dispatch_log] == [bs, bs, bs, N]  # then the direct call
 
 
 @pytest.mark.serving
@@ -284,9 +284,12 @@ def test_predict_service_ms_is_shape_keyed_not_linear_in_b(bm25_index, bm25_quer
     qt, qw = bm25_queries
     L = qt.shape[1]
     # scripted service times: the B=8 batch takes 10 ms, the B=32 batch 16
-    # ms. A SAAT search_batch reads the clock exactly three times (start,
-    # stop, cost-model calibration stamp) — the script covers two calls.
-    clock = _ScriptedClock([0.0, 0.010, 0.010, 0.010, 0.026, 0.026])
+    # ms. A SAAT search_batch reads the clock exactly five times (start, the
+    # ends of its prep, dispatch and wait, the cost-model calibration stamp)
+    # — the script covers two calls, each spending its time in the wait.
+    clock = _ScriptedClock(
+        [0.0, 0.0, 0.0, 0.010, 0.010] + [0.010, 0.010, 0.010, 0.026, 0.026]
+    )
     srv = AnytimeServer(
         bm25_index,
         ServingConfig(k=5, rho_ladder=(10**9,), lq_buckets=(L,)),
@@ -300,6 +303,7 @@ def test_predict_service_ms_is_shape_keyed_not_linear_in_b(bm25_index, bm25_quer
     p32 = srv.predict_service_ms(32, L)
     assert p8 == pytest.approx(10.0)
     assert p32 == pytest.approx(16.0)  # observed, NOT 4 * p8 = 40 ms
+    assert [d.wait_ms for d in srv.dispatch_log] == pytest.approx([10.0, 16.0])
     assert p32 != pytest.approx(4 * p8)
     # nearest-shape fallback: a smaller unseen shape borrows the closest
     # executable's time unscaled (over-predicts, safe) ...
