@@ -7,17 +7,18 @@ is bounded by construction.
 
 TPU adaptation (DESIGN.md §2): ``rho`` becomes a *static tensor shape*. The
 plan step orders candidate segments by contribution; the execute step maps the
-first ``rho`` posting slots onto (segment, offset) pairs with a vectorized
-``searchsorted`` over the segment-length prefix sum, gathers doc ids, and
-scatter-adds contributions into a dense accumulator. Every query therefore
-executes the *identical* instruction stream — the strongest possible form of
-the paper's "SAAT has predictable latency" claim, and simultaneously the
-straggler-mitigation primitive for multi-pod serving.
+first ``rho`` posting slots onto (segment, offset) pairs over the
+segment-length prefix sum, gathers doc ids, and scatter-adds contributions
+into a dense accumulator. Every query therefore executes the *identical*
+instruction stream — the strongest possible form of the paper's "SAAT has
+predictable latency" claim, and simultaneously the straggler-mitigation
+primitive for multi-pod serving.
 
 The engine is *natively batched*: a ``[B, Lq]`` query batch runs one batched
-argsort in the planner, one histogram-based batched ``searchsorted`` in the
-posting gather, and one batch-aware scatter — a single executable per
-(k, rho) configuration, not ``B`` vmapped single-query programs. ``saat_search_vmap`` keeps the original
+argsort in the planner, one delta scatter and prefix sum that expand the plan
+entries over the posting slots (then one doc-id read per slot), and one
+batch-aware scatter — a single executable per (k, rho) configuration, not
+``B`` vmapped single-query programs. ``saat_search_vmap`` keeps the original
 ``jax.vmap(one-query)`` formulation as a parity oracle and benchmark baseline
 (``benchmarks/side_batched_vs_vmap.py``).
 
@@ -122,22 +123,6 @@ def saat_plan(
     )
 
 
-def _batched_searchsorted_slots(cum: jax.Array, rho: int) -> jax.Array:
-    """Row-wise ``searchsorted(cum[b], arange(rho), side='right')`` without vmap.
-
-    Because the queries are the *sorted* slot ids ``0..rho-1``, the binary
-    search collapses to a counting argument: ``j[b, p] = #{i : cum[b, i] <= p}``
-    is the prefix sum of a histogram of ``cum`` values. One batched
-    ``[B, n_cand]`` scatter-add plus one batched ``[B, rho]`` cumsum —
-    integer ops only, so bit-identical to ``jnp.searchsorted``.
-    """
-    B = cum.shape[0]
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-    bins = jnp.clip(cum, 0, rho)  # bin rho collects entries past the budget
-    hist = jnp.zeros((B, rho + 1), jnp.int32).at[rows, bins].add(1)
-    return jnp.cumsum(hist[:, :rho], axis=-1)
-
-
 def _gather_postings(
     index: ImpactIndex, plan: SaatPlan, rho: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -156,30 +141,52 @@ def _gather_postings(
     return docs, contribs, n_processed
 
 
+def _slot_values(plan: SaatPlan, rho: int) -> jax.Array:
+    """``[2, B, rho]``: each slot's ``starts - first`` and contribution bits.
+
+    Slot ``p`` belongs to plan entry ``j(p) = #{i : cum_len[i] <= p}`` (the
+    row-wise ``searchsorted(cum_len, p, side='right')``, clamped to the last
+    entry), and that map is monotone in ``p``. So an entry's value at every
+    slot is a prefix sum: put ``v[i] - v[i-1]`` at entry ``i``'s first slot
+    ``first[i] = cum_len[i-1]`` (0 for ``i = 0``) and the sum over slots
+    ``<= p`` telescopes to ``v[j(p)]``. Entries that start at or past the
+    budget land in bin ``rho``, which is dropped. The two values are the
+    posting-store base ``starts - first`` (so ``pidx = p + base``) and the
+    contribution's float32 bits as int32: int32 addition wraps exactly, so
+    both come back bit for bit. One ``[2, B, n_cand]`` scatter-add and one
+    ``[2, B, rho]`` cumsum, with no per-slot gather of the plan.
+    """
+    B = plan.cum_len.shape[0]
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    first = jnp.concatenate(
+        [jnp.zeros((B, 1), jnp.int32), plan.cum_len[:, :-1]], axis=-1
+    )
+    bits = jax.lax.bitcast_convert_type(plan.contribs, jnp.int32)
+    vals = jnp.stack([plan.starts - first, bits])  # [2, B, n_cand]
+    deltas = vals - jnp.pad(vals[..., :-1], ((0, 0), (0, 0), (1, 0)))
+    bins = jnp.minimum(first, rho)  # bin rho collects entries past the budget
+    slots = jnp.zeros((2, B, rho + 1), jnp.int32).at[:, rows, bins].add(deltas)
+    return jnp.cumsum(slots[..., :rho], axis=-1)
+
+
 def _gather_postings_batched(
     index: ImpactIndex, plan: SaatPlan, rho: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Batched slot -> posting map: one histogram searchsorted over [B, rho].
+    """Batched slot -> posting map: a delta scatter and one prefix sum over
+    [B, rho] expand the plan entries, then one doc-id read per slot.
 
-    Two device phases, each under its own scope: ``saat.slots`` (the slot ->
-    plan-entry search) and ``saat.gather`` (the posting reads).
+    Two device phases, each under its own scope: ``saat.slots`` (the
+    expansion of plan entries over the slots, :func:`_slot_values`) and
+    ``saat.gather`` (the posting reads).
     """
-    B, n_cand = plan.cum_len.shape
-    # the slot ids come first, as the traced program (and the hot-path
-    # lint's fingerprint of it) has always had them
-    with jax.named_scope("saat.gather"):
-        p = jnp.broadcast_to(jnp.arange(rho, dtype=jnp.int32), (B, rho))
     with jax.named_scope("saat.slots"):
-        j = _batched_searchsorted_slots(plan.cum_len, rho)
+        base, bits = _slot_values(plan, rho)
     with jax.named_scope("saat.gather"):
-        j = jnp.minimum(j, n_cand - 1)
-        prev_cum = jnp.take_along_axis(plan.cum_len, jnp.maximum(j - 1, 0), axis=-1)
-        prev = jnp.where(j > 0, prev_cum, 0)
-        offset = p - prev
-        pidx = jnp.take_along_axis(plan.starts, j, axis=-1) + offset
+        p = jnp.arange(rho, dtype=jnp.int32)
         valid = p < plan.total_postings[:, None]
-        docs = index.doc_ids[jnp.where(valid, pidx, 0)]
-        contribs = jnp.where(valid, jnp.take_along_axis(plan.contribs, j, axis=-1), 0.0)
+        docs = index.doc_ids[jnp.where(valid, p + base, 0)]
+        contribs = jax.lax.bitcast_convert_type(bits, jnp.float32)
+        contribs = jnp.where(valid, contribs, 0.0)
         docs = jnp.where(valid, docs, 0)
         n_processed = jnp.minimum(plan.total_postings, rho).astype(jnp.int32)
     return docs, contribs, n_processed
@@ -280,8 +287,9 @@ def saat_search(
     ``rho >= index.n_postings`` (the executor stops at the query's own total).
 
     The whole batch is one executable per (k, rho, scatter_impl): the planner
-    runs one batched argsort, the gather one batched binary search, and the
-    scatter one batch-aware kernel launch — no per-query vmapped programs.
+    runs one batched argsort, the slot expansion one delta scatter and prefix
+    sum, the gather one doc-id read per slot, and the scatter one
+    batch-aware kernel launch — no per-query vmapped programs.
     Its device work falls in four named scopes, which a profiler trace
     keeps on each operation: ``saat.plan``, ``saat.slots``, ``saat.gather``
     and ``saat.select`` (scatter, pad mask and top-k, fused or not).

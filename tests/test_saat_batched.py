@@ -5,12 +5,20 @@ The batched engine must be indistinguishable from the legacy vmap path
 oracle at a rank-safe rho — for every scatter_impl, including ragged batches
 with zero-weight pad terms and budgets past the total posting count.
 """
+from types import SimpleNamespace
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import exact_rho, exhaustive_search, saat_search, saat_search_vmap
-from repro.core.saat import max_segments_per_term, saat_plan
+from repro.core.saat import (
+    SaatPlan,
+    _gather_postings_batched,
+    max_segments_per_term,
+    saat_plan,
+)
 from repro.metrics.ir_metrics import rank_overlap
 
 SCATTER_IMPLS = ("jnp", "sort", "pallas")
@@ -133,6 +141,72 @@ def test_max_segments_cached_without_device_sync(bm25_index):
     assert bm25_index.max_segs > 0
     assert max_segments_per_term(bm25_index) == bm25_index.max_segs
     assert bm25_index.max_segs == int(np.asarray(bm25_index.term_seg_count).max())
+
+
+def _take_along_axis_gather(doc_ids, plan, rho):
+    """The slot -> posting map as per-slot ``take_along_axis`` gathers of the
+    plan: the row-wise ``searchsorted`` of the slots over ``cum_len`` by a
+    histogram of its entries, then the entry's start, its predecessor's
+    ``cum_len`` and its contribution read at every slot."""
+    B, n_cand = plan.cum_len.shape
+    rows = jnp.arange(B)[:, None]
+    hist = jnp.zeros((B, rho + 1), jnp.int32).at[rows, jnp.clip(plan.cum_len, 0, rho)].add(1)
+    j = jnp.minimum(jnp.cumsum(hist[:, :rho], axis=-1), n_cand - 1)
+    p = jnp.broadcast_to(jnp.arange(rho, dtype=jnp.int32), (B, rho))
+    prev_cum = jnp.take_along_axis(plan.cum_len, jnp.maximum(j - 1, 0), axis=-1)
+    pidx = jnp.take_along_axis(plan.starts, j, axis=-1) + p - jnp.where(j > 0, prev_cum, 0)
+    valid = p < plan.total_postings[:, None]
+    docs = jnp.where(valid, doc_ids[jnp.where(valid, pidx, 0)], 0)
+    contribs = jnp.where(valid, jnp.take_along_axis(plan.contribs, j, axis=-1), 0.0)
+    return docs, contribs
+
+
+# Hand-made plans: segment lengths per row (one row per list), how the
+# contributions are drawn, and the budget.
+_PLAN_CASES = {
+    "zero_len_first_middle_last": ([[0, 3, 0, 5, 0, 2, 0], [0, 0, 4, 1, 0, 0, 6]], "random", 64),
+    "pad_row": ([[3, 7, 2, 9], [0, 0, 0, 0], [5, 0, 1, 4]], "random", 128),
+    "total_below_rho": ([[4, 2, 6], [1, 1, 1]], "random", 200),
+    "total_above_rho_entries_past_budget": (
+        [[40, 30, 50, 20, 60, 0, 10], [100, 90, 1, 1, 0, 3, 80]], "random", 100),
+    "total_at_rho": ([[60, 40, 28], [128, 0, 0]], "random", 128),
+    "single_entry": ([[17], [0], [300]], "random", 256),
+    "equal_contribs": ([[5, 0, 9, 3, 11], [2, 2, 2, 2, 2]], "equal", 64),
+    "signed_zero_and_negative": ([[3, 4, 0, 5], [6, 0, 7, 2]], "signed", 200),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLAN_CASES))
+def test_slot_expansion_matches_take_along_axis_gathers(case):
+    """The delta scatter and prefix sum give every slot the doc id and the
+    contribution *bits* that per-slot gathers of the plan give."""
+    lens, contrib_mode, rho = _PLAN_CASES[case]
+    lens = np.asarray(lens, np.int32)
+    rng = np.random.default_rng(sorted(_PLAN_CASES).index(case))
+    n_post = 4096
+    # zero-length entries carry a start and a contribution too: no slot may see them
+    starts = rng.integers(0, n_post - lens.max() + 1, lens.shape)
+    if contrib_mode == "equal":
+        contribs = np.full(lens.shape, 0.1, np.float32)
+    elif contrib_mode == "signed":
+        contribs = rng.choice(np.array([0.0, -0.0, -1.5, 3.25e-8], np.float32), lens.shape)
+    else:
+        contribs = rng.random(lens.shape, dtype=np.float32) * 40.0
+    cum = np.cumsum(lens, axis=-1, dtype=np.int32)
+    plan = SaatPlan(
+        starts=jnp.asarray(starts, jnp.int32), contribs=jnp.asarray(contribs),
+        cum_len=jnp.asarray(cum), total_postings=jnp.asarray(cum[:, -1]),
+    )
+    doc_ids = jnp.asarray(rng.permutation(n_post).astype(np.int32))
+    docs, got, n_proc = jax.jit(
+        lambda d, pl: _gather_postings_batched(SimpleNamespace(doc_ids=d), pl, rho)
+    )(doc_ids, plan)
+    want_docs, want = _take_along_axis_gather(doc_ids, plan, rho)
+    np.testing.assert_array_equal(np.asarray(docs), np.asarray(want_docs))
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.int32), np.asarray(want).view(np.int32)
+    )
+    np.testing.assert_array_equal(np.asarray(n_proc), np.minimum(cum[:, -1], rho))
 
 
 SAAT_PHASES = {"saat.plan", "saat.slots", "saat.gather", "saat.select"}

@@ -7,6 +7,7 @@ with ``interpret=False`` for a v5e that is described, not attached, at the
 widths ``chip_smoke.py`` serves: a batch of 32 queries against a
 262,144-passage index (``repro.launch.smoke.DEPLOYMENT``). Nothing runs;
 the compiled executable must contain the Mosaic kernel (``tpu_custom_call``).
+One case compiles the whole batched SAAT step and reads its gathers.
 """
 import os
 
@@ -123,3 +124,64 @@ def test_chunk_step_compiles(one_chip, k, trips, tmax):
         ((D["n_docs"],), i32),
         sharding=one_chip,
     )
+
+
+def _gathers(hlo_text: str) -> list[tuple[str, int, str, str]]:
+    """(result dtype, result elements, operand type, op_name) of every gather
+    in a compiled module, fusion bodies included."""
+    import math
+    import re
+
+    types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo_text))
+    out = []
+    for m in re.finditer(
+        r"= (\w+)\[([\d,]*)\]\S* gather\(%([\w.\-]+),.*?(?:op_name=\"([^\"]*)\"|$)",
+        hlo_text, re.M,
+    ):
+        n = math.prod(int(d) for d in m.group(2).split(",") if d)
+        out.append((m.group(1), n, types.get(m.group(3), "?"), m.group(4) or ""))
+    return out
+
+
+def test_saat_step_reads_each_slot_once(one_chip, monkeypatch):
+    """The batched SAAT step at deployment widths expands its plan over the
+    ``B x rho`` posting slots by a scatter and a prefix sum: outside the
+    select, the only gather of ``B x rho`` elements is the doc-id read of the
+    posting store, under ``saat.gather``. Per-slot gathers of the plan's
+    tables (``take_along_axis``) cost as much a slot as the posting read."""
+    import importlib
+
+    from repro.core.impact_index import ImpactIndex
+    from repro.core.saat import saat_search
+
+    # the engine asks the backend, which is the CPU here, whether to interpret
+    fused_ops = importlib.import_module("repro.kernels.impact_scatter_topk.ops")
+    monkeypatch.setattr(fused_ops, "interpret_default", lambda: False)
+    n_docs, lq, rho, tmax = D["n_docs"], D["lq"], D["rho"], D["tmax"][-1]
+    n_post = n_docs * 230  # SPLADEv2 passages hold about 230 terms
+    n_seg, vocab, nb = n_post // 10, 30522, D["n_blocks"]
+    s = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)  # noqa: E731
+    index = ImpactIndex(
+        doc_ids=s((n_post,), i32), seg_term=s((n_seg,), i32), seg_weight=s((n_seg,), f32),
+        seg_start=s((n_seg,), i32), seg_len=s((n_seg,), i32),
+        term_seg_start=s((vocab + 1,), i32), term_seg_count=s((vocab + 1,), i32),
+        term_post_count=s((vocab + 1,), i32), term_max_weight=s((vocab + 1,), f32),
+        bm_block=s((D["n_bm"],), i32), bm_weight=s((D["n_bm"],), f32),
+        term_bm_start=s((vocab + 1,), i32), term_bm_count=s((vocab + 1,), i32),
+        doc_terms=s((n_docs, tmax), i32), doc_weights=s((n_docs, tmax), f32),
+        doc_n_terms=s((n_docs,), i32), doc_weight_sum=s((n_docs,), f32),
+        n_docs=n_docs, n_terms=vocab, n_blocks=nb, block_size=D["block_size"],
+        max_doc_terms=tmax, scale=1.0, bits=8, max_segs=255, max_bm=nb,
+    )
+    text = saat_search.lower(
+        index, s((B, lq), i32), s((B, lq), f32), k=D["ks"][0], rho=rho,
+        max_segs_per_term=255, fused_topk=True,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    slot_reads = [
+        g for g in _gathers(text) if g[1] == B * rho and "saat.select" not in g[3].split("/")
+    ]
+    assert len(slot_reads) == 1, slot_reads
+    dtype, _, operand, name = slot_reads[0]
+    assert (dtype, operand) == ("s32", f"s32[{n_post}]")
+    assert "saat.gather" in name.split("/") and "take_along_axis" not in name
