@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -162,10 +163,29 @@ def _compile_counter():
 
 
 def generate(cfg: dict, seed: int):
-    """The seed's collection under the configuration's corpus parameters."""
-    corpus = collection.generate_corpus(collection.CorpusConfig(
-        n_docs=cfg["n_docs"], n_queries=cfg["n_queries"], seed=seed, **cfg["corpus"]))
-    return corpus, collection.apply_treatment(corpus, cfg["treatment"], seed=seed)
+    """The seed's collection under the configuration's corpus parameters.
+
+    Where the configuration states a ``query_seed``, its queries come from
+    that fixed stream instead: the same concept model, drawn over a corpus
+    of ``n_queries`` passages of their own. Every seed then serves the same
+    queries over its own passages, so a cell whose work follows the queries
+    (exact DAAT) does the same work for every seed. No passage of the
+    seed's collection was drawn for those queries, so ``qrels`` are -1."""
+
+    def draw(n_docs: int, n_queries: int, s: int):
+        corpus = collection.generate_corpus(collection.CorpusConfig(
+            n_docs=n_docs, n_queries=n_queries, seed=s, **cfg["corpus"]))
+        return corpus, collection.apply_treatment(corpus, cfg["treatment"], seed=s)
+
+    if "query_seed" not in cfg:
+        return draw(cfg["n_docs"], cfg["n_queries"], seed)
+    corpus, enc = draw(cfg["n_docs"], 0, seed)
+    qc, qe = draw(cfg["n_queries"], cfg["n_queries"], int(cfg["query_seed"]))
+    corpus = dataclasses.replace(corpus, query_terms=qc.query_terms,
+                                 query_concepts=qc.query_concepts,
+                                 qrels=np.full(qc.n_queries, -1, np.int32))
+    return corpus, dataclasses.replace(enc, query_terms=qe.query_terms,
+                                       query_weights=qe.query_weights)
 
 
 # The index arrays whose extent follows the collection: (field, capacity key, fill).
@@ -185,29 +205,27 @@ def _over(what: str, got, cap) -> SetupError:
                       f"capacity {cap}: raise it in the configuration's file")
 
 
-def build_index(enc, n_docs: int, capacity: dict):
-    """The program's index of the collection, fitted to ``capacity`` on the
-    host: impacts on one grid up to ``max_weight`` (a larger weight takes
-    the top impact, as the reference's does), the doc-major store
-    ``doc_terms`` wide, the posting, segment and block-max stores padded
-    past their ends (the per-term tables never reach the padding) and the
-    plan bounds at their ceilings: every segment count a term can have, and
-    every block. Its arrays and static fields are then the same for every
-    seed, and so are the executables that take it."""
+def _on_host():
+    """Build on the host's CPU device, where the process has one."""
     import jax
-    from repro.core import build_impact_index
 
+    try:
+        return jax.default_device(jax.devices("cpu")[0])
+    except RuntimeError:
+        return contextlib.nullcontext()
+
+
+def _check_longest(enc, n_docs: int, capacity: dict) -> None:
     longest = int(np.bincount(enc.doc_idx, minlength=n_docs).max())
     if longest > capacity["doc_terms"]:
         raise _over("a passage of", longest, capacity["doc_terms"])
-    try:  # built as host arrays, padded and placed once
-        host = jax.default_device(jax.devices("cpu")[0])
-    except RuntimeError:
-        host = contextlib.nullcontext()
-    with host:
-        index = build_impact_index(enc.doc_idx, enc.term_idx, enc.weights, n_docs, enc.n_terms,
-                                   max_doc_terms=capacity["doc_terms"],
-                                   quant_max_weight=capacity["max_weight"])
+
+
+def fit_index(index, capacity: dict):
+    """One index fitted to ``capacity``: the posting, segment and block-max
+    stores padded past their ends (the per-term tables never reach the
+    padding) and the plan bounds at their ceilings: every segment count a
+    term can have, and every block."""
     arrays = {}
     for field, key, fill in _RAGGED:
         a = np.asarray(getattr(index, field))
@@ -219,26 +237,81 @@ def build_index(enc, n_docs: int, capacity: dict):
                                max_bm=index.n_blocks)
 
 
+def build_index(enc, n_docs: int, capacity: dict):
+    """The program's index of the collection, fitted to ``capacity`` on the
+    host (:func:`fit_index`): impacts on one grid up to ``max_weight`` (a
+    larger weight takes the top impact, as the reference's does) and the
+    doc-major store ``doc_terms`` wide. Its arrays and static fields are
+    then the same for every seed, and so are the executables that take it."""
+    from repro.core import build_impact_index
+
+    _check_longest(enc, n_docs, capacity)
+    with _on_host():  # built as host arrays, padded and placed once
+        index = build_impact_index(enc.doc_idx, enc.term_idx, enc.weights, n_docs, enc.n_terms,
+                                   max_doc_terms=capacity["doc_terms"],
+                                   quant_max_weight=capacity["max_weight"])
+    return fit_index(index, capacity)
+
+
+def build_stack(enc, n_docs: int, capacity: dict, n_shards: int):
+    """The collection split into ``n_shards`` equal doc ranges, each shard's
+    index on the one grid up to ``max_weight`` and fitted to the per-shard
+    ``capacity`` as :func:`build_index` fits the one index, then stacked on
+    a leading shard axis on the host. Returns (stack, docs per shard)."""
+    from repro.serving.sharded import shard_corpus, stack_indexes
+
+    _check_longest(enc, n_docs, capacity)
+    with _on_host():
+        shards, docs_per_shard = shard_corpus(
+            enc.doc_idx, enc.term_idx, enc.weights, n_docs, enc.n_terms, n_shards,
+            max_doc_terms=capacity["doc_terms"], quant_max_weight=capacity["max_weight"])
+        stack = stack_indexes([fit_index(s, capacity) for s in shards])
+    return stack, docs_per_shard
+
+
 def setup(cfg: dict, trf: dict, seed: int):
-    """Collection, index on the chip, server, warm executables."""
+    """Collection, index on the chips, server, warm executables. A
+    configuration with ``"shards": S`` over 1 serves S equal doc ranges,
+    one per chip of a one-host mesh, through the program's ``PodServer``
+    and its cross-chip k-merge; its ``capacity`` is then per shard."""
     import jax
-    from repro.serving import AnytimeServer
 
     phases = {}
     t = time.perf_counter()
     corpus, enc = generate(cfg, seed)
     phases["generate_s"] = time.perf_counter() - t
+    n_shards = int(cfg.get("shards", 1))
     t = time.perf_counter()
-    index = build_index(enc, corpus.n_docs, cfg["capacity"])
+    if n_shards > 1:
+        index, docs_per_shard = build_stack(enc, corpus.n_docs, cfg["capacity"], n_shards)
+    else:
+        index = build_index(enc, corpus.n_docs, cfg["capacity"])
     phases["index_build_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    index = jax.block_until_ready(jax.device_put(index, jax.devices()[0]))
+    if n_shards > 1:
+        from jax.sharding import Mesh
+        from repro.serving import PodServer
+        from repro.serving.sharded import place_index_stack
+
+        devices = jax.devices()[:n_shards]
+        if len(devices) < n_shards:
+            raise SetupError(f"{n_shards} shards need {n_shards} chips, JAX sees {len(devices)}")
+        mesh = Mesh(np.array(devices).reshape(1, n_shards), ("pod", "model"))
+        index = jax.block_until_ready(place_index_stack(index, mesh))
+        make_server = functools.partial(PodServer, mesh, docs_per_shard=docs_per_shard,
+                                        n_docs_total=corpus.n_docs)
+    else:
+        from repro.serving import AnytimeServer
+
+        index = jax.block_until_ready(jax.device_put(index, jax.devices()[0]))
+        make_server = AnytimeServer
     phases["placement_s"] = time.perf_counter() - t
-    _log(f"index: n_docs={index.n_docs} postings={index.n_postings} bytes={index.nbytes()}")
+    _log(f"index: shards={n_shards} n_docs={index.n_docs} doc_ids={tuple(index.doc_ids.shape)} "
+         f"bytes={index.nbytes()}")
 
     t = time.perf_counter()
     lanes = list(trf["lanes"])
-    server = AnytimeServer(index, _serving_config(cfg, trf))
+    server = make_server(index, _serving_config(cfg, trf))
     engine = server.cfg.engine
     rho = int(cfg["rho"]) if engine == "saat" else None
     if rho is not None:
@@ -362,11 +435,11 @@ def run(cfg: dict, trf: dict, *, seed: int, seconds: float, traced: bool, t_star
     compiles["on"] = False
     if compiles["n"]:
         raise SetupError(f"{compiles['n']} executables were compiled inside the window")
-    dev = jax.devices()[0]
-    stats = dev.memory_stats() or {}
+    used = jax.devices()[: int(cfg.get("shards", 1))]
+    peak = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in used]
     device = {
-        "platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices()),
-        "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0)),
+        "platform": used[0].platform, "kind": used[0].device_kind, "count": len(jax.devices()),
+        "memory_peak_bytes": max(peak), "memory_peak_bytes_per_chip": peak,
     }
     run_rec = Run(
         engine=engine, k=int(trf["k"]), rho=rho, setup_s=setup_s,

@@ -55,6 +55,22 @@ def test_schedule_and_lanes_do_not_depend_on_the_seed(saat_cfg, open_trf):
     assert not np.array_equal(widths[0], widths[1])
 
 
+def test_a_query_seed_fixes_the_queries_and_not_the_passages():
+    cfg = dict(treatment="bm25", n_docs=2048, n_queries=64, query_seed=5,
+               corpus={"concepts_per_doc": 16, "terms_per_doc_concept": 5,
+                       "stopwords_per_doc": 9.4, "max_tf": 2, "concepts_per_query": 3.15,
+                       "terms_per_query_concept": 1.5})
+    (ca, a), (_, b) = harness.generate(cfg, 3), harness.generate(cfg, 2**31 + 11)
+    assert len(a.query_terms) == 64 and np.all(ca.qrels == -1)
+    for x, y, wx, wy in zip(a.query_terms, b.query_terms, a.query_weights, b.query_weights):
+        assert np.array_equal(x, y) and np.array_equal(wx, wy)
+    assert not np.array_equal(a.weights, b.weights)
+    # the passages are the seed's, as without a query seed
+    plain = harness.generate({k: v for k, v in cfg.items() if k != "query_seed"}, 3)[1]
+    assert np.array_equal(plain.term_idx, a.term_idx) and np.array_equal(plain.weights, a.weights)
+    assert not all(np.array_equal(x, y) for x, y in zip(plain.query_terms, a.query_terms))
+
+
 def test_every_seed_runs_the_same_executables(saat_cfg, open_trf):
     def statics(index):
         leaves, treedef = jax.tree_util.tree_flatten(index)

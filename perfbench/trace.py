@@ -5,9 +5,9 @@ tracing: only the device's operations and the host spans the benchmark
 names ``bench.*``). :func:`read_xplane` pulls the device operations and the
 host spans out of the trace file, and :func:`reduce` turns them into the
 device's busy and idle time over the window, the operations that took most
-time (innermost ops only: a loop's event holds its body's), and the longest
-idle gaps, each named by the host span that covered
-most of it.
+time (innermost ops only: a loop's event holds its body's), the time in
+cross-chip collectives, and the longest idle gaps, each named by the host
+span that covered most of it.
 
 The reduction works on plain ``(name, start_ns, end_ns)`` lists, so
 ``perfbench/tests`` checks it on a hand-made trace.
@@ -23,6 +23,10 @@ import tempfile
 
 WINDOW_SPAN = "bench.window"
 _TOP = 10
+# XLA's collective ops, by the head of their names (``all-gather.3``,
+# ``all_gather.15``, ``all-reduce-start.1``, ``collective-permute-done``, ...)
+COLLECTIVES = ("all-gather", "all-reduce", "collective-permute", "all-to-all",
+               "reduce-scatter")
 
 
 @dataclasses.dataclass
@@ -31,6 +35,9 @@ class TraceSummary:
     window_s: float
     device_ops: list  # [[name, seconds], ...] most time first
     idle_gaps: list  # [[host span, seconds], ...] longest first
+    # union of collective op intervals, mean over the chips; None where no
+    # chip ran one
+    collective_s: float | None = None
 
     @property
     def idle_share(self) -> float:
@@ -69,6 +76,10 @@ def op_name(event_name: str) -> str:
     """``fusion.12`` of an XLA Ops event named by its HLO text
     (``%fusion.12 = f32[...] fusion(...)``)."""
     return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def is_collective(name: str) -> bool:
+    return name.replace("_", "-").startswith(COLLECTIVES)
 
 
 def read_xplane(path: str):
@@ -156,19 +167,24 @@ def _cover_name(gap: tuple[float, float], spans) -> str:
 
 
 def reduce(devices, spans) -> TraceSummary:
-    """Busy time, idle time, top ops and named idle gaps over the window,
-    which is the ``bench.window`` host span."""
+    """Busy time, idle time, top ops, collective time and named idle gaps
+    over the window, which is the ``bench.window`` host span. Times are the
+    mean over the devices (chips) of the trace."""
     windows = [(s, e) for n, s, e in spans if n == WINDOW_SPAN]
     if not windows:
         raise ValueError("the trace holds no bench.window span")
     lo, hi = windows[0]
     if not devices or not any(devices):
         raise ValueError("the trace holds no device operation")
-    busy_ns, totals = 0.0, {}
+    busy_ns, coll_ns, totals = 0.0, 0.0, {}
     all_gaps = []
+    any_coll = False
     for ops in devices:
         merged = union(ops, lo, hi)
         busy_ns += sum(e - s for s, e in merged)
+        coll = [o for o in ops if is_collective(o[0])]
+        any_coll = any_coll or bool(coll)
+        coll_ns += sum(e - s for s, e in union(coll, lo, hi))
         all_gaps += gaps(merged, lo, hi)
         for name, s, e in leaves(ops):
             d = min(e, hi) - max(s, lo)
@@ -179,6 +195,7 @@ def reduce(devices, spans) -> TraceSummary:
     return TraceSummary(
         busy_s=busy_ns / len(devices) / 1e9,
         window_s=(hi - lo) / 1e9,
-        device_ops=[[n, d / 1e9] for n, d in top_ops],
+        device_ops=[[n, d / len(devices) / 1e9] for n, d in top_ops],
         idle_gaps=[[_cover_name(g, spans), (g[1] - g[0]) / 1e9] for g in longest],
+        collective_s=coll_ns / len(devices) / 1e9 if any_coll else None,
     )
